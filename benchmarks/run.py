@@ -41,6 +41,9 @@ def main(argv=None) -> int:
                     help="comma-separated benchmark names")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         beam_width,
         build,

@@ -48,9 +48,8 @@ import numpy as np
 from repro.core.build import _repair_connectivity
 from repro.core.hnsw import GraphArrays
 
-# Row-chunk byte budget for gathered (B, C, d) candidate blocks. Off-TPU the
-# scoring path materializes the gathered block in host memory; on TPU the
-# fused kernel streams it, but the same chunking bounds per-call latency.
+# Row-chunk byte budget for gathered (B, C, d) candidate blocks: the scoring
+# and pruning passes materialize one such block per call.
 _SCORE_BUDGET = 96 * 1024 * 1024
 _POS_INF = np.int32(2**30)  # position/id sentinel for the sort tricks
 
@@ -133,16 +132,18 @@ def _prune_chunk(x_sub, node_idx, cand_ids, m_max: int, alpha: float,
     safe = jnp.clip(cand_ids, 0, x_sub.shape[0] - 1)
     node_vec = x_sub[node_idx]                      # (B, d)
     cand_vec = x_sub[safe]                          # (B, C, d)
-    sq = jnp.einsum("bcd,bcd->bc", cand_vec, cand_vec)
-    nsq = jnp.einsum("bd,bd->b", node_vec, node_vec)
+    # full f32 products: the chip's default f32 matmul is one bf16 pass,
+    # too coarse for the identity's cancellation between close rows
+    dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    sq = dot("bcd,bcd->bc", cand_vec, cand_vec)
+    nsq = dot("bd,bd->b", node_vec, node_vec)
     d_u = jnp.maximum(
-        nsq[:, None] + sq
-        - 2.0 * jnp.einsum("bd,bcd->bc", node_vec, cand_vec), 0.0
+        nsq[:, None] + sq - 2.0 * dot("bd,bcd->bc", node_vec, cand_vec), 0.0
     )
     d_u = jnp.where(valid, d_u, jnp.inf)
     pair = jnp.maximum(
         sq[:, :, None] + sq[:, None, :]
-        - 2.0 * jnp.einsum("bid,bjd->bij", cand_vec, cand_vec), 0.0
+        - 2.0 * dot("bid,bjd->bij", cand_vec, cand_vec), 0.0
     )
 
     def body(j, st):
@@ -176,36 +177,38 @@ def _prune_chunk(x_sub, node_idx, cand_ids, m_max: int, alpha: float,
 # ---------------------------------------------------------------------------
 
 
-def _score_ids(x_dev, node_rows: np.ndarray, ids: np.ndarray, p: float,
-               interpret) -> np.ndarray:
+@functools.partial(jax.jit, static_argnames=("p",))
+def _score_chunk(x, rows, ids, p: float):
+    """Root-free Lp from x[rows[i]] to x[ids[i, :]]; ids outside [0, n)
+    score +inf."""
+    # imported at trace time: repro.core.__init__ pulls in this module
+    from repro.kernels.ops import gather_rowwise_lp
+
+    return gather_rowwise_lp(x[rows], ids, x, p)
+
+
+def _score_ids(x_dev, node_rows: np.ndarray, ids: np.ndarray,
+               p: float) -> np.ndarray:
     """Exact base-metric distances node_rows[i] -> ids[i, :] (chunked).
 
-    Routed through the exact-Lp dispatch entry point
-    (kernels.ops.lp_gather_distance): fused Pallas gather kernel on TPU,
-    jnp reference off-TPU. ids < 0 score +inf. Rows are padded to one
-    uniform chunk shape so the whole pass compiles exactly one program.
+    Each chunk is one XLA gather of its (chunk, C, d) rows, then
+    `rowwise_lp` (kernels.ops.gather_rowwise_lp) on every backend. The
+    build scores ~3,000 candidate rows per corpus row; the fused Pallas
+    gather kernel (the query path's) copies one row at a time, and
+    PERF.md records the per-row cost of both paths on the chip.
+    Rows are padded to one chunk shape (one compiled program per pass)
+    and every chunk is dispatched before the first result is read.
     """
-    from repro.kernels.ops import lp_gather_distance
-
     n_rows, c = ids.shape
-    d = x_dev.shape[1]
-    chunk = _row_chunk_for(c, d, n_rows)
-    out = np.empty((n_rows, c), np.float32)
-    ids_j = jnp.asarray(ids, dtype=jnp.int32)
-    rows_j = jnp.asarray(node_rows, dtype=jnp.int32)
-    for s in range(0, n_rows, chunk):
-        e = min(s + chunk, n_rows)
-        pad = chunk - (e - s)
-        q = x_dev[rows_j[s:e]]
-        blk = ids_j[s:e]
-        if pad:
-            q = jnp.concatenate([q, jnp.zeros((pad, d), q.dtype)])
-            blk = jnp.concatenate(
-                [blk, jnp.full((pad, c), -1, jnp.int32)])
-        dd = lp_gather_distance(q, blk, x_dev, p, root=False,
-                                interpret=interpret)
-        out[s:e] = np.asarray(dd[: e - s])
-    return out
+    chunk = _row_chunk_for(c, x_dev.shape[1], n_rows)
+    n_pad = _round_up(n_rows, chunk)
+    rows = np.zeros(n_pad, np.int32)
+    rows[:n_rows] = node_rows
+    blk = np.full((n_pad, c), -1, np.int32)
+    blk[:n_rows] = ids
+    parts = [_score_chunk(x_dev, rows[s:s + chunk], blk[s:s + chunk], p=p)
+             for s in range(0, n_pad, chunk)]
+    return np.concatenate([np.asarray(d) for d in parts])[:n_rows]
 
 
 def _prune_all(x_dev, n_rows: int, cand_ids: np.ndarray, m_max: int,
@@ -294,7 +297,7 @@ def _exact_seed_pools(data, metric_ps, k: int, interpret,
             pools[p] = (ids2[:, :k].copy(), d2[:, :k].copy())
             continue
         # exact-p scoring of the shared candidate block (chunked gather)
-        dp = _score_ids(x_dev, rows, ids2, p, interpret)
+        dp = _score_ids(x_dev, rows, ids2, p)
         m_ids, m_d = _merge_topk(
             jnp.full((n, 1), -1, jnp.int32), jnp.full((n, 1), jnp.inf),
             jnp.asarray(ids2), jnp.asarray(dp), k,
@@ -346,7 +349,7 @@ def nn_descent_pools(
         """The shared pass: one id block, one distance eval per metric."""
         cand = np.where(cand == own, -1, cand)  # no self-loops
         for p in metric_ps:
-            dd = _score_ids(x_dev, own[:, 0], cand, p, interpret)
+            dd = _score_ids(x_dev, own[:, 0], cand, p)
             ids_p, d_p = pools[p]
             pools[p] = _merge_topk(
                 jnp.asarray(ids_p), jnp.asarray(d_p),
@@ -462,7 +465,7 @@ def _reverse_edges(sel: np.ndarray, nl: int, r_max: int) -> np.ndarray:
 
 def _build_level(
     sub: np.ndarray, x_dev, cand_ids: np.ndarray, p: float, m_max: int,
-    alpha: float, entry_local: int, interpret,
+    alpha: float, entry_local: int,
 ) -> np.ndarray:
     """One level's adjacency from sorted candidate pools (local ids).
 
@@ -485,7 +488,7 @@ def _build_level(
     merged = np.asarray(_dedup_keep_first(jnp.asarray(merged),
                                           merged.shape[1]))
     # exact base-metric ordering for the phase-2 prune
-    dd = _score_ids(x_dev, rows, merged, p, interpret)
+    dd = _score_ids(x_dev, rows, merged, p)
     sd, si = jax.lax.sort(
         (jnp.asarray(dd), jnp.asarray(merged, dtype=jnp.int32)), num_keys=1
     )
@@ -518,7 +521,7 @@ def _emit_arrays(
         m_max = m0 if l == 0 else m
         if l == 0:
             mat = _build_level(data, x_dev, pool_ids, p, m_max, alpha,
-                               int(entry), interpret)
+                               int(entry))
             adj0 = np.where(mat >= 0, mat, n).astype(np.int32)
             continue
         sub = data[nodes]
@@ -530,7 +533,7 @@ def _emit_arrays(
             kk = min(2 * m_max, len(nodes) - 1)
             cand = _exact_knn_local(sub, p, kk, interpret=interpret)
             mat = _build_level(sub, sub_dev, cand, p, m_max, alpha,
-                               entry_local, interpret)
+                               entry_local)
         gmat = np.where(mat >= 0, nodes[np.clip(mat, 0, None)], n)
         g2l = np.full(n, -1, np.int32)
         g2l[nodes] = np.arange(len(nodes), dtype=np.int32)

@@ -70,12 +70,18 @@ def lp_distance(x: jax.Array, y: jax.Array, p, root: bool = True) -> jax.Array:
     return _lp_distance_v(x, y, _as_p_vec(p), root)
 
 
+def _matmul_t(q, x):
+    """q @ x.T at full f32 precision (TPU's default f32 matmul is one bf16
+    pass; the identity's cancellation needs every digit)."""
+    return jnp.matmul(q, x.T, precision=jax.lax.Precision.HIGHEST)
+
+
 @partial(jax.jit, static_argnames=("p", "root"))
 def _pairwise_lp_s(q, x, p: float, root: bool):
     if p == 2.0:
         qq = jnp.sum(q * q, axis=-1)
         xx = jnp.sum(x * x, axis=-1)
-        s = qq[:, None] + xx[None, :] - 2.0 * (q @ x.T)
+        s = qq[:, None] + xx[None, :] - 2.0 * _matmul_t(q, x)
         s = jnp.maximum(s, 0.0)  # clamp fp cancellation
         return jnp.sqrt(s) if root else s
     s = jnp.sum(_abs_diff_pow(q[:, None, :] - x[None, :, :], p), axis=-1)
@@ -91,7 +97,7 @@ def _pairwise_lp_v(q, x, p, root: bool):
                 axis=-1)
     qq = jnp.sum(q * q, axis=-1)
     xx = jnp.sum(x * x, axis=-1)
-    s2 = jnp.maximum(qq[:, None] + xx[None, :] - 2.0 * (q @ x.T), 0.0)
+    s2 = jnp.maximum(qq[:, None] + xx[None, :] - 2.0 * _matmul_t(q, x), 0.0)
     s = jnp.where(p[:, None] == 2.0, s2, s)
     return _root(s, p[:, None]) if root else s
 

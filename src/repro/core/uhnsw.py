@@ -587,11 +587,11 @@ def verify_candidates(
         Qp = jnp.take(Q, band.perm, axis=1)
         if metrics.is_static_p(p):
             return _verify_two_band_jit_s(
-                Q, Qp, cand_ids, cand_base, X, band.codes, band.scale,
+                Q, Qp, cand_ids, cand_base, X, band.rows, band.scale,
                 band.radius, float(p), k, kappa, tau, float(base_p),
                 interpret, block_d)
         return _verify_two_band_jit_v(
-            Q, Qp, cand_ids, cand_base, X, band.codes, band.scale,
+            Q, Qp, cand_ids, cand_base, X, band.rows, band.scale,
             band.radius, jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
             k, kappa, tau, float(base_p), interpret, block_d)
     if abandon:
@@ -773,6 +773,11 @@ class UHNSW:
         self.g1, self.g2 = g1, g2
         self.params = params or UHNSWParams()
         self.X = jnp.asarray(g1.data)
+        from repro.kernels.ops import kernel_rows
+
+        # the verification kernels' row source: X itself unless the TPU
+        # needs the feature axis lane-padded (laid out once, here)
+        self._X_rows = kernel_rows(self.X)
         self.arrays1 = GraphArrays.from_graph(g1)
         self.arrays2 = GraphArrays.from_graph(g2)
         # lazy verification-scan caches (DESIGN.md §10): the int8 band
@@ -799,9 +804,11 @@ class UHNSW:
         """(x_scan, perm) energy-ordered corpus view for energy_perm."""
         if self._scan_cache is None:
             from repro.index.compressed import energy_order
+            from repro.kernels.ops import kernel_rows
 
             perm = jnp.asarray(energy_order(self.X))
-            self._scan_cache = (jnp.take(self.X, perm, axis=1), perm)
+            self._scan_cache = (
+                kernel_rows(jnp.take(self.X, perm, axis=1)), perm)
         return self._scan_cache
 
     def _verify_extras(self) -> dict:
@@ -961,7 +968,7 @@ class UHNSW:
         kappa = prm.kappa or max(k // 2, 1)
         p_arg = float(p) if metrics.is_static_p(p) else p
         ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
-            Q, cand_ids, self.X, p_arg, k, kappa, prm.tau,
+            Q, cand_ids, self._X_rows, p_arg, k, kappa, prm.tau,
             interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
             abandon=prm.abandon, block_d=prm.abandon_block_d,
             **self._verify_extras(),

@@ -127,25 +127,13 @@ def logical_to_spec(
 
 
 def set_mesh(mesh):
-    """Context manager activating `mesh` across jax versions.
-
-    jax >= 0.5 exposes jax.sharding.set_mesh; on older versions the Mesh
-    object itself is the context manager (NamedSharding / shard_map carry
-    their mesh explicitly, so the context only backs bare-PartitionSpec
-    jit/pjit uses).
-    """
-    sm = getattr(jax.sharding, "set_mesh", None)
-    if sm is not None:
-        return sm(mesh)
-    return mesh
+    """Context manager activating `mesh` (`jax.sharding.set_mesh`)."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def abstract_mesh(axis_sizes: tuple[int, ...], axis_names: tuple[str, ...]):
-    """AbstractMesh across jax versions (ctor signature changed ~0.5)."""
-    try:
-        return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
-    except TypeError:
-        return jax.sharding.AbstractMesh(tuple(zip(axis_names, axis_sizes)))
+    """`jax.sharding.AbstractMesh` from parallel size / name tuples."""
+    return jax.sharding.AbstractMesh(tuple(axis_sizes), tuple(axis_names))
 
 
 def constrain(x: jax.Array, rt: Runtime, logical: tuple[str | None, ...]):

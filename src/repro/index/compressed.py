@@ -35,7 +35,7 @@ is dwarfed by the BOUND_SLACK deflation applied at comparison time.
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 
 import jax
 import jax.numpy as jnp
@@ -58,12 +58,21 @@ class CompressedBand:
       perm: (d,) int32 — band coord j = original coord perm[j]. Queries
         enter the screen as Q[:, perm]; results never need unpermuting
         (the screen emits keep decisions, not distances).
+      rows: the screen kernel's row source — `codes` itself unless the
+        TPU needs the feature axis lane-padded (kernels.ops.kernel_rows);
+        derived from `codes` at construction.
     """
 
     codes: jax.Array
     scale: jax.Array
     radius: jax.Array
     perm: jax.Array
+    rows: jax.Array = field(init=False, repr=False, compare=False)
+
+    def __post_init__(self):
+        from repro.kernels.ops import kernel_rows
+
+        object.__setattr__(self, "rows", kernel_rows(self.codes))
 
     @property
     def n(self) -> int:

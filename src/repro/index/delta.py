@@ -107,7 +107,9 @@ class DeltaBuffer:
             z = jnp.zeros((b, 0))
             return z.astype(jnp.int32), z, z.astype(jnp.int32)
         if self._cache is None:
-            self._cache = jnp.asarray(self.vectors())
+            from repro.kernels.ops import kernel_rows
+
+            self._cache = kernel_rows(jnp.asarray(self.vectors()))
         n_delta = len(self._vecs)
         d = self.d
         ids = jnp.broadcast_to(jnp.asarray(self.ids())[None, :],

@@ -69,6 +69,7 @@ from repro.core.uhnsw import (
 from repro.index.delta import DeltaBuffer
 from repro.index.health import SegmentHealthTracker
 from repro.index.segment import SegmentedGraphs, build_segment_pair, build_segments
+from repro.kernels.ops import kernel_rows
 
 
 @dataclass(frozen=True)
@@ -374,6 +375,18 @@ class ShardedUHNSW:
         return int(self._X_host.shape[1])
 
     @property
+    def X(self) -> jax.Array:
+        """(n_frozen, d) f32 device copy of the frozen rows."""
+        return self._X
+
+    @X.setter
+    def X(self, value: jax.Array) -> None:
+        # every placement of the frozen rows also lays out the verification
+        # kernels' row source: X itself unless the TPU needs lane padding
+        self._X = value
+        self._X_rows = kernel_rows(value)
+
+    @property
     def num_segments(self) -> int:
         return self.segments.num_segments
 
@@ -440,7 +453,8 @@ class ShardedUHNSW:
             from repro.index.compressed import energy_order
 
             perm = jnp.asarray(energy_order(self.X))
-            self._scan_cache = (jnp.take(self.X, perm, axis=1), perm)
+            self._scan_cache = (
+                kernel_rows(jnp.take(self.X, perm, axis=1)), perm)
         return self._scan_cache
 
     def _verify_extras(self) -> dict:
@@ -547,7 +561,7 @@ class ShardedUHNSW:
                 # -1 padding passes through: verify_candidates scores it inf
                 ids, dists, n_p, iters, frac, f32f, bandf = \
                     verify_candidates(
-                        Q, cand_ids, self.X, p, k, kappa, prm.tau,
+                        Q, cand_ids, self._X_rows, p, k, kappa, prm.tau,
                         interpret=prm.interpret, cand_base=cand_dists,
                         base_p=base_p, abandon=prm.abandon,
                         block_d=prm.abandon_block_d,
@@ -561,7 +575,7 @@ class ShardedUHNSW:
         # vector p over one homogeneous base: the traced-p program + the
         # per-row base-metric skip mask, exactly as _search_mixed runs it
         ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
-            Q, cand_ids, self.X, p, k, kappa, prm.tau,
+            Q, cand_ids, self._X_rows, p, k, kappa, prm.tau,
             interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
             abandon=prm.abandon, block_d=prm.abandon_block_d,
             **self._verify_extras(),
@@ -765,7 +779,7 @@ class ShardedUHNSW:
         cand_ids, cand_dists = cands.ids, cands.base_dists
         kappa = prm.kappa or max(k // 2, 1)
         ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
-            Q, cand_ids, self.X, p_vec, k, kappa, prm.tau,
+            Q, cand_ids, self._X_rows, p_vec, k, kappa, prm.tau,
             interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
             abandon=prm.abandon, block_d=prm.abandon_block_d,
             **self._verify_extras(),

@@ -47,26 +47,59 @@ from repro.core.lp_ops import lp_root_folded as _root
 # Every kernel here takes p either as a Python float (per-p compile-time
 # specialization — the classic path) or as a per-query-row array (the
 # mixed-p serving path, DESIGN.md §6). Vector p reaches the kernel as a
-# pre-padded (B, 1) f32 operand tiled (TB, 1); the body reads one traced
+# pre-padded (B, 1) f32 operand blocked (TB, 1) into SMEM; the body reads one
 # scalar per query row and the shared op-sequence table's where-select
 # reproduces each row's scalar op sequence bit-for-bit (rows with p == 2
 # additionally take the same MXU matmul-identity branch the scalar p=2
-# kernel uses). All three vector-p kernels share `_row_dist_block` so the
-# parity-critical op sequence cannot drift between entry points.
+# kernel uses). The gather/rowwise vector-p kernels share
+# `_row_dist_block` so the parity-critical op sequence cannot drift
+# between entry points.
+#
+# Mosaic lowering rules the bodies follow: a traced index only ever
+# indexes a *ref* (`q_ref[i, :]`, `dt_ref[pl.ds(off, bd), :]`), never a
+# loaded value (that would be a `dynamic_slice`, which Pallas TPU cannot
+# lower); per-row scalars (p, thresholds) and candidate ids used as DMA
+# addresses live in SMEM and are read as scalars. The ids also ride a
+# second, VMEM copy for the vectorized padding mask.
 
 
-def _row_dist_block(qi: jax.Array, c: jax.Array, pi) -> jax.Array:
-    """One query row vs a (TC, d) candidate tile under traced per-row p.
+def _smem_rows(block_b: int) -> pl.BlockSpec:
+    """(TB, 1) per-query-row scalars (p, thresholds) blocked into SMEM."""
+    return pl.BlockSpec((block_b, 1), lambda i, j: (i, 0),
+                        memory_space=pltpu.SMEM)
 
-    The elementwise family table scores every p; rows with pi == 2 take
-    the MXU matmul-identity value instead (the same expression the scalar
-    p=2 kernels emit, including the cancellation clamp).
+
+def _smem_ids(block_b: int, block_c: int) -> pl.BlockSpec:
+    """(TB, TC) candidate ids blocked into SMEM: the gather's DMA
+    addresses, read one scalar per row copy."""
+    return pl.BlockSpec((block_b, block_c), lambda i, j: (i, j),
+                        memory_space=pltpu.SMEM)
+
+
+def _mxu_dot(a: jax.Array, b: jax.Array) -> jax.Array:
+    """f32-exact MXU product for the p=2 identity (multi-pass on the chip;
+    a one-pass bf16 product would cost ~3 significant digits)."""
+    return jnp.dot(a, b, preferred_element_type=jnp.float32,
+                   precision=jax.lax.Precision.HIGHEST)
+
+
+def _row_dist_block(qi: jax.Array, c: jax.Array, p) -> jax.Array:
+    """One query row vs a (TC, d) candidate tile -> (TC,) power sums.
+
+    Static p emits that p's op sequence only (p == 2: the MXU matmul
+    identity with its cancellation clamp). Traced per-row p scores every
+    family with the elementwise table and rows with p == 2 take the MXU
+    identity value instead — the same expression the static p=2 path
+    emits, so each row is bit-identical to its scalar specialization.
     """
-    s = jnp.sum(_abs_pow(c - qi[None, :], pi), axis=-1)
-    s2 = jnp.sum(qi * qi) + jnp.sum(c * c, axis=-1) - 2.0 * jnp.dot(
-        c, qi, preferred_element_type=jnp.float32
-    )
-    return jnp.where(pi == 2.0, jnp.maximum(s2, 0.0), s)
+    if is_static_p(p) and p != 2.0:
+        return jnp.sum(_abs_pow(c - qi[None, :], p), axis=-1)
+    s2 = jnp.sum(qi * qi) + jnp.sum(c * c, axis=-1) - 2.0 * _mxu_dot(c, qi)
+    s2 = jnp.maximum(s2, 0.0)
+    if is_static_p(p):
+        return s2
+    s = jnp.sum(_abs_pow(c - qi[None, :], p), axis=-1)
+    return jnp.where(p == 2.0, s2, s)
 
 
 # ---------------------------------------------------------------------------
@@ -80,7 +113,7 @@ def _pairwise_l2_kernel(q_ref, x_ref, o_ref, *, root: bool):
     x = x_ref[...].astype(jnp.float32)
     qq = jnp.sum(q * q, axis=-1)[:, None]
     xx = jnp.sum(x * x, axis=-1)[None, :]
-    s = qq + xx - 2.0 * jnp.dot(q, x.T, preferred_element_type=jnp.float32)
+    s = qq + xx - 2.0 * _mxu_dot(q, x.T)
     s = jnp.maximum(s, 0.0)
     o_ref[...] = (jnp.sqrt(s) if root else s).astype(o_ref.dtype)
 
@@ -99,15 +132,17 @@ def _pairwise_vpu_kernel(q_ref, x_ref, o_ref, *, p: float, root: bool):
     jax.lax.fori_loop(0, tb, body, 0)
 
 
-def _pairwise_vec_kernel(p_ref, q_ref, x_ref, o_ref, *, root: bool):
+def _pairwise_vec_kernel(p_ref, q_ref, x_ref, o_ref, s2_ref, *, root: bool):
     """Mixed-p path: per-row traced p; p==2 rows take the MXU identity.
 
     The identity term is hoisted as one (TB, TN) matmul — the same shape
     the scalar `_pairwise_l2_kernel` emits, so p==2 rows are bit-identical
-    to the scalar p=2 kernel. (The fast/slow VPU families match the scalar
-    VPU kernel's op sequences exactly; XLA's fusion choices can still
-    reassociate the d-axis sum by 1-2 ulp on non-lane-aligned tile shapes
-    for p=1.5 — pinned with an explicit ulp tolerance in
+    to the scalar p=2 kernel. It is parked in a VMEM scratch so the row
+    loop reads it by ref (Mosaic cannot index a loaded value with a traced
+    row). (The fast/slow VPU families match the scalar VPU kernel's op
+    sequences exactly; XLA's fusion choices can still reassociate the
+    d-axis sum by 1-2 ulp on non-lane-aligned tile shapes for p=1.5 —
+    pinned with an explicit ulp tolerance in
     tests/test_kernels.py::test_pairwise_vector_p_vs_scalar_ulp_pinned —
     so only the gather/rowwise entry points — the serving hot path — carry
     the hard bit-parity contract.)
@@ -116,17 +151,15 @@ def _pairwise_vec_kernel(p_ref, q_ref, x_ref, o_ref, *, root: bool):
     x = x_ref[...].astype(jnp.float32)
     qq = jnp.sum(q * q, axis=-1)
     xx = jnp.sum(x * x, axis=-1)
-    s2 = qq[:, None] + xx[None, :] - 2.0 * jnp.dot(
-        q, x.T, preferred_element_type=jnp.float32
-    )
-    s2 = jnp.maximum(s2, 0.0)
+    s2 = qq[:, None] + xx[None, :] - 2.0 * _mxu_dot(q, x.T)
+    s2_ref[...] = jnp.maximum(s2, 0.0)
     tb = q.shape[0]
 
     def body(i, _):
         pi = p_ref[i, 0]
-        qi = q[i, :]
+        qi = q_ref[i, :].astype(jnp.float32)
         s = jnp.sum(_abs_pow(x - qi[None, :], pi), axis=-1)
-        s = jnp.where(pi == 2.0, s2[i, :], s)
+        s = jnp.where(pi == 2.0, s2_ref[i, :], s)
         o_ref[i, :] = (_root(s, pi) if root else s).astype(o_ref.dtype)
         return 0
 
@@ -159,12 +192,13 @@ def pairwise_lp_kernel_call(
             functools.partial(_pairwise_vec_kernel, root=root),
             grid=(b // block_b, n // block_n),
             in_specs=[
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
+                _smem_rows(block_b),
                 pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
                 pl.BlockSpec((block_n, d), lambda i, j: (j, 0)),
             ],
             out_specs=pl.BlockSpec((block_b, block_n), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((b, n), out_dtype),
+            scratch_shapes=[pltpu.VMEM((block_b, block_n), jnp.float32)],
             interpret=interpret,
         )(p, q, x)
 
@@ -193,15 +227,12 @@ def pairwise_lp_kernel_call(
 
 
 def _rowwise_l2_kernel(q_ref, c_ref, o_ref, *, root: bool):
-    q = q_ref[...].astype(jnp.float32)  # (TB, d)
-    tb = q.shape[0]
+    tb = q_ref.shape[0]
 
     def body(i, _):
         c = c_ref[i, :, :].astype(jnp.float32)  # (TC, d)
-        qi = q[i, :]
-        s = jnp.sum(qi * qi) + jnp.sum(c * c, axis=-1) - 2.0 * jnp.dot(
-            c, qi, preferred_element_type=jnp.float32
-        )
+        qi = q_ref[i, :].astype(jnp.float32)
+        s = jnp.sum(qi * qi) + jnp.sum(c * c, axis=-1) - 2.0 * _mxu_dot(c, qi)
         s = jnp.maximum(s, 0.0)
         o_ref[i, :] = (jnp.sqrt(s) if root else s).astype(o_ref.dtype)
         return 0
@@ -263,7 +294,7 @@ def rowwise_lp_kernel_call(
             functools.partial(_rowwise_vec_kernel, root=root),
             grid=(b // block_b, cc // block_c),
             in_specs=[
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
+                _smem_rows(block_b),
                 pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
                 pl.BlockSpec((block_b, block_c, d), lambda i, j: (i, j, 0)),
             ],
@@ -303,82 +334,125 @@ def rowwise_lp_kernel_call(
 # (MXU dot for p=2, VPU elementwise otherwise). The (B, C, d) intermediate
 # never exists.
 #
+# Row DMAs follow X's HBM tiling: a DMA may only move whole layout tiles of
+# rows (8 rows for 32-bit data, 32 for int8) and whole 128-lane rows, so
+# each candidate fetches the aligned row tile that holds it into a small
+# staging buffer and copies its one row out of VMEM; the compiled-path
+# callers (kernels/ops.py) pad d to a lane multiple. Rows past the last
+# whole tile (n % tile rows of them) ride a small f32 `tail` operand.
+#
 # Ids outside [0, n) are padding sentinels (-1 from merges, n from beams):
 # they gather a clamped dummy row and score +inf, so callers can pass padded
 # id blocks straight through.
 # ---------------------------------------------------------------------------
 
 
-def _dma_gather_rows(ids_row, x_hbm, gx_ref, sem, n: int, block_c: int):
-    """DMA the TC candidate rows of one query into the VMEM scratch.
+def _tile_rows(dtype) -> int:
+    """Rows per HBM layout tile of a (n, d) array: the row granularity of
+    a DMA out of it (8 for 32-bit, 32 for int8)."""
+    return 8 * 4 // jnp.dtype(dtype).itemsize
 
-    DMAs issue sequentially (start/wait per row); a double-buffered variant
-    would overlap row j+1's copy with row j's compute, but the VMEM scratch
-    already bounds the win to DMA latency. Shared by the scalar and
-    vector-p gather kernels.
+
+def _row_source(x: jax.Array):
+    """(in_specs, operands, scratch) of the tile-aligned row gather from
+    x (n, d): x stays in HBM; its last n % tile-rows rows ride a padded f32
+    `tail` block in VMEM; scratch is the x-dtype staging tile plus its f32
+    widening (row picks index 32-bit refs only)."""
+    n, d = x.shape
+    rows = _tile_rows(x.dtype)
+    n_al = n - n % rows
+    tail = jnp.zeros((rows, d), jnp.float32)
+    tail = tail.at[:n - n_al].set(x[n_al:].astype(jnp.float32))
+    specs = [pl.BlockSpec(memory_space=pl.ANY),   # X stays in HBM
+             pl.BlockSpec(memory_space=pltpu.VMEM)]  # tail, fetched once
+    scratch = [pltpu.VMEM((rows, d), x.dtype), pltpu.VMEM((rows, d), jnp.float32)]
+    return specs, (x, tail), scratch
+
+
+def _dma_gather_rows(ids_ref, i, x_hbm, tail_ref, stage_ref, wide_ref,
+                     gx_ref, sem, *, n: int, block_c: int):
+    """Copy the TC candidate rows of query row i into the (TC, d) f32
+    scratch `gx_ref`.
+
+    Row ids are read as scalars from the SMEM id block. Each row's aligned
+    HBM tile is DMA'd into `stage_ref` and the row picked out by ref;
+    rows in the ragged last tile come from `tail_ref`. DMAs issue
+    sequentially (start/wait per row); a double-buffered variant would
+    overlap row j+1's copy with row j's compute, but the VMEM scratch
+    already bounds the win to DMA latency. Shared by the gather, abandon
+    and screen kernels.
     """
+    rows = stage_ref.shape[0]
+    n_al = n - n % rows
+    widen = stage_ref.dtype != jnp.float32
+    pick_ref = wide_ref if widen else stage_ref
 
     def gather(j, _):
-        safe = jnp.clip(ids_row[j], 0, n - 1)
-        cp = pltpu.make_async_copy(
-            x_hbm.at[pl.ds(safe, 1), :], gx_ref.at[pl.ds(j, 1), :], sem
-        )
-        cp.start()
-        cp.wait()
+        idx = jnp.clip(ids_ref[i, j], 0, n - 1)
+        if n_al:
+            @pl.when(idx < n_al)
+            def _():
+                base = pl.multiple_of(idx // rows * rows, rows)
+                cp = pltpu.make_async_copy(
+                    x_hbm.at[pl.ds(base, rows), :], stage_ref, sem)
+                cp.start()
+                cp.wait()
+                if widen:
+                    wide_ref[...] = stage_ref[...].astype(jnp.float32)
+                gx_ref[pl.ds(j, 1), :] = pick_ref[pl.ds(idx - base, 1), :]
+        if n_al < n:
+            @pl.when(idx >= n_al)
+            def _():
+                gx_ref[pl.ds(j, 1), :] = tail_ref[pl.ds(idx - n_al, 1), :]
         return 0
 
     jax.lax.fori_loop(0, block_c, gather, 0)
 
 
-def _gather_lp_kernel(ids_ref, q_ref, x_hbm, o_ref, gx_ref, sem,
-                      *, p: float, root: bool, n: int, block_c: int):
+def _split_p(refs, p):
+    """Kernel operand lists carry an SMEM (TB, 1) p block right after the
+    query tile iff p is traced (p is None); scalar p is a static float."""
+    if p is not None:
+        return refs, lambda i: p
+    head, p_ref, tail = refs[:3], refs[3], refs[4:]
+    return head + tail, lambda i: p_ref[i, 0]
+
+
+def _gather_lp_kernel(*refs, p, root: bool, n: int, block_c: int):
     """One (TB, TC) output tile.
 
-    Per query row: TC row DMAs (HBM -> VMEM scratch), then one vectorized
-    (TC, d) distance block.
+    Per query row: TC row copies (HBM -> VMEM scratch), then one vectorized
+    (TC, d) distance block. Scalar p (a static float) emits that p's op
+    sequence only; traced p (p=None, per-row SMEM scalars) selects per row.
     """
+    (ids_s, ids_v, q_ref, x_hbm, tail_ref, o_ref, stage_ref, wide_ref,
+     gx_ref, sem), p_of = _split_p(refs, p)
     tb = q_ref.shape[0]
 
     def per_query(i, _):
-        ids_row = ids_ref[i, :]  # (TC,)
-        _dma_gather_rows(ids_row, x_hbm, gx_ref, sem, n, block_c)
+        _dma_gather_rows(ids_s, i, x_hbm, tail_ref, stage_ref, wide_ref,
+                         gx_ref, sem, n=n, block_c=block_c)
+        pi = p_of(i)
         qi = q_ref[i, :].astype(jnp.float32)
-        ct = gx_ref[...].astype(jnp.float32)  # (TC, d)
-        if p == 2.0:
-            s = jnp.sum(qi * qi) + jnp.sum(ct * ct, axis=-1) - 2.0 * jnp.dot(
-                ct, qi, preferred_element_type=jnp.float32
-            )
-            s = jnp.maximum(s, 0.0)
-        else:
-            s = jnp.sum(_abs_pow(ct - qi[None, :], p), axis=-1)
-        val = _root(s, p) if root else s
-        ok = (ids_row >= 0) & (ids_row < n)
-        o_ref[i, :] = jnp.where(ok, val, jnp.inf).astype(o_ref.dtype)
-        return 0
-
-    jax.lax.fori_loop(0, tb, per_query, 0)
-
-
-def _gather_lp_vec_kernel(ids_ref, q_ref, p_ref, x_hbm, o_ref, gx_ref, sem,
-                          *, root: bool, n: int, block_c: int):
-    """Mixed-p variant of `_gather_lp_kernel`: same per-row DMA gather, with
-    each query row scored under its own traced p (p==2 rows take the same
-    MXU-identity branch the scalar p=2 kernel emits)."""
-    tb = q_ref.shape[0]
-
-    def per_query(i, _):
-        ids_row = ids_ref[i, :]  # (TC,)
-        _dma_gather_rows(ids_row, x_hbm, gx_ref, sem, n, block_c)
-        pi = p_ref[i, 0]
-        qi = q_ref[i, :].astype(jnp.float32)
-        ct = gx_ref[...].astype(jnp.float32)  # (TC, d)
-        s = _row_dist_block(qi, ct, pi)
+        s = _row_dist_block(qi, gx_ref[...], pi)
         val = _root(s, pi) if root else s
+        ids_row = ids_v[i, :]
         ok = (ids_row >= 0) & (ids_row < n)
         o_ref[i, :] = jnp.where(ok, val, jnp.inf).astype(o_ref.dtype)
         return 0
 
     jax.lax.fori_loop(0, tb, per_query, 0)
+
+
+def _gather_in_specs(block_b: int, block_c: int, d: int, vector_p: bool):
+    """ids (SMEM + VMEM copies), query tile, [p] — the shared head of
+    every gather-family kernel's operand list."""
+    specs = [
+        _smem_ids(block_b, block_c),
+        pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
+        pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
+    ]
+    return specs + [_smem_rows(block_b)] if vector_p else specs
 
 
 def gather_lp_kernel_call(
@@ -393,7 +467,8 @@ def gather_lp_kernel_call(
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:
-    """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0).
+    """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0;
+    compiled for TPU, d % 128 == 0 — zero columns are distance-neutral).
 
     p: Python float, or a pre-padded (B, 1) f32 array (one metric per query
     row — the mixed-p contract described in the module preamble).
@@ -403,47 +478,25 @@ def gather_lp_kernel_call(
     n = x.shape[0]
     assert b == b2 and b % block_b == 0 and cc % block_c == 0, \
         (b, b2, cc, block_b, block_c)
-
-    if not is_static_p(p):
+    vector_p = not is_static_p(p)
+    if vector_p:
         assert p.shape == (b, 1), (p.shape, b)
-        return pl.pallas_call(
-            functools.partial(
-                _gather_lp_vec_kernel, root=root, n=n, block_c=block_c
-            ),
-            grid=(b // block_b, cc // block_c),
-            in_specs=[
-                pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-                pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # X stays in HBM
-            ],
-            out_specs=pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            out_shape=jax.ShapeDtypeStruct((b, cc), out_dtype),
-            scratch_shapes=[
-                pltpu.VMEM((block_c, d), jnp.float32),
-                pltpu.SemaphoreType.DMA,
-            ],
-            interpret=interpret,
-        )(ids, q, p, x)
-
+    src_specs, src_ops, src_scratch = _row_source(x)
     return pl.pallas_call(
         functools.partial(
-            _gather_lp_kernel, p=p, root=root, n=n, block_c=block_c
+            _gather_lp_kernel, p=None if vector_p else float(p), root=root,
+            n=n, block_c=block_c,
         ),
         grid=(b // block_b, cc // block_c),
-        in_specs=[
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # X stays in HBM
-        ],
+        in_specs=_gather_in_specs(block_b, block_c, d, vector_p) + src_specs,
         out_specs=pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, cc), out_dtype),
-        scratch_shapes=[
+        scratch_shapes=src_scratch + [
             pltpu.VMEM((block_c, d), jnp.float32),
             pltpu.SemaphoreType.DMA,
         ],
         interpret=interpret,
-    )(ids, q, x)
+    )(ids, ids, q, *((p,) if vector_p else ()), *src_ops)
 
 
 # ---------------------------------------------------------------------------
@@ -455,117 +508,159 @@ def gather_lp_kernel_call(
 # terms, so a candidate's partial sum over a prefix of dimension blocks is a
 # monotone lower bound on its final distance — any candidate whose partial
 # sum (or provable lower bound, core/lp_ops.lp_entry_bound/lp_suffix_bound)
-# exceeds the per-query threshold tile is abandoned exactly, skipping all
+# exceeds the per-query threshold is abandoned exactly, skipping all
 # remaining blocks' transcendental work.
 #
-# Layout: the gathered (TC, d) rows are transposed ONCE to (d, TC) so
-# dimension blocks are *sublane* slices (granularity 8, block_d=32 default)
-# while candidates occupy full 128-wide lanes — fine-grained abandonment
-# checks without wasting lanes (a (TC, 32) lane-dim slice would run the VPU
-# at 1/4 occupancy). Per block, `lax.cond` on the row's alive mask skips the
+# Layout: the gathered (TC, d) rows minus the query are transposed ONCE
+# into a (d, TC) VMEM scratch so dimension blocks are *sublane* slices
+# (`pl.ds` on the scratch ref; granularity 8, block_d=32 default) while
+# candidates occupy full 128-wide lanes — fine-grained abandonment checks
+# without wasting lanes (a (TC, 32) lane-dim slice would run the VPU at
+# 1/4 occupancy). Per block, `lax.cond` on the row's alive mask skips the
 # transcendental family entirely once every candidate in the tile is dead;
 # a row whose candidates are all dead at entry (threshold -inf = frozen
-# query, or every entry bound beaten) skips its DMA gather too.
+# query, or every entry bound beaten) skips its DMA gather too. The alive
+# mask crosses loop and branch boundaries as int32 (Mosaic cannot carry
+# i1 vectors through scf.if). `d` is the logical width: lane-padding
+# columns past it are never scanned.
 # ---------------------------------------------------------------------------
 
 
-def _abandon_row(ids_row, qi, thr, sb_row, pi, x_hbm, gx_ref, sem,
-                 *, base_p: float, n: int, block_c: int, block_d: int):
-    """One query row of the abandoning scan. Returns (dists, nd) (TC,)."""
-    d = qi.shape[0]
-    nb = d // block_d
-    valid = (ids_row >= 0) & (ids_row < n)
-    lb = lp_entry_bound(sb_row, base_p, pi, d)
-    alive0 = valid & (lb <= thr)
+def _blocked_scan(i, ids_v, sb_ref, thr, pi, gather_tile, block_fn,
+                  *, base_p: float, n: int, d: int, block_c: int,
+                  block_d: int, deflate: float):
+    """The alive-gated blocked scan shared by the abandon and screen
+    kernels, for query row i. Returns (s, alive, nd) (TC,).
 
-    def dead_row(_):
-        return (jnp.full((block_c,), jnp.inf, jnp.float32),
-                jnp.zeros((block_c,), jnp.int32))
+    `gather_tile()` fills the (d, TC) scratch for this row (skipped when
+    every candidate is dead at entry); `block_fn(off)` returns the block
+    at dimension offset `off` as (lower-bound terms, base-metric terms),
+    both (block_d, TC) >= 0. A candidate dies when its deflated running
+    sum, or that plus the suffix bound, exceeds `thr`.
+    """
+    nb = d // block_d
+    sb_row = sb_ref[i, :]
+    ids_row = ids_v[i, :]
+    valid = (ids_row >= 0) & (ids_row < n)
+    alive0 = (valid & (lp_entry_bound(sb_row, base_p, pi, d) <= thr)
+              ).astype(jnp.int32)
+    zeros = jnp.zeros((block_c,), jnp.float32)
+    nd0 = jnp.zeros((block_c,), jnp.int32)
 
     def scan_row(_):
-        _dma_gather_rows(ids_row, x_hbm, gx_ref, sem, n, block_c)
-        # one transpose + subtract; dimension blocks below are sublane
-        # slices of this (d, TC) diff tile
-        dt = gx_ref[...].astype(jnp.float32).T - qi[:, None]
+        gather_tile()
+
+        def compute(b, carry):
+            s, sbase, alive_i, nd = carry
+            alive = alive_i != 0
+            al, au = block_fn(pl.multiple_of(b * block_d, block_d))
+            bs = jnp.sum(pow_from_abs(al, pi), axis=0)
+            bb = jnp.sum(au if base_p == 1.0 else au * au, axis=0)
+            s = jnp.where(alive, s + bs, s)
+            sbase = jnp.where(alive, sbase + bb, sbase)
+            nd = nd + jnp.where(alive, block_d, 0)
+            dead = s * deflate > thr
+            d_rem = (d - (b + 1) * block_d).astype(jnp.float32)
+            rem = lp_suffix_bound(sb_row - sbase, base_p, pi, d_rem)
+            dead = dead | ((d_rem > 0) & ((s + rem) * deflate > thr))
+            return (s, sbase, (alive & ~dead).astype(jnp.int32), nd)
 
         def block_step(b, carry):
-            s, sbase, alive, nd = carry
+            return jax.lax.cond(jnp.max(carry[2]) > 0,
+                                functools.partial(compute, b),
+                                lambda c: c, carry)
 
-            def compute(args):
-                s, sbase, alive, nd = args
-                blk = jax.lax.dynamic_slice(
-                    dt, (b * block_d, 0), (block_d, block_c))
-                a = jnp.abs(blk)
-                bs = jnp.sum(pow_from_abs(a, pi), axis=0)
-                bb = jnp.sum(a if base_p == 1.0 else a * a, axis=0)
-                s = jnp.where(alive, s + bs, s)
-                sbase = jnp.where(alive, sbase + bb, sbase)
-                nd = nd + jnp.where(alive, block_d, 0)
-                dead = s > thr
-                d_rem = (d - (b + 1) * block_d).astype(jnp.float32)
-                rem = lp_suffix_bound(sb_row - sbase, base_p, pi, d_rem)
-                dead = dead | ((d_rem > 0) & (s + rem > thr))
-                return (s, sbase, alive & ~dead, nd)
+        s, _, alive, nd = jax.lax.fori_loop(
+            0, nb, block_step, (zeros, zeros, alive0, nd0))
+        return s, alive, nd
 
-            return jax.lax.cond(jnp.any(carry[2]), compute,
-                                lambda args: args, carry)
-
-        s0 = jnp.zeros((block_c,), jnp.float32)
-        carry = (s0, s0, alive0, jnp.zeros((block_c,), jnp.int32))
-        s, _, alive, nd = jax.lax.fori_loop(0, nb, block_step, carry)
-        return jnp.where(alive, s, jnp.inf), nd
-
-    return jax.lax.cond(jnp.any(alive0), scan_row, dead_row, 0)
+    s, alive, nd = jax.lax.cond(jnp.max(alive0) > 0, scan_row,
+                                lambda _: (zeros, nd0, nd0), 0)
+    return s, alive != 0, nd
 
 
-def _gather_abandon_kernel(ids_ref, q_ref, th_ref, sb_ref, x_hbm,
-                           o_ref, nd_ref, gx_ref, sem,
-                           *, p: float, base_p: float, n: int,
+def _gather_abandon_kernel(*refs, p, base_p: float, n: int, d: int,
                            block_c: int, block_d: int):
+    (ids_s, ids_v, q_ref, th_ref, sb_ref, x_hbm, tail_ref, o_ref, nd_ref,
+     stage_ref, wide_ref, gx_ref, dt_ref, sem), p_of = _split_p(refs, p)
     tb = q_ref.shape[0]
 
     def per_query(i, _):
-        out, nd = _abandon_row(
-            ids_ref[i, :], q_ref[i, :].astype(jnp.float32), th_ref[i, 0],
-            sb_ref[i, :], p, x_hbm, gx_ref, sem,
-            base_p=base_p, n=n, block_c=block_c, block_d=block_d,
-        )
-        o_ref[i, :] = out.astype(o_ref.dtype)
+        qi = q_ref[i, :].astype(jnp.float32)
+
+        def gather_tile():
+            _dma_gather_rows(ids_s, i, x_hbm, tail_ref, stage_ref, wide_ref,
+                             gx_ref, sem, n=n, block_c=block_c)
+            # one subtract + transpose; dimension blocks are sublane
+            # slices of this (d, TC) diff tile
+            dt_ref[...] = (gx_ref[...] - qi[None, :]).T
+
+        def block_fn(off):
+            a = jnp.abs(dt_ref[pl.ds(off, block_d), :])
+            return a, a
+
+        s, alive, nd = _blocked_scan(
+            i, ids_v, sb_ref, th_ref[i, 0], p_of(i), gather_tile, block_fn,
+            base_p=base_p, n=n, d=d, block_c=block_c, block_d=block_d,
+            deflate=1.0)
+        o_ref[i, :] = jnp.where(alive, s, jnp.inf).astype(o_ref.dtype)
         nd_ref[i, :] = nd
         return 0
 
     jax.lax.fori_loop(0, tb, per_query, 0)
 
 
-def _gather_abandon_vec_kernel(ids_ref, q_ref, th_ref, sb_ref, p_ref, x_hbm,
-                               o_ref, nd_ref, gx_ref, sem,
-                               *, base_p: float, n: int,
-                               block_c: int, block_d: int):
-    """Mixed-p variant: each query row scanned under its own traced p."""
-    tb = q_ref.shape[0]
-
-    def per_query(i, _):
-        out, nd = _abandon_row(
-            ids_ref[i, :], q_ref[i, :].astype(jnp.float32), th_ref[i, 0],
-            sb_ref[i, :], p_ref[i, 0], x_hbm, gx_ref, sem,
-            base_p=base_p, n=n, block_c=block_c, block_d=block_d,
-        )
-        o_ref[i, :] = out.astype(o_ref.dtype)
-        nd_ref[i, :] = nd
-        return 0
-
-    jax.lax.fori_loop(0, tb, per_query, 0)
+def _blocked_call(kernel, ids, q, p, thresh, sb, src, extra, *, d: int,
+                  block_b: int, block_c: int, block_d: int,
+                  out_dtypes, interpret: bool, **kw):
+    """pallas_call shared by the abandon and screen kernels: operands are
+    the gather head (ids, q, [p]), SMEM thresholds, base sums, whole-array
+    VMEM `extra` operands, then the row source `src` (n, dx); d <= dx is
+    the logical width scanned."""
+    b, dx = q.shape
+    b2, cc = ids.shape
+    n = src.shape[0]
+    assert b == b2 and b % block_b == 0 and cc % block_c == 0, \
+        (b, b2, cc, block_b, block_c)
+    assert d <= dx and d % block_d == 0, (d, dx, block_d)
+    assert thresh.shape == (b, 1), (thresh.shape, b)
+    vector_p = not is_static_p(p)
+    if vector_p:
+        assert p.shape == (b, 1), (p.shape, b)
+    src_specs, src_ops, src_scratch = _row_source(src)
+    tile = pl.BlockSpec((block_b, block_c), lambda i, j: (i, j))
+    return pl.pallas_call(
+        functools.partial(
+            kernel, p=None if vector_p else float(p), n=n, d=d,
+            block_c=block_c, block_d=block_d, **kw,
+        ),
+        grid=(b // block_b, cc // block_c),
+        in_specs=_gather_in_specs(block_b, block_c, dx, vector_p)
+        + [_smem_rows(block_b), tile]
+        + [pl.BlockSpec(memory_space=pltpu.VMEM)] * len(extra) + src_specs,
+        out_specs=(tile, tile),
+        out_shape=tuple(jax.ShapeDtypeStruct((b, cc), dt)
+                        for dt in out_dtypes),
+        scratch_shapes=src_scratch + [
+            pltpu.VMEM((block_c, dx), jnp.float32),
+            pltpu.VMEM((dx, block_c), jnp.float32),
+            pltpu.SemaphoreType.DMA,
+        ],
+        interpret=interpret,
+    )(ids, ids, q, *((p,) if vector_p else ()), thresh, sb, *extra,
+      *src_ops)
 
 
 def gather_lp_abandon_kernel_call(
     ids: jax.Array,     # (B, C) int32 candidate ids; out-of-range = padding
-    q: jax.Array,       # (B, d)
+    q: jax.Array,       # (B, dx)
     thresh: jax.Array,  # (B, 1) per-query abandon bound (power-sum space;
                         # -inf = row frozen, +inf = no abandonment)
     sb: jax.Array,      # (B, C) base-metric power sums (0 = no bound info)
-    x: jax.Array,       # (n, d) HBM-resident dataset
+    x: jax.Array,       # (n, dx) HBM-resident dataset
     p,
     *,
+    d: int | None = None,
     base_p: float = 1.0,
     block_b: int = 8,
     block_c: int = 128,
@@ -574,184 +669,76 @@ def gather_lp_abandon_kernel_call(
     out_dtype=jnp.float32,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0,
-    d % block_d == 0). Returns (dists (B, C) root-free power sums with +inf
-    for abandoned/padding candidates, nd (B, C) int32 dimensions scanned).
+    d % block_d == 0; d = logical width <= dx, default dx — compiled for
+    TPU, dx % 128 == 0). Returns (dists (B, C) root-free power sums with
+    +inf for abandoned/padding candidates, nd (B, C) int32 dimensions
+    scanned).
 
     p: Python float, or a pre-padded (B, 1) f32 array (one metric per query
     row — the mixed-p contract in the module preamble). base_p (static 1.0
     or 2.0) names the metric of `sb` for the entry/suffix bounds.
     """
-    b, d = q.shape
-    b2, cc = ids.shape
-    n = x.shape[0]
-    assert b == b2 and b % block_b == 0 and cc % block_c == 0, \
-        (b, b2, cc, block_b, block_c)
-    assert d % block_d == 0, (d, block_d)
-
-    common = dict(
-        grid=(b // block_b, cc // block_c),
-        out_specs=(
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, cc), out_dtype),
-            jax.ShapeDtypeStruct((b, cc), jnp.int32),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_c, d), jnp.float32),
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )
-    if not is_static_p(p):
-        assert p.shape == (b, 1), (p.shape, b)
-        return pl.pallas_call(
-            functools.partial(
-                _gather_abandon_vec_kernel, base_p=base_p, n=n,
-                block_c=block_c, block_d=block_d,
-            ),
-            in_specs=[
-                pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-                pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # X stays in HBM
-            ],
-            **common,
-        )(ids, q, thresh, sb, p, x)
-    return pl.pallas_call(
-        functools.partial(
-            _gather_abandon_kernel, p=float(p), base_p=base_p, n=n,
-            block_c=block_c, block_d=block_d,
-        ),
-        in_specs=[
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # X stays in HBM
-        ],
-        **common,
-    )(ids, q, thresh, sb, x)
+    return _blocked_call(
+        _gather_abandon_kernel, ids, q, p, thresh, sb, x, (),
+        d=q.shape[1] if d is None else d, block_b=block_b, block_c=block_c,
+        block_d=block_d, out_dtypes=(out_dtype, jnp.int32),
+        interpret=interpret, base_p=base_p)
 
 
 # ---------------------------------------------------------------------------
 # compressed-band screen kernel (DESIGN.md §10): ids (B, C) + thresholds
-# (B, 1) + base sums (B, C) + int8 codes (n, d) + scale/radius (1, d)
-#   -> keep (B, C) int32 0/1, nd (B, C) int32 band dimensions scanned
+# (B, 1) + base sums (B, C) + int8 codes (n, d) + scale (1, d) / radius
+# (d, 1) -> keep (B, C) int32 0/1, nd (B, C) int32 band dimensions scanned
 #
 # The storage-side sibling of the abandon kernel: instead of gathering f32
 # rows and accumulating *exact* partial power sums, it gathers int8 band
-# rows (1/4 the DMA bytes) and accumulates the certified per-coordinate
-# lower bound max(|q_j - x̂_j| - radius_j, 0)^p (index/compressed.py).
-# A candidate whose deflated running bound exceeds the per-query threshold
-# provably cannot enter the top-k, so the two-band scan never issues its
-# f32 gather — the screen's survivors are the only rows the exact rerank
-# touches. Same transposed (d, TC) layout, same per-block lax.cond alive
-# gating, same entry/suffix bounds from the beam's base power sums as the
-# abandon kernel; the suffix bound's scanned base mass accumulates the
-# per-coordinate *upper* bounds (|q_j - x̂_j| + radius_j) so the remaining
-# mass stays an underestimate. Because the accumulated sum is a float-
-# evaluated bound (not an exact partial of the true distance), every kill
-# comparison deflates by BOUND_SLACK.
+# rows and accumulates the certified per-coordinate lower bound
+# max(|q_j - x̂_j| - radius_j, 0)^p (index/compressed.py). A candidate
+# whose deflated running bound exceeds the per-query threshold provably
+# cannot enter the top-k, so the two-band scan never issues its f32
+# gather — the screen's survivors are the only rows the exact rerank
+# touches. Same transposed (d, TC) scratch layout, same per-block lax.cond
+# alive gating, same entry/suffix bounds from the beam's base power sums
+# as the abandon kernel (`_blocked_scan`); the suffix bound's scanned base
+# mass accumulates the per-coordinate *upper* bounds (|q_j - x̂_j| +
+# radius_j) so the remaining mass stays an underestimate. Because the
+# accumulated sum is a float-evaluated bound (not an exact partial of the
+# true distance), every kill comparison deflates by BOUND_SLACK. The
+# dequant scale multiplies the gathered rows as a (1, d) row; the radius
+# rides as a (d, 1) column so each dimension block slices it by ref. An
+# int8 row tile is 32 rows, so on the chip a candidate's band fetch moves
+# as many bytes as its f32 row fetch (8 rows) — the screen saves the
+# rerank's gathers and transcendentals, not DMA bytes.
 # ---------------------------------------------------------------------------
 
 
-def _screen_row(ids_row, qi, thr, sb_row, pi, scale_col, radius_col,
-                codes_hbm, gx_ref, sem,
-                *, base_p: float, n: int, block_c: int, block_d: int):
-    """One query row of the compressed screen. Returns (keep, nd) (TC,)."""
-    d = qi.shape[0]
-    nb = d // block_d
-    deflate = 1.0 - BOUND_SLACK
-    valid = (ids_row >= 0) & (ids_row < n)
-    lb = lp_entry_bound(sb_row, base_p, pi, d)
-    alive0 = valid & (lb <= thr)
-
-    def dead_row(_):
-        return (jnp.zeros((block_c,), jnp.int32),
-                jnp.zeros((block_c,), jnp.int32))
-
-    def scan_row(_):
-        _dma_gather_rows(ids_row, codes_hbm, gx_ref, sem, n, block_c)
-        # dequant + subtract once; dimension blocks below are sublane
-        # slices of this (d, TC) |q - x̂| tile
-        a0 = jnp.abs(
-            gx_ref[...].astype(jnp.float32).T * scale_col - qi[:, None])
-
-        def block_step(b, carry):
-            s, sbase, alive, nd = carry
-
-            def compute(args):
-                s, sbase, alive, nd = args
-                blk = jax.lax.dynamic_slice(
-                    a0, (b * block_d, 0), (block_d, block_c))
-                rblk = jax.lax.dynamic_slice(
-                    radius_col, (b * block_d, 0), (block_d, 1))
-                al = jnp.maximum(blk - rblk, 0.0)   # certified lower bounds
-                au = blk + rblk                     # upper bounds (suffix)
-                bs = jnp.sum(pow_from_abs(al, pi), axis=0)
-                bb = jnp.sum(au if base_p == 1.0 else au * au, axis=0)
-                s = jnp.where(alive, s + bs, s)
-                sbase = jnp.where(alive, sbase + bb, sbase)
-                nd = nd + jnp.where(alive, block_d, 0)
-                dead = s * deflate > thr
-                d_rem = (d - (b + 1) * block_d).astype(jnp.float32)
-                rem = lp_suffix_bound(sb_row - sbase, base_p, pi, d_rem)
-                dead = dead | ((d_rem > 0) & ((s + rem) * deflate > thr))
-                return (s, sbase, alive & ~dead, nd)
-
-            return jax.lax.cond(jnp.any(carry[2]), compute,
-                                lambda args: args, carry)
-
-        s0 = jnp.zeros((block_c,), jnp.float32)
-        carry = (s0, s0, alive0, jnp.zeros((block_c,), jnp.int32))
-        _, _, alive, nd = jax.lax.fori_loop(0, nb, block_step, carry)
-        return alive.astype(jnp.int32), nd
-
-    return jax.lax.cond(jnp.any(alive0), scan_row, dead_row, 0)
-
-
-def _gather_screen_kernel(ids_ref, q_ref, th_ref, sb_ref, sc_ref, rad_ref,
-                          codes_hbm, keep_ref, nd_ref, gx_ref, sem,
-                          *, p: float, base_p: float, n: int,
+def _gather_screen_kernel(*refs, p, base_p: float, n: int, d: int,
                           block_c: int, block_d: int):
+    (ids_s, ids_v, q_ref, th_ref, sb_ref, sc_ref, rad_ref, codes_hbm,
+     tail_ref, keep_ref, nd_ref, stage_ref, wide_ref, gx_ref, dt_ref,
+     sem), p_of = _split_p(refs, p)
     tb = q_ref.shape[0]
-    scale_col = sc_ref[...].astype(jnp.float32).T    # (d, 1)
-    radius_col = rad_ref[...].astype(jnp.float32).T  # (d, 1)
 
     def per_query(i, _):
-        keep, nd = _screen_row(
-            ids_ref[i, :], q_ref[i, :].astype(jnp.float32), th_ref[i, 0],
-            sb_ref[i, :], p, scale_col, radius_col, codes_hbm, gx_ref, sem,
-            base_p=base_p, n=n, block_c=block_c, block_d=block_d,
-        )
-        keep_ref[i, :] = keep
-        nd_ref[i, :] = nd
-        return 0
+        qi = q_ref[i, :].astype(jnp.float32)
 
-    jax.lax.fori_loop(0, tb, per_query, 0)
+        def gather_tile():
+            _dma_gather_rows(ids_s, i, codes_hbm, tail_ref, stage_ref,
+                             wide_ref, gx_ref, sem, n=n, block_c=block_c)
+            # dequant + subtract + transpose once; dimension blocks are
+            # sublane slices of this (d, TC) |q - x̂| tile
+            dt_ref[...] = jnp.abs(gx_ref[...] * sc_ref[...] - qi[None, :]).T
 
+        def block_fn(off):
+            a = dt_ref[pl.ds(off, block_d), :]
+            r = rad_ref[pl.ds(off, block_d), :]
+            return jnp.maximum(a - r, 0.0), a + r  # certified lower / upper
 
-def _gather_screen_vec_kernel(ids_ref, q_ref, th_ref, sb_ref, p_ref, sc_ref,
-                              rad_ref, codes_hbm, keep_ref, nd_ref, gx_ref,
-                              sem, *, base_p: float, n: int,
-                              block_c: int, block_d: int):
-    """Mixed-p variant: each query row screened under its own traced p."""
-    tb = q_ref.shape[0]
-    scale_col = sc_ref[...].astype(jnp.float32).T
-    radius_col = rad_ref[...].astype(jnp.float32).T
-
-    def per_query(i, _):
-        keep, nd = _screen_row(
-            ids_ref[i, :], q_ref[i, :].astype(jnp.float32), th_ref[i, 0],
-            sb_ref[i, :], p_ref[i, 0], scale_col, radius_col, codes_hbm,
-            gx_ref, sem,
-            base_p=base_p, n=n, block_c=block_c, block_d=block_d,
-        )
-        keep_ref[i, :] = keep
+        _, alive, nd = _blocked_scan(
+            i, ids_v, sb_ref, th_ref[i, 0], p_of(i), gather_tile, block_fn,
+            base_p=base_p, n=n, d=d, block_c=block_c, block_d=block_d,
+            deflate=1.0 - BOUND_SLACK)
+        keep_ref[i, :] = alive.astype(jnp.int32)
         nd_ref[i, :] = nd
         return 0
 
@@ -760,15 +747,16 @@ def _gather_screen_vec_kernel(ids_ref, q_ref, th_ref, sb_ref, p_ref, sc_ref,
 
 def gather_lp_screen_kernel_call(
     ids: jax.Array,     # (B, C) int32 candidate ids; out-of-range = padding
-    q: jax.Array,       # (B, d) queries, band (permuted) coordinate order
+    q: jax.Array,       # (B, dx) queries, band (permuted) coordinate order
     thresh: jax.Array,  # (B, 1) per-query screen bound (power-sum space;
                         # -inf = row frozen, +inf = keep everything)
     sb: jax.Array,      # (B, C) base-metric power sums (0 = no bound info)
-    scale: jax.Array,   # (1, d) f32 per-coordinate dequant scales
-    radius: jax.Array,  # (1, d) f32 per-coordinate max dequant error
-    codes: jax.Array,   # (n, d) int8 HBM-resident compressed band
+    scale: jax.Array,   # (1, dx) f32 per-coordinate dequant scales
+    radius: jax.Array,  # (dx, 1) f32 per-coordinate max dequant error
+    codes: jax.Array,   # (n, dx) int8 HBM-resident compressed band
     p,
     *,
+    d: int | None = None,
     base_p: float = 1.0,
     block_b: int = 8,
     block_c: int = 128,
@@ -776,72 +764,21 @@ def gather_lp_screen_kernel_call(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0,
-    d % block_d == 0). Returns (keep (B, C) int32 — 1 iff the candidate
+    d % block_d == 0; d = logical width <= dx, default dx — compiled for
+    TPU, dx % 128 == 0). Returns (keep (B, C) int32 — 1 iff the candidate
     survived the screen and its f32 row must be gathered for the exact
     rerank, nd (B, C) int32 band dimensions scanned).
 
     p: Python float, or a pre-padded (B, 1) f32 array (one metric per
     query row — the mixed-p contract in the module preamble). base_p
     (static 1.0 or 2.0) names the metric of `sb` for the entry/suffix
-    bounds. scale/radius ride as (1, d) operands pinned per grid step.
+    bounds. scale/radius are whole-array VMEM operands (fetched once).
     """
-    b, d = q.shape
-    b2, cc = ids.shape
-    n = codes.shape[0]
-    assert b == b2 and b % block_b == 0 and cc % block_c == 0, \
-        (b, b2, cc, block_b, block_c)
-    assert d % block_d == 0, (d, block_d)
-    assert scale.shape == (1, d) and radius.shape == (1, d), \
-        (scale.shape, radius.shape, d)
-
-    common = dict(
-        grid=(b // block_b, cc // block_c),
-        out_specs=(
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-        ),
-        out_shape=(
-            jax.ShapeDtypeStruct((b, cc), jnp.int32),
-            jax.ShapeDtypeStruct((b, cc), jnp.int32),
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_c, d), jnp.int8),
-            pltpu.SemaphoreType.DMA,
-        ],
-        interpret=interpret,
-    )
-    if not is_static_p(p):
-        assert p.shape == (b, 1), (p.shape, b)
-        return pl.pallas_call(
-            functools.partial(
-                _gather_screen_vec_kernel, base_p=base_p, n=n,
-                block_c=block_c, block_d=block_d,
-            ),
-            in_specs=[
-                pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-                pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-                pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-                pl.BlockSpec((1, d), lambda i, j: (0, 0)),
-                pl.BlockSpec((1, d), lambda i, j: (0, 0)),
-                pl.BlockSpec(memory_space=pltpu.ANY),  # codes stay in HBM
-            ],
-            **common,
-        )(ids, q, thresh, sb, p, scale, radius, codes)
-    return pl.pallas_call(
-        functools.partial(
-            _gather_screen_kernel, p=float(p), base_p=base_p, n=n,
-            block_c=block_c, block_d=block_d,
-        ),
-        in_specs=[
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((block_b, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, 1), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_b, block_c), lambda i, j: (i, j)),
-            pl.BlockSpec((1, d), lambda i, j: (0, 0)),
-            pl.BlockSpec((1, d), lambda i, j: (0, 0)),
-            pl.BlockSpec(memory_space=pltpu.ANY),  # codes stay in HBM
-        ],
-        **common,
-    )(ids, q, thresh, sb, scale, radius, codes)
+    dx = q.shape[1]
+    assert scale.shape == (1, dx) and radius.shape == (dx, 1), \
+        (scale.shape, radius.shape, dx)
+    return _blocked_call(
+        _gather_screen_kernel, ids, q, p, thresh, sb, codes, (scale, radius),
+        d=dx if d is None else d, block_b=block_b, block_c=block_c,
+        block_d=block_d, out_dtypes=(jnp.int32, jnp.int32),
+        interpret=interpret, base_p=base_p)

@@ -3,9 +3,15 @@
 Responsibilities:
   * VMEM-aware tile-size selection (the BlockSpec working set must fit VMEM);
   * padding arbitrary (B, N, C) up to tile multiples and slicing the result;
-  * interpret-mode fallback on non-TPU backends (this container is CPU-only,
-    so tests/benches run the kernel bodies in interpret mode; on a real TPU
-    the same code lowers to Mosaic);
+  * operand layout for Mosaic: per-row p and thresholds go in as
+    pre-padded (B, 1) f32 columns the kernels block into SMEM; candidate
+    ids as (B, C) int32, -1-padded; the compiled gather family reads its
+    row source (corpus, band codes) in `kernel_rows` layout — the feature
+    axis zero-padded to a lane multiple once, by the index that owns it —
+    and pads only the queries to match;
+  * interpret-mode fallback on non-TPU backends (tests run on the CPU with
+    the kernel bodies in interpret mode or the jnp reference; on a TPU the
+    same code lowers to Mosaic);
   * `lp_gather_distance` — the single entry point for exact-Lp candidate
     scoring in the query path (verify_candidates, delta scans). On TPU it
     runs the fused gather+distance kernel (rows gathered tile-by-tile in
@@ -77,7 +83,7 @@ def _pad_axis(a: jax.Array, axis: int, to: int, fill: float) -> jax.Array:
 
 
 def _pad_p_col(p: jax.Array, to: int) -> jax.Array:
-    """(B,) per-row p -> pre-padded (to, 1) f32 kernel operand.
+    """(B,) per-row p -> pre-padded (to, 1) f32 kernel operand (SMEM).
 
     Padding rows get p=1.0 — the cheapest family; their outputs are sliced
     off, so any valid p would do.
@@ -86,27 +92,61 @@ def _pad_p_col(p: jax.Array, to: int) -> jax.Array:
     return _pad_axis(p, 0, to, 1.0)[:, None]
 
 
-@functools.partial(
-    jax.jit, static_argnames=("p", "root", "interpret", "block_b", "block_n")
-)
-def _pallas_pairwise_lp_s(
-    q: jax.Array,
-    x: jax.Array,
-    p: float,
-    root: bool = True,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_n: int | None = None,
-) -> jax.Array:
+def _p_rows(p, b: int):
+    """Scalar p stays a Python float; vector p broadcasts to (B,) — a (1,)
+    array means "one p for every row"."""
+    return p if is_static_p(p) else jnp.broadcast_to(p, (b,))
+
+
+def _p_operand(p, bp: int):
+    """Kernel p operand: the static float, or the padded (bp, 1) column."""
+    return p if is_static_p(p) else _pad_p_col(p, bp)
+
+
+def _root_rows(out: jax.Array, p, root: bool) -> jax.Array:
+    """Apply the Lp root to a (B, ...) result under scalar or per-row p."""
+    if not root:
+        return out
+    return lp_root(out, p if is_static_p(p) else p[:, None])
+
+
+def kernel_rows(x: jax.Array) -> jax.Array:
+    """x (n, d) in the row layout the compiled gather-family kernels read.
+
+    Mosaic DMAs whole 128-lane rows, so on a TPU a feature width that is
+    not a lane multiple is zero-padded to one (zero columns are
+    distance-neutral: |0 - 0|^p = 0). Owners of a row source (the index's
+    corpus, the compressed band's codes, the delta buffer) call this once
+    when they place it on the device; the kernel wrappers never pad a row
+    source per call. Off the TPU, or when d is already a lane multiple,
+    x is returned as is (no copy).
+    """
+    if not _on_tpu() or x.shape[-1] % _LANE == 0:
+        return x
+    return _pad_axis(x, x.ndim - 1, _round_up(x.shape[-1], _LANE), 0)
+
+
+def _match_rows(q: jax.Array, x: jax.Array, interpret: bool):
+    """Zero-pad queries (B, d) to the width of a `kernel_rows` row source
+    (n, dx >= d). The compiled kernels need dx % 128 == 0: a row source
+    that is not in `kernel_rows` layout is refused rather than copied."""
+    dx = x.shape[-1]
+    if not interpret and dx % _LANE:
+        raise ValueError(
+            f"compiled gather kernels need a lane-aligned row source, got "
+            f"width {dx}; place it with kernels.ops.kernel_rows first")
+    return _pad_axis(q, 1, dx, 0.0)
+
+
+def _pairwise_impl(q, x, p, root=True, interpret=None, block_b=None,
+                   block_n=None):
     if interpret is None:
         interpret = not _on_tpu()
     b, d = q.shape
     n, _ = x.shape
+    p = _p_rows(p, b)
     tb, tn = _pick_tiles_pairwise(b, n, d)
-    if block_b is not None:
-        tb = block_b
-    if block_n is not None:
-        tn = block_n
+    tb, tn = block_b or tb, block_n or tn
     bp, np_ = _round_up(b, tb), _round_up(n, tn)
     qp = _pad_axis(q, 0, bp, 0.0)
     xp = _pad_axis(x, 0, np_, 0.0)
@@ -115,41 +155,24 @@ def _pallas_pairwise_lp_s(
     # kernel divides at runtime — rooting on the (B, N) result with the
     # barriered lp_root keeps static-p and vector-p wrappers bit-consistent.
     out = _k.pairwise_lp_kernel_call(
-        qp, xp, p, root=False, block_b=tb, block_n=tn, interpret=interpret
-    )[:b, :n]
-    return lp_root(out, p) if root else out
-
-
-@functools.partial(
-    jax.jit, static_argnames=("root", "interpret", "block_b", "block_n")
-)
-def _pallas_pairwise_lp_v(
-    q: jax.Array,
-    x: jax.Array,
-    p: jax.Array,
-    root: bool = True,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_n: int | None = None,
-) -> jax.Array:
-    if interpret is None:
-        interpret = not _on_tpu()
-    b, d = q.shape
-    p = jnp.broadcast_to(p, (b,))  # (1,) = "one p for every row"
-    n, _ = x.shape
-    tb, tn = _pick_tiles_pairwise(b, n, d)
-    if block_b is not None:
-        tb = block_b
-    if block_n is not None:
-        tn = block_n
-    bp, np_ = _round_up(b, tb), _round_up(n, tn)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    xp = _pad_axis(x, 0, np_, 0.0)
-    out = _k.pairwise_lp_kernel_call(
-        qp, xp, _pad_p_col(p, bp), root=False, block_b=tb, block_n=tn,
+        qp, xp, _p_operand(p, bp), root=False, block_b=tb, block_n=tn,
         interpret=interpret,
     )[:b, :n]
-    return lp_root(out, p[:, None]) if root else out
+    return _root_rows(out, p, root)
+
+
+_PW_STATIC = ("root", "interpret", "block_b", "block_n")
+_pallas_pairwise_lp_s = jax.jit(_pairwise_impl,
+                                static_argnames=("p",) + _PW_STATIC)
+_pallas_pairwise_lp_v = jax.jit(_pairwise_impl, static_argnames=_PW_STATIC)
+
+
+def _dispatch(fn_s, fn_v, *args, p, **kw):
+    """Call the static-p jit with float(p), or the traced-p jit with a
+    (B,)/(1,) f32 array — the scalar-vs-vector contract (DESIGN.md §6)."""
+    if is_static_p(p):
+        return fn_s(*args, p=float(p), **kw)
+    return fn_v(*args, p=jnp.atleast_1d(jnp.asarray(p, jnp.float32)), **kw)
 
 
 def pallas_pairwise_lp(
@@ -167,74 +190,35 @@ def pallas_pairwise_lp(
     query row under its own metric (one compiled program for any p mix —
     DESIGN.md §6).
     """
-    if is_static_p(p):
-        return _pallas_pairwise_lp_s(q, x, float(p), root, interpret,
-                                     block_b, block_n)
-    return _pallas_pairwise_lp_v(q, x, jnp.atleast_1d(
-        jnp.asarray(p, jnp.float32)), root, interpret, block_b, block_n)
+    return _dispatch(_pallas_pairwise_lp_s, _pallas_pairwise_lp_v, q, x,
+                     p=p, root=root, interpret=interpret, block_b=block_b,
+                     block_n=block_n)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("p", "root", "interpret", "block_b", "block_c")
-)
-def _pallas_rowwise_lp_s(
-    q: jax.Array,
-    c: jax.Array,
-    p: float,
-    root: bool = True,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-) -> jax.Array:
+def _rowwise_impl(q, c, p, root=True, interpret=None, block_b=None,
+                  block_c=None):
     if interpret is None:
         interpret = not _on_tpu()
     b, d = q.shape
     _, cc, _ = c.shape
+    p = _p_rows(p, b)
     tb, tc = _pick_tiles_rowwise(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
+    tb, tc = block_b or tb, block_c or tc
     bp, cp = _round_up(b, tb), _round_up(cc, tc)
     qp = _pad_axis(q, 0, bp, 0.0)
     cpad = _pad_axis(_pad_axis(c, 1, cp, 0.0), 0, bp, 0.0)
-    # root outside the kernel — see _pallas_pairwise_lp_s for why
+    # root outside the kernel — see _pairwise_impl for why
     out = _k.rowwise_lp_kernel_call(
-        qp, cpad, p, root=False, block_b=tb, block_c=tc, interpret=interpret
-    )[:b, :cc]
-    return lp_root(out, p) if root else out
-
-
-@functools.partial(
-    jax.jit, static_argnames=("root", "interpret", "block_b", "block_c")
-)
-def _pallas_rowwise_lp_v(
-    q: jax.Array,
-    c: jax.Array,
-    p: jax.Array,
-    root: bool = True,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-) -> jax.Array:
-    if interpret is None:
-        interpret = not _on_tpu()
-    b, d = q.shape
-    p = jnp.broadcast_to(p, (b,))  # (1,) = "one p for every row"
-    _, cc, _ = c.shape
-    tb, tc = _pick_tiles_rowwise(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    cpad = _pad_axis(_pad_axis(c, 1, cp, 0.0), 0, bp, 0.0)
-    out = _k.rowwise_lp_kernel_call(
-        qp, cpad, _pad_p_col(p, bp), root=False, block_b=tb, block_c=tc,
+        qp, cpad, _p_operand(p, bp), root=False, block_b=tb, block_c=tc,
         interpret=interpret,
     )[:b, :cc]
-    return lp_root(out, p[:, None]) if root else out
+    return _root_rows(out, p, root)
+
+
+_RW_STATIC = ("root", "interpret", "block_b", "block_c")
+_pallas_rowwise_lp_s = jax.jit(_rowwise_impl,
+                               static_argnames=("p",) + _RW_STATIC)
+_pallas_rowwise_lp_v = jax.jit(_rowwise_impl, static_argnames=_RW_STATIC)
 
 
 def pallas_rowwise_lp(
@@ -252,11 +236,9 @@ def pallas_rowwise_lp(
     query row under its own metric (one compiled program for any p mix —
     DESIGN.md §6).
     """
-    if is_static_p(p):
-        return _pallas_rowwise_lp_s(q, c, float(p), root, interpret,
-                                    block_b, block_c)
-    return _pallas_rowwise_lp_v(q, c, jnp.atleast_1d(
-        jnp.asarray(p, jnp.float32)), root, interpret, block_b, block_c)
+    return _dispatch(_pallas_rowwise_lp_s, _pallas_rowwise_lp_v, q, c,
+                     p=p, root=root, interpret=interpret, block_b=block_b,
+                     block_c=block_c)
 
 
 def lp_pairwise_distance(
@@ -289,9 +271,10 @@ def _pick_tiles_gather(b: int, c: int, d: int) -> tuple[int, int]:
 
     VMEM working set ~ 4*(TB*d + TB*TC + TC*d + TB*TC) bytes: the q tile,
     the ids tile, the (TC, d) gathered-row scratch, and the out tile — X
-    itself stays in HBM, so d no longer multiplies TC*TB. TB stays a
-    multiple of the 8-wide sublane (like the other pickers) so the tile
-    refs lower cleanly on TPU.
+    itself stays in HBM, so d no longer multiplies TC*TB (the row-tile
+    staging buffers add a fixed 2*8*d f32 words). TB stays a multiple of
+    the 8-wide sublane (like the other pickers) so the tile refs lower
+    cleanly on TPU.
     """
     tb = min(8, _round_up(b, 8))
     # tc is a power-of-two multiple of _LANE (128/256/512) so the halving
@@ -304,103 +287,77 @@ def _pick_tiles_gather(b: int, c: int, d: int) -> tuple[int, int]:
     return max(tb, 8), max(tc, _LANE)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("p", "root", "interpret", "block_b", "block_c")
-)
-def _lp_gather_distance_s(
-    q: jax.Array,
-    ids: jax.Array,
-    x: jax.Array,
-    p: float,
-    root: bool = False,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-) -> jax.Array:
+def _gather_operands(q, ids, p, thresh, sb, tb: int, tc: int):
+    """Pad the gather family's per-query operands to (TB, TC) multiples:
+    q rows with zeros, ids with -1 (padding scores +inf), p via
+    `_p_operand`, thresholds with -inf (padding rows die at entry, so the
+    kernel skips their DMA gathers) and base sums with 0."""
+    b, _ = q.shape
+    _, cc = ids.shape
+    bp, cp = _round_up(b, tb), _round_up(cc, tc)
+    ops_ = [
+        _pad_axis(q, 0, bp, 0.0),
+        jnp.pad(ids.astype(jnp.int32), ((0, bp - b), (0, cp - cc)),
+                constant_values=-1),
+        _p_operand(p, bp),
+    ]
+    if thresh is not None:
+        ops_.append(_pad_axis(thresh.astype(jnp.float32), 0, bp,
+                              -jnp.inf)[:, None])
+        ops_.append(_pad_axis(_pad_axis(sb.astype(jnp.float32), 1, cp, 0.0),
+                              0, bp, 0.0))
+    return ops_
+
+
+def gather_rowwise_lp(q, ids, x, p, root: bool = False) -> jax.Array:
+    """XLA gather of the candidate rows, then `rowwise_lp` -> (B, C) f32.
+
+    The plain-XLA sibling of the fused gather kernel, with the same
+    contract: ids (B, C) outside [0, x.shape[0]) are padding and score
+    +inf; q (B, d) and x share one width. It materializes the gathered
+    (B, C, d) block. `lp_gather_distance` runs it off the TPU, and the
+    bulk graph builder scores its candidate blocks with it on every
+    backend (DESIGN.md §7).
+    """
     n = x.shape[0]
+    p = _p_rows(p, q.shape[0])
+    valid = (ids >= 0) & (ids < n)
+    dd = rowwise_lp(q, x[jnp.clip(ids, 0, n - 1)], p, root=False)
+    return _root_rows(jnp.where(valid, dd, jnp.inf), p, root)
+
+
+def _gather_impl(q, ids, x, p, root=False, interpret=None, block_b=None,
+                 block_c=None):
+    n = x.shape[0]
+    b, _ = q.shape
+    p = _p_rows(p, b)
     if ids.ndim == 1:
         valid = (ids >= 0) & (ids < n)
         xs = x[jnp.clip(ids, 0, n - 1)]  # gathered once, shared by all rows
-        d = pallas_pairwise_lp(q, xs, p, root=False, interpret=interpret)
-        d = jnp.where(valid[None, :], d, jnp.inf)
-        return lp_root(d, p) if root else d
+        dd = pallas_pairwise_lp(_pad_axis(q, 1, x.shape[1], 0.0), xs, p,
+                                root=False, interpret=interpret)
+        return _root_rows(jnp.where(valid[None, :], dd, jnp.inf), p, root)
     if interpret is None and not _on_tpu():
-        valid = (ids >= 0) & (ids < n)
-        d = rowwise_lp(q, x[jnp.clip(ids, 0, n - 1)], p, root=False)
-        d = jnp.where(valid, d, jnp.inf)
-        return lp_root(d, p) if root else d
-    if interpret is None:
-        interpret = False
-    b, d = q.shape
+        return gather_rowwise_lp(q, ids, x, p, root)
+    interpret = bool(interpret)
+    q = _match_rows(q, x, interpret)
     _, cc = ids.shape
-    tb, tc = _pick_tiles_gather(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    # pad ids with -1 (sentinel) so padded slots score inf, not garbage
-    ip = jnp.pad(
-        ids.astype(jnp.int32),
-        ((0, bp - b), (0, cp - cc)),
-        constant_values=-1,
-    )
+    tb, tc = _pick_tiles_gather(b, cc, q.shape[1])
+    tb, tc = block_b or tb, block_c or tc
+    qp, ip, pp = _gather_operands(q, ids, p, None, None, tb, tc)
     # apply the root *outside* the kernel on the (B, C) result: for root=True
     # callers this keeps the kernel body identical across root modes.
     out = _k.gather_lp_kernel_call(
-        ip, qp, x, p, root=False, block_b=tb, block_c=tc, interpret=interpret
-    )[:b, :cc]
-    return lp_root(out, p) if root else out
-
-
-@functools.partial(
-    jax.jit, static_argnames=("root", "interpret", "block_b", "block_c")
-)
-def _lp_gather_distance_v(
-    q: jax.Array,
-    ids: jax.Array,
-    x: jax.Array,
-    p: jax.Array,    # (B,) per-query metric
-    root: bool = False,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-) -> jax.Array:
-    n = x.shape[0]
-    p = jnp.broadcast_to(p, (q.shape[0],))  # (1,) = "one p for every row"
-    if ids.ndim == 1:
-        valid = (ids >= 0) & (ids < n)
-        xs = x[jnp.clip(ids, 0, n - 1)]  # gathered once, shared by all rows
-        d = pallas_pairwise_lp(q, xs, p, root=False, interpret=interpret)
-        d = jnp.where(valid[None, :], d, jnp.inf)
-        return lp_root(d, p[:, None]) if root else d
-    if interpret is None and not _on_tpu():
-        valid = (ids >= 0) & (ids < n)
-        d = rowwise_lp(q, x[jnp.clip(ids, 0, n - 1)], p, root=False)
-        d = jnp.where(valid, d, jnp.inf)
-        return lp_root(d, p[:, None]) if root else d
-    if interpret is None:
-        interpret = False
-    b, d = q.shape
-    _, cc = ids.shape
-    tb, tc = _pick_tiles_gather(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    ip = jnp.pad(
-        ids.astype(jnp.int32),
-        ((0, bp - b), (0, cp - cc)),
-        constant_values=-1,
-    )
-    out = _k.gather_lp_kernel_call(
-        ip, qp, x, _pad_p_col(p, bp), root=False, block_b=tb, block_c=tc,
+        ip, qp, x, pp, root=False, block_b=tb, block_c=tc,
         interpret=interpret,
     )[:b, :cc]
-    return lp_root(out, p[:, None]) if root else out
+    return _root_rows(out, p, root)
+
+
+_G_STATIC = ("root", "interpret", "block_b", "block_c")
+_lp_gather_distance_s = jax.jit(_gather_impl,
+                                static_argnames=("p",) + _G_STATIC)
+_lp_gather_distance_v = jax.jit(_gather_impl, static_argnames=_G_STATIC)
 
 
 def pick_abandon_block_d(d: int) -> int:
@@ -420,10 +377,12 @@ def pick_abandon_block_d(d: int) -> int:
 
 
 def _pick_tiles_abandon(b: int, c: int, d: int) -> tuple[int, int]:
-    """Choose (TB, TC) for the abandon kernel.
+    """Choose (TB, TC) for the abandon and screen kernels.
 
     Like `_pick_tiles_gather` plus the transposed (d, TC) diff tile the
-    blocked scan keeps live: ~ 4*(TB*d + 2*TC*d + 3*TB*TC) bytes.
+    blocked scan keeps live: ~ 4*(TB*d + 2*TC*d + 3*TB*TC) bytes. The
+    screen's gathered int8 band rows are widened into the same f32
+    (TC, d) scratch, so one picker serves both.
     """
     tb = min(8, _round_up(b, 8))
     tc = _LANE
@@ -434,109 +393,39 @@ def _pick_tiles_abandon(b: int, c: int, d: int) -> tuple[int, int]:
     return max(tb, 8), max(tc, _LANE)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("p", "base_p", "root", "interpret", "block_b",
-                     "block_c", "block_d"),
-)
-def _lp_gather_abandon_s(
-    q: jax.Array,
-    ids: jax.Array,
-    x: jax.Array,
-    thresh: jax.Array,
-    sb: jax.Array,
-    p: float,
-    base_p: float,
-    root: bool = False,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-    block_d: int | None = None,
-):
+def _abandon_impl(q, ids, x, thresh, sb, p, base_p, root=False,
+                  interpret=None, block_b=None, block_c=None, block_d=None):
     b, d = q.shape
+    p = _p_rows(p, b)
     bd = block_d or pick_abandon_block_d(d)
     if interpret is None and not _on_tpu():
         from repro.kernels.ref import gather_lp_abandon_ref
 
         out, nd = gather_lp_abandon_ref(q, ids, x, thresh, sb, p, base_p, bd)
-        return (lp_root(out, p) if root else out), nd
-    if interpret is None:
-        interpret = False
+        return _root_rows(out, p, root), nd
+    interpret = bool(interpret)
+    q = _match_rows(q, x, interpret)
     _, cc = ids.shape
-    tb, tc = _pick_tiles_abandon(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    ip = jnp.pad(ids.astype(jnp.int32), ((0, bp - b), (0, cp - cc)),
-                 constant_values=-1)
-    # padding rows get threshold -inf: every candidate dies at entry, so
-    # the kernel skips their DMA gathers entirely
-    tp = _pad_axis(thresh.astype(jnp.float32), 0, bp, -jnp.inf)[:, None]
-    sp = _pad_axis(_pad_axis(sb.astype(jnp.float32), 1, cp, 0.0), 0, bp, 0.0)
+    tb, tc = _pick_tiles_abandon(b, cc, q.shape[1])
+    tb, tc = block_b or tb, block_c or tc
+    qp, ip, pp, tp, sp = _gather_operands(q, ids, p, thresh, sb, tb, tc)
     out, nd = _k.gather_lp_abandon_kernel_call(
-        ip, qp, tp, sp, x, p, base_p=base_p, block_b=tb, block_c=tc,
+        ip, qp, tp, sp, x, pp, d=d, base_p=base_p, block_b=tb, block_c=tc,
         block_d=bd, interpret=interpret,
     )
-    out, nd = out[:b, :cc], nd[:b, :cc]
-    return (lp_root(out, p) if root else out), nd
+    return _root_rows(out[:b, :cc], p, root), nd[:b, :cc]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("base_p", "root", "interpret", "block_b", "block_c",
-                     "block_d"),
-)
-def _lp_gather_abandon_v(
-    q: jax.Array,
-    ids: jax.Array,
-    x: jax.Array,
-    thresh: jax.Array,
-    sb: jax.Array,
-    p: jax.Array,    # (B,) per-query metric
-    base_p: float,
-    root: bool = False,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-    block_d: int | None = None,
-):
-    b, d = q.shape
-    p = jnp.broadcast_to(p, (b,))  # (1,) = "one p for every row"
-    bd = block_d or pick_abandon_block_d(d)
-    if interpret is None and not _on_tpu():
-        from repro.kernels.ref import gather_lp_abandon_ref
-
-        out, nd = gather_lp_abandon_ref(q, ids, x, thresh, sb, p, base_p, bd)
-        return (lp_root(out, p[:, None]) if root else out), nd
-    if interpret is None:
-        interpret = False
-    _, cc = ids.shape
-    tb, tc = _pick_tiles_abandon(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    ip = jnp.pad(ids.astype(jnp.int32), ((0, bp - b), (0, cp - cc)),
-                 constant_values=-1)
-    tp = _pad_axis(thresh.astype(jnp.float32), 0, bp, -jnp.inf)[:, None]
-    sp = _pad_axis(_pad_axis(sb.astype(jnp.float32), 1, cp, 0.0), 0, bp, 0.0)
-    out, nd = _k.gather_lp_abandon_kernel_call(
-        ip, qp, tp, sp, x, _pad_p_col(p, bp), base_p=base_p, block_b=tb,
-        block_c=tc, block_d=bd, interpret=interpret,
-    )
-    out, nd = out[:b, :cc], nd[:b, :cc]
-    return (lp_root(out, p[:, None]) if root else out), nd
+_A_STATIC = ("base_p", "root", "interpret", "block_b", "block_c", "block_d")
+_lp_gather_abandon_s = jax.jit(_abandon_impl,
+                               static_argnames=("p",) + _A_STATIC)
+_lp_gather_abandon_v = jax.jit(_abandon_impl, static_argnames=_A_STATIC)
 
 
 def lp_gather_abandon(
     q: jax.Array,       # (B, d) f32 queries
     ids: jax.Array,     # (B, C) int32 candidate ids; out-of-range = padding
-    x: jax.Array,       # (n, d) f32 dataset
+    x: jax.Array,       # (n, d) f32 dataset; `kernel_rows` layout on TPU
     thresh: jax.Array,  # (B,) per-query abandon bound (power-sum space;
                         # +inf = no abandonment, -inf = skip the whole row)
     sb: jax.Array,      # (B, C) base-metric power sums of the candidates
@@ -565,137 +454,49 @@ def lp_gather_abandon(
     reference (kernels/ref.py — computes-then-masks, same `nd`
     accounting) off TPU, `interpret=True` for CPU kernel-parity tests.
     """
-    if is_static_p(p):
-        return _lp_gather_abandon_s(q, ids, x, thresh, sb, float(p),
-                                    float(base_p), root, interpret,
-                                    block_b, block_c, block_d)
-    return _lp_gather_abandon_v(
-        q, ids, x, thresh, sb,
-        jnp.atleast_1d(jnp.asarray(p, jnp.float32)), float(base_p), root,
-        interpret, block_b, block_c, block_d)
+    return _dispatch(_lp_gather_abandon_s, _lp_gather_abandon_v,
+                     q, ids, x, thresh, sb, p=p, base_p=float(base_p),
+                     root=root, interpret=interpret, block_b=block_b,
+                     block_c=block_c, block_d=block_d)
 
 
-def _pick_tiles_screen(b: int, c: int, d: int) -> tuple[int, int]:
-    """Choose (TB, TC) for the compressed-band screen kernel.
-
-    Like `_pick_tiles_abandon` but the gathered-rows scratch is int8
-    (1 byte/dim) while the dequantized |q - x̂| tile stays f32:
-    ~ tc*d + 4*(tb*d + tc*d + 3*tb*tc) bytes.
-    """
-    tb = min(8, _round_up(b, 8))
-    tc = _LANE
-    while tc < min(512, c):
-        tc *= 2
-    while tc > _LANE and \
-            tc * d + 4 * (tb * d + tc * d + 3 * tb * tc) > _VMEM_BUDGET:
-        tc //= 2
-    return max(tb, 8), max(tc, _LANE)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=("p", "base_p", "interpret", "block_b", "block_c",
-                     "block_d"),
-)
-def _lp_gather_screen_s(
-    q: jax.Array,
-    ids: jax.Array,
-    codes: jax.Array,
-    scale: jax.Array,
-    radius: jax.Array,
-    thresh: jax.Array,
-    sb: jax.Array,
-    p: float,
-    base_p: float,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-    block_d: int | None = None,
-):
+def _screen_impl(q, ids, codes, scale, radius, thresh, sb, p, base_p,
+                 interpret=None, block_b=None, block_c=None, block_d=None):
     b, d = q.shape
+    p = _p_rows(p, b)
     bd = block_d or pick_abandon_block_d(d)
     if interpret is None and not _on_tpu():
         from repro.kernels.ref import gather_lp_screen_ref
 
         return gather_lp_screen_ref(q, ids, codes, scale, radius, thresh,
                                     sb, p, base_p, bd)
-    if interpret is None:
-        interpret = False
+    interpret = bool(interpret)
+    q = _match_rows(q, codes, interpret)
+    dx = q.shape[1]
     _, cc = ids.shape
-    tb, tc = _pick_tiles_screen(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    ip = jnp.pad(ids.astype(jnp.int32), ((0, bp - b), (0, cp - cc)),
-                 constant_values=-1)
-    # padding rows get threshold -inf: every candidate dies at entry, so
-    # the kernel skips their DMA gathers entirely
-    tp = _pad_axis(thresh.astype(jnp.float32), 0, bp, -jnp.inf)[:, None]
-    sp = _pad_axis(_pad_axis(sb.astype(jnp.float32), 1, cp, 0.0), 0, bp, 0.0)
+    tb, tc = _pick_tiles_abandon(b, cc, dx)
+    tb, tc = block_b or tb, block_c or tc
+    qp, ip, pp, tp, sp = _gather_operands(q, ids, p, thresh, sb, tb, tc)
+    # the (d,) scale and radius vectors are padded here: O(d), not O(n d)
     keep, nd = _k.gather_lp_screen_kernel_call(
-        ip, qp, tp, sp, scale.reshape(1, d), radius.reshape(1, d), codes,
-        p, base_p=base_p, block_b=tb, block_c=tc, block_d=bd,
+        ip, qp, tp, sp, _pad_axis(scale, 0, dx, 0.0)[None, :],
+        _pad_axis(radius, 0, dx, 0.0)[:, None], codes, pp, d=d,
+        base_p=base_p, block_b=tb, block_c=tc, block_d=bd,
         interpret=interpret,
     )
     return keep[:b, :cc].astype(bool), nd[:b, :cc]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("base_p", "interpret", "block_b", "block_c", "block_d"),
-)
-def _lp_gather_screen_v(
-    q: jax.Array,
-    ids: jax.Array,
-    codes: jax.Array,
-    scale: jax.Array,
-    radius: jax.Array,
-    thresh: jax.Array,
-    sb: jax.Array,
-    p: jax.Array,    # (B,) per-query metric
-    base_p: float,
-    interpret: bool | None = None,
-    block_b: int | None = None,
-    block_c: int | None = None,
-    block_d: int | None = None,
-):
-    b, d = q.shape
-    p = jnp.broadcast_to(p, (b,))  # (1,) = "one p for every row"
-    bd = block_d or pick_abandon_block_d(d)
-    if interpret is None and not _on_tpu():
-        from repro.kernels.ref import gather_lp_screen_ref
-
-        return gather_lp_screen_ref(q, ids, codes, scale, radius, thresh,
-                                    sb, p, base_p, bd)
-    if interpret is None:
-        interpret = False
-    _, cc = ids.shape
-    tb, tc = _pick_tiles_screen(b, cc, d)
-    if block_b is not None:
-        tb = block_b
-    if block_c is not None:
-        tc = block_c
-    bp, cp = _round_up(b, tb), _round_up(cc, tc)
-    qp = _pad_axis(q, 0, bp, 0.0)
-    ip = jnp.pad(ids.astype(jnp.int32), ((0, bp - b), (0, cp - cc)),
-                 constant_values=-1)
-    tp = _pad_axis(thresh.astype(jnp.float32), 0, bp, -jnp.inf)[:, None]
-    sp = _pad_axis(_pad_axis(sb.astype(jnp.float32), 1, cp, 0.0), 0, bp, 0.0)
-    keep, nd = _k.gather_lp_screen_kernel_call(
-        ip, qp, tp, sp, scale.reshape(1, d), radius.reshape(1, d), codes,
-        _pad_p_col(p, bp), base_p=base_p, block_b=tb, block_c=tc,
-        block_d=bd, interpret=interpret,
-    )
-    return keep[:b, :cc].astype(bool), nd[:b, :cc]
+_S_STATIC = ("base_p", "interpret", "block_b", "block_c", "block_d")
+_lp_gather_screen_s = jax.jit(_screen_impl, static_argnames=("p",) + _S_STATIC)
+_lp_gather_screen_v = jax.jit(_screen_impl, static_argnames=_S_STATIC)
 
 
 def lp_gather_screen(
     q: jax.Array,       # (B, d) f32 queries, band (permuted) coord order
     ids: jax.Array,     # (B, C) int32 candidate ids; out-of-range = padding
-    codes: jax.Array,   # (n, d) int8 compressed band (index/compressed.py)
+    codes: jax.Array,   # (n, d) int8 band codes (index/compressed.py),
+                        # `kernel_rows` layout on TPU
     scale: jax.Array,   # (d,) f32 per-coordinate dequant scales
     radius: jax.Array,  # (d,) f32 per-coordinate max dequant error
     thresh: jax.Array,  # (B,) per-query screen bound (power-sum space;
@@ -725,21 +526,17 @@ def lp_gather_screen(
     Pallas kernel on TPU, the blocked jnp reference (kernels/ref.py) off
     TPU, `interpret=True` for CPU kernel-parity tests.
     """
-    if is_static_p(p):
-        return _lp_gather_screen_s(q, ids, codes, scale, radius, thresh,
-                                   sb, float(p), float(base_p), interpret,
-                                   block_b, block_c, block_d)
-    return _lp_gather_screen_v(
-        q, ids, codes, scale, radius, thresh, sb,
-        jnp.atleast_1d(jnp.asarray(p, jnp.float32)), float(base_p),
-        interpret, block_b, block_c, block_d)
+    return _dispatch(_lp_gather_screen_s, _lp_gather_screen_v,
+                     q, ids, codes, scale, radius, thresh, sb, p=p,
+                     base_p=float(base_p), interpret=interpret,
+                     block_b=block_b, block_c=block_c, block_d=block_d)
 
 
 def lp_gather_distance(
     q: jax.Array,    # (B, d) f32 queries
     ids: jax.Array,  # (B, C) int32 candidate ids; anything outside [0, n) is
                      # padding (-1 from merges, n from beam sentinels)
-    x: jax.Array,    # (n, d) f32 dataset
+    x: jax.Array,    # (n, d) f32 dataset; `kernel_rows` layout on TPU
     p,
     root: bool = False,
     interpret: bool | None = None,
@@ -774,9 +571,6 @@ def lp_gather_distance(
     once-gathered (C, d) block — no per-query re-gather, and p=2 keeps
     its MXU matmul — instead of broadcasting the id row B times.
     """
-    if is_static_p(p):
-        return _lp_gather_distance_s(q, ids, x, float(p), root, interpret,
-                                     block_b, block_c)
-    return _lp_gather_distance_v(
-        q, ids, x, jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
-        root, interpret, block_b, block_c)
+    return _dispatch(_lp_gather_distance_s, _lp_gather_distance_v,
+                     q, ids, x, p=p, root=root, interpret=interpret,
+                     block_b=block_b, block_c=block_c)

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs.base import get_arch
 from repro.dist.sharding import Runtime, set_mesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_local_mesh
 
 
@@ -53,7 +54,7 @@ def serve_retrieval(args) -> int:
     from repro.core.uhnsw import UHNSWParams
     from repro.index.persist import DurableIndex, latest_durable_snapshot
     from repro.index.sharded import ShardedUHNSW
-    from repro.retrieval.engine import FaultInjector
+    from repro.retrieval.engine import ENGINE_FAILED, FaultInjector
     from repro.retrieval.service import QueryRequest, UniversalVectorService
 
     # chaos rehearsal (DESIGN.md §9, §11): a seeded injector at the
@@ -181,6 +182,14 @@ def serve_retrieval(args) -> int:
                   f"{pb['dim_frac_w'] / pb['n_p'] if pb['n_p'] else 1.0:.2f}"
                   f" f32-rows="
                   f"{pb['f32_rows_w'] / pb['n_p'] if pb['n_p'] else 1.0:.2f}")
+    # without an injector every FAILED request is a real device or
+    # recovery fault: the run must not report success
+    engine_state = service.engine.state
+    if injector is None and (st["failed"] or engine_state == ENGINE_FAILED):
+        print(f"serve: {st['failed']} request(s) FAILED, engine "
+              f"{engine_state}, with no fault injector configured",
+              file=sys.stderr)
+        return 1
     return 0
 
 
@@ -225,6 +234,7 @@ def main(argv=None) -> int:
                          "band (DESIGN.md §10): bitwise-identical results, "
                          "f32 row gathers only for screen survivors")
     args = ap.parse_args(argv)
+    enable_compile_cache()
     return serve_retrieval(args) if args.retrieval else serve_lm(args)
 
 

@@ -238,3 +238,51 @@ def test_gather_explicit_tile_override():
     b = lp_gather_distance(q, ids, x, 0.8, interpret=True,
                            block_b=8, block_c=256)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-6)
+
+
+@pytest.mark.parametrize("p", [0.8, 2.0, "vector"])
+def test_lane_padded_row_source_matches_logical(p, monkeypatch):
+    """A row source in `kernel_rows` layout (d=96 zero-padded to 128, as
+    the index places it on a TPU) scores like the logical rows: the
+    gather, abandon and screen kernels see only distance-neutral zero
+    columns, and the blocked scans stop at the logical d."""
+    from repro.index.compressed import build_band
+    from repro.kernels import ops
+
+    q, ids, x, ids_np = _gather_case(5, b=8, c=150, n=300, d=96)
+    if p == "vector":
+        p = jnp.asarray([0.5, 0.8, 1.0, 1.25, 1.5, 2.0, 0.8, 2.0],
+                        jnp.float32)
+    band = build_band(x)
+    with monkeypatch.context() as m:
+        m.setattr(ops, "_on_tpu", lambda: True)
+        x_rows, codes_rows = ops.kernel_rows(x), ops.kernel_rows(band.codes)
+    assert x_rows.shape == codes_rows.shape == (300, 128)
+
+    def same(a, b):
+        a, b = np.asarray(a), np.asarray(b)
+        np.testing.assert_array_equal(np.isinf(a), np.isinf(b))
+        np.testing.assert_allclose(a[np.isfinite(a)], b[np.isfinite(b)],
+                                   rtol=1e-6)
+
+    same(lp_gather_distance(q, ids, x_rows, p, interpret=True),
+         lp_gather_distance(q, ids, x, p, interpret=True))
+    full = lp_gather_distance(q, ids, x, p, interpret=True)
+    thr = jnp.asarray(np.nanmedian(
+        np.where(np.isfinite(full), full, np.nan), axis=1))
+    sb = jnp.zeros(ids.shape, jnp.float32)
+    got, nd = ops.lp_gather_abandon(q, ids, x_rows, thr, sb, p,
+                                    interpret=True)
+    want, nd_want = ops.lp_gather_abandon(q, ids, x, thr, sb, p,
+                                          interpret=True)
+    same(got, want)
+    np.testing.assert_array_equal(np.asarray(nd), np.asarray(nd_want))
+    assert int(np.asarray(nd).max()) == 96
+    qp = jnp.take(q, band.perm, axis=1)
+    keep, nd8 = ops.lp_gather_screen(qp, ids, codes_rows, band.scale,
+                                     band.radius, thr, sb, p, interpret=True)
+    keep_w, nd8_w = ops.lp_gather_screen(qp, ids, band.codes, band.scale,
+                                         band.radius, thr, sb, p,
+                                         interpret=True)
+    np.testing.assert_array_equal(np.asarray(keep), np.asarray(keep_w))
+    np.testing.assert_array_equal(np.asarray(nd8), np.asarray(nd8_w))
