@@ -413,8 +413,7 @@ def run_faulted(quick: bool = False):
         # -- clean pass (injector detached, index fully healthy) ---------
         eng.fault_injector = None
         base = dict(service.stats)
-        service.stats["latency_ms"].clear()      # per-pass p50 windows
-        service.stats["latency_records"].clear()
+        service.stats["latency_records"].clear()  # per-pass p50 windows
         clean_outs, clean_dt = serve_all(service, streams(1000))
         clean_lat = service.latency_summary()
         n_served_clean = sum(len(o) for o in clean_outs)
@@ -429,7 +428,6 @@ def run_faulted(quick: bool = False):
         ctr0 = {key: int(service.stats[key])
                 for key in ("poison_detected", "seg_quarantined",
                             "seg_recovered")}
-        service.stats["latency_ms"].clear()
         service.stats["latency_records"].clear()
         fault_dt = 0.0
         outs = []

@@ -140,6 +140,12 @@ class CandidateSet(NamedTuple):
     coverage_frac: float = 1.0  # exact served fraction of the corpus
                                 # under the alive mask these candidates
                                 # were generated with (host-side float)
+    # beam-loop occupancy: one entry per segment lane of a row, the trip
+    # count of the batched level-0 loop that searched it (its largest
+    # lane `hops`). A batched while_loop runs every lane until the slowest
+    # finishes, so a row's lanes occupy hops_max.sum() lane-trips of which
+    # `hops` did work.
+    hops_max: jax.Array | int = 0
 
 
 class SearchStats(NamedTuple):
@@ -191,6 +197,8 @@ class SearchStats(NamedTuple):
     poisoned: jax.Array | float = 0.0  # (B,) 1.0 where the query-time
         # NaN/inf guard masked non-finite gathered distances (the engine
         # bisects this back to a segment and quarantines it)
+    hops_max: jax.Array | int = 0  # CandidateSet.hops_max, carried
+        # through stage B (0 where no staged candidates fed the stats)
 
     def phase_n_b(self):
         """(probe, spill) N_b split with the None default resolved."""
@@ -938,7 +946,8 @@ class UHNSW:
             expand_width=min(prm.expand_width, ef),
         )
         return CandidateSet(ids=cand_ids, base_dists=cand_dists, n_b=n_b,
-                            hops=hops, base_p=base_p)
+                            hops=hops, base_p=base_p,
+                            hops_max=jnp.max(hops, keepdims=True))
 
     def search_stage_finish(self, Q, cands: CandidateSet, p, k: int):
         """Stage 2 of 2: verification (or the base-metric skip) over a
@@ -964,7 +973,8 @@ class UHNSW:
                 base_p=base_p, hops=hops,
                 n_dim_frac=jnp.ones(n_b.shape, jnp.float32),
                 n_f32_rows_frac=jnp.ones(n_b.shape, jnp.float32),
-                n_band_frac=jnp.zeros(n_b.shape, jnp.float32))
+                n_band_frac=jnp.zeros(n_b.shape, jnp.float32),
+                hops_max=cands.hops_max)
         kappa = prm.kappa or max(k // 2, 1)
         p_arg = float(p) if metrics.is_static_p(p) else p
         ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
@@ -983,7 +993,8 @@ class UHNSW:
                                        base_p=base_p, hops=hops,
                                        n_dim_frac=frac,
                                        n_f32_rows_frac=f32f,
-                                       n_band_frac=bandf)
+                                       n_band_frac=bandf,
+                                       hops_max=cands.hops_max)
 
     def _search_scalar(self, Q, p: float, k: int):
         _, base_p = self.base_graph_for(p)

@@ -203,7 +203,10 @@ def segmented_knn_search(
 
     Returns (gids (B, t) int32 global ids (-1 past the end of real data),
     dists (B, t) base-metric root-free distances, n_b (B,), hops (B,),
-    poisoned (B,) bool).
+    poisoned (B,) bool, hops_max ()). `hops` sums each row's level-0 trips
+    over its segment lanes; `hops_max`, the largest trip count of any
+    (segment, row) lane, is the trip count of the one batched loop that
+    runs them all — every lane is held for that many trips.
     """
     n_pad = arrays.n
     base_p = arrays.metric_p
@@ -247,7 +250,7 @@ def segmented_knn_search(
     d = jnp.moveaxis(d, 0, 1).reshape(b, -1)
     sd, si = jax.lax.sort((d, g), num_keys=1)
     return (si[:, :t], sd[:, :t], nb.sum(axis=0), hops.sum(axis=0),
-            pois.any(axis=0))
+            pois.any(axis=0), hops.max())
 
 
 @functools.partial(jax.jit, static_argnames=("t",))
@@ -524,13 +527,14 @@ class ShardedUHNSW:
         alive_list = (self._alive_segments() if alive is None
                       else sorted(int(i) for i in alive))
         (cand_ids, cand_dists, n_b, hops, n_b_probe, n_b_spill,
-         n_cand_spill, poisoned) = self._segment_candidates(
+         n_cand_spill, poisoned, hops_max) = self._segment_candidates(
             arrays, Q, k=k, alive=alive_list)
         return CandidateSet(ids=cand_ids, base_dists=cand_dists, n_b=n_b,
                             hops=hops, base_p=base_p, n_b_probe=n_b_probe,
                             n_b_spill=n_b_spill, n_cand_spill=n_cand_spill,
                             poisoned=poisoned,
-                            coverage_frac=self.coverage_frac(alive_list))
+                            coverage_frac=self.coverage_frac(alive_list),
+                            hops_max=hops_max)
 
     def search_stage_finish(self, Q, cands: CandidateSet, p, k: int):
         """Stage 2 of 2: verification (or base-metric skip) + delta merge.
@@ -571,7 +575,8 @@ class ShardedUHNSW:
             return self._merge_delta(Q, p, k, ids, dists, n_p, iters, n_b,
                                      hops, base_p, frac, f32f, bandf,
                                      phases, coverage=cands.coverage_frac,
-                                     poisoned=cands.poisoned)
+                                     poisoned=cands.poisoned,
+                                     hops_max=cands.hops_max)
         # vector p over one homogeneous base: the traced-p program + the
         # per-row base-metric skip mask, exactly as _search_mixed runs it
         ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
@@ -589,7 +594,8 @@ class ShardedUHNSW:
         return self._merge_delta(Q, p_arr, k, ids, dists, n_p, iters, n_b,
                                  hops, base_p, frac, f32f, bandf, phases,
                                  coverage=cands.coverage_frac,
-                                 poisoned=cands.poisoned)
+                                 poisoned=cands.poisoned,
+                                 hops_max=cands.hops_max)
 
     def _phase_split(self, cands: CandidateSet, n_p):
         """Per-phase (probe, spill) N_b/N_p attribution (DESIGN.md §3).
@@ -669,10 +675,12 @@ class ShardedUHNSW:
         """Policy-dispatched cross-segment candidate generation.
 
         Returns (gids (B, t), dists (B, t), n_b, hops, n_b_probe,
-        n_b_spill, n_cand_spill, poisoned) — the middle three feed the
-        per-phase stats split (DESIGN.md §3); threshold-free work is
-        "probe", work under an inherited bound is "spill". `poisoned` is
-        the per-row NaN/inf-guard flag (DESIGN.md §11).
+        n_b_spill, n_cand_spill, poisoned, hops_max) — the middle three
+        feed the per-phase stats split (DESIGN.md §3); threshold-free work
+        is "probe", work under an inherited bound is "spill". `poisoned` is
+        the per-row NaN/inf-guard flag (DESIGN.md §11). `hops_max` has one
+        entry per searched segment: the trip count of the beam program
+        that searched it (`CandidateSet.hops_max`).
 
         `alive` (sorted segment indices; None = all) restricts the search
         to a subset: every derived quantity — candidate width t, the
@@ -707,20 +715,21 @@ class ShardedUHNSW:
                 m = np.zeros(s_total, dtype=bool)
                 m[alive] = True
                 mask = jnp.asarray(m)
-            gids, dists, n_b, hops, pois = segmented_knn_search(
+            gids, dists, n_b, hops, pois, h_max = segmented_knn_search(
                 arrays, self.segments.X, self.segments.node_ids, Q,
                 ef=ef, t=t, max_hops=prm.max_hops, expand_width=width,
                 alive=mask,
             )
             zero = jnp.zeros_like(n_b)
-            return gids, dists, n_b, hops, n_b, zero, zero, pois
+            return (gids, dists, n_b, hops, n_b, zero, zero, pois,
+                    jnp.full((s,), h_max))
         rank = sp.resolve_thresh_rank(t, s, k)
         base_p = arrays.metric_p
         alive_key = None if all_alive else tuple(alive)
         if sp.policy == "two_phase":
             (arr_a, x_a, ni_a), (arr_b, x_b, ni_b) = self._phase_stacks(
                 base_p, probe, alive_key)
-            g_a, d_a, nb_a, hops_a, pois_a = segmented_knn_search(
+            g_a, d_a, nb_a, hops_a, pois_a, hmax_a = segmented_knn_search(
                 arr_a, x_a, ni_a, Q, ef=ef, t=t, max_hops=prm.max_hops,
                 expand_width=width,
             )
@@ -735,27 +744,31 @@ class ShardedUHNSW:
             # on ef=t builds (ef*ef_shrink < t there).
             ef_b = max(k or 1, rank, int(round(ef * sp.ef_shrink)))
             t_b = min(t, ef_b)
-            g_b, d_b, nb_b, hops_b, pois_b = segmented_knn_search(
+            g_b, d_b, nb_b, hops_b, pois_b, hmax_b = segmented_knn_search(
                 arr_b, x_b, ni_b, Q, ef=ef_b, t=t_b, max_hops=prm.max_hops,
                 expand_width=min(width, ef_b), thresh=thresh,
             )
             gids, dists, flags = merge_phase_lists(g_a, d_a, g_b, d_b, t)
             n_cand_spill = ((flags == 1) & (gids >= 0)).sum(axis=1)
+            hops_max = jnp.concatenate([jnp.full((probe,), hmax_a),
+                                        jnp.full((s - probe,), hmax_b)])
             return (gids, dists, nb_a + nb_b, hops_a + hops_b,
                     nb_a, nb_b, n_cand_spill.astype(jnp.int32),
-                    pois_a | pois_b)
+                    pois_a | pois_b, hops_max)
         # round_robin: single-phase cascade — every turn inherits the
         # running merged rank-r best of all earlier turns as its bound
         order = [i for i in self._probe_order() if i in set(alive)]
         gids = dists = flags = pois = None
         nb_probe = nb_spill = hops = None
+        hops_max = []
         for turn, i in enumerate(order):
             arr_i, x_i, ni_i = self._segment_stack(base_p, i)
             thresh = dists[:, rank - 1] if turn else None
-            g_i, d_i, nb_i, hops_i, pois_i = segmented_knn_search(
+            g_i, d_i, nb_i, hops_i, pois_i, hmax_i = segmented_knn_search(
                 arr_i, x_i, ni_i, Q, ef=ef, t=t, max_hops=prm.max_hops,
                 expand_width=width, thresh=thresh,
             )
+            hops_max.append(hmax_i)
             if turn == 0:
                 gids, dists, pois = g_i, d_i, pois_i
                 flags = jnp.zeros_like(g_i)
@@ -768,7 +781,8 @@ class ShardedUHNSW:
                 pois = pois | pois_i
         n_cand_spill = ((flags == 1) & (gids >= 0)).sum(axis=1)
         return (gids, dists, nb_probe + nb_spill, hops,
-                nb_probe, nb_spill, n_cand_spill.astype(jnp.int32), pois)
+                nb_probe, nb_spill, n_cand_spill.astype(jnp.int32), pois,
+                jnp.stack(hops_max))
 
     def _graph_search_base_vec(self, Q, p_vec, k: int, base_p: float):
         """One homogeneous-base sub-batch with per-row p (traced-p program),
@@ -810,7 +824,8 @@ class ShardedUHNSW:
 
     def _merge_delta(self, Q, p, k, ids, dists, n_p, iters, n_b, hops,
                      base_p, n_dim_frac, n_f32_frac, n_band_frac,
-                     phases=None, coverage: float = 1.0, poisoned=0.0):
+                     phases=None, coverage: float = 1.0, poisoned=0.0,
+                     hops_max=0):
         """Sort-merge exact delta-tier hits into the verified top-k.
 
         With abandonment on, the delta scan inherits the verified top-k's
@@ -862,7 +877,7 @@ class ShardedUHNSW:
                             n_band_frac=n_band_frac,
                             coverage_frac=float(coverage),
                             degraded=bool(coverage < 1.0),
-                            poisoned=poisoned)
+                            poisoned=poisoned, hops_max=hops_max)
         return ids, dists, stats
 
     def modeled_query_cost(self, stats: SearchStats, p, d: int) -> dict:

@@ -16,9 +16,17 @@ batch composition (tests/test_mixed_p.py pins this).
 
 The engine shares the service's stats dict (`default_stats` is the one
 schema both write): Eq. 1 counters, per-base/per-p attribution, flush
-reasons, shed/degraded counts, and per-request latency records that
-separate queue-wait from device-compute and flag cold (first-compile)
-program shapes.
+reasons, shed/degraded counts, the level-0 beam loop's lane occupancy
+(`beam_lane_trips` / `beam_lane_slots`), and per-request latency records
+that separate queue-wait from device-compute and flag cold
+(first-compile) program shapes.
+
+Each wave's stages run inside `jax.profiler.TraceAnnotation` spans
+(`engine.make_waves`, `engine.dispatch_search`, `engine.dispatch_finish`,
+`engine.collect` and, inside it, `engine.collect.wait`), tagged with the
+wave's sequence number (`wave=`) and padded size (`rows=`): a profile
+shows which stage of the engine's host work each device idle gap falls
+in. With no profiling session active the annotations are inert.
 
 Fault tolerance (DESIGN.md §9): every device interaction — stage A/B
 dispatch and host collection — sits behind a fault boundary. A wave that
@@ -45,6 +53,7 @@ from collections import deque
 from types import SimpleNamespace
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.core.metrics import base_metric_for
 from repro.index.health import QUARANTINED
@@ -155,13 +164,18 @@ def default_stats() -> dict:
                    "dim_frac_w": 0.0, "f32_rows_w": 0.0},
         },
         "per_p": {},                 # "%g" % p -> {queries, n_b, n_p}
+        # level-0 beam-loop occupancy: lane-trips that did work (each
+        # row's hops, summed over its segment lanes) and lane-trips the
+        # batched loops ran (per row, each lane's loop trip count); their
+        # ratio is the share of the loop's lanes not idling in lockstep.
+        # Padding rows are in neither (padded_rows counts them).
+        "beam_lane_trips": 0,
+        "beam_lane_slots": 0,
         # per-request latency; bounded so a long-running service cannot
         # grow it without limit (latency_summary reports over the window).
-        # latency_ms holds total ms (back-compat); latency_records holds
-        # (total_ms, queue_ms, compute_ms, cold) per request — the
-        # attribution fix: queue-wait vs device-compute vs first-call
-        # compile are separable.
-        "latency_ms": deque(maxlen=10_000),
+        # latency_records holds (total_ms, queue_ms, compute_ms, cold) per
+        # request — the attribution fix: queue-wait vs device-compute vs
+        # first-call compile are separable.
         "latency_records": deque(maxlen=10_000),
     }
 
@@ -194,6 +208,7 @@ class ServingEngine:
         self._results: dict[int, tuple] = {}
         self._failures: dict[int, str] = {}    # request_id -> error message
         self._seen_shapes: set[tuple] = set()  # cold-program detection
+        self._wave_seq = 0                     # next wave's sequence number
 
     # -- admission -----------------------------------------------------------
 
@@ -350,11 +365,19 @@ class ServingEngine:
             self.fault_injector = keep_inj
         return batches
 
+    def _make_waves(self, fl: Flush) -> list[Wave]:
+        """Cut a flush into ladder waves numbered on from the last."""
+        with TraceAnnotation("engine.make_waves", wave=self._wave_seq,
+                             rows=len(fl.requests)):
+            waves = make_waves(fl, self.policy.ladder, self._wave_seq)
+        self._wave_seq += len(waves)
+        return waves
+
     def _run(self, flushes: list[Flush]) -> None:
         work: deque[Wave] = deque()
         for fl in flushes:
             self.stats["flushes"][fl.reason] += 1
-            work.extend(make_waves(fl, self.policy.ladder))
+            work.extend(self._make_waves(fl))
         self._run_waves(work)
 
     def _run_waves(self, work: deque[Wave]) -> None:
@@ -591,7 +614,7 @@ class ServingEngine:
             for part in (wave.requests[:mid], wave.requests[mid:]):
                 fl = Flush(base=wave.base, k=wave.k, exact=wave.exact,
                            requests=part, reason=wave.reason)
-                subs.extend(make_waves(fl, self.policy.ladder))
+                subs.extend(self._make_waves(fl))
             for w in reversed(subs):
                 work.appendleft(w)
             return
@@ -615,8 +638,12 @@ class ServingEngine:
     # -- collection + stats --------------------------------------------------
 
     def _collect(self, wave: Wave) -> None:
-        ids, dists, n_b, n_p, frac, f32, phases, cov, pois = \
-            self.pipeline.collect(wave)
+        with wave.span("engine.collect"):
+            self._collect_wave(wave)
+
+    def _collect_wave(self, wave: Wave) -> None:
+        (ids, dists, n_b, n_p, frac, f32, phases, cov, pois, hops,
+         hops_max) = self.pipeline.collect(wave)
         st = self.stats
         health = getattr(self.index, "health", None)
         if pois.any():
@@ -676,6 +703,8 @@ class ServingEngine:
         st["n_p_spill"] += float(np_sp.sum())
         st["dim_frac_w"] += frac_w
         st["f32_rows_w"] += f32_w
+        st["beam_lane_trips"] += int(hops.sum())
+        st["beam_lane_slots"] += int(hops_max.sum()) * wave.n_real
         pb = st["per_base"]["G1" if wave.base == 1.0 else "G2"]
         pb["queries"] += wave.n_real
         pb["batches"] += 1
@@ -694,5 +723,4 @@ class ServingEngine:
             total = (done - r.arrival_t) * 1e3
             queue = max(r.flush_t - r.arrival_t, 0.0) * 1e3
             compute = max(done - r.flush_t, 0.0) * 1e3
-            st["latency_ms"].append(total)
             st["latency_records"].append((total, queue, compute, cold))

@@ -24,13 +24,21 @@ invariance (tests/test_mixed_p.py) makes them bitwise-identical to
 A `Wave` is one device-call unit: a ladder-sized, padded, homogeneous
 (base, k, exact) slice of a scheduler flush. Its query tensor and
 candidate set stay device-resident between the stages.
+
+Each stage runs inside a `jax.profiler.TraceAnnotation` named for it
+(`engine.dispatch_search`, `engine.dispatch_finish`, and
+`engine.collect.wait` around the blocking read), tagged with the wave's
+sequence number and padded size, so a profile puts the engine's host
+work on the device trace's clock wave by wave. Outside a profiling
+session an annotation does nothing.
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.retrieval.engine.request import (
     DONE,
@@ -57,6 +65,11 @@ class Wave:
     result: tuple | None = None      # (ids, dists, stats) after stage B
     attempt: int = 0                 # failed executions so far (retry budget)
     health_gen: int | None = None    # health generation at stage-A dispatch
+    seq: int = 0                     # the engine's wave sequence number
+
+    def span(self, name: str) -> TraceAnnotation:
+        """A profiler annotation of one of this wave's stages."""
+        return TraceAnnotation(name, wave=self.seq, rows=self.size)
 
     @property
     def n_real(self) -> int:
@@ -67,17 +80,19 @@ class Wave:
         return self.size - self.n_real
 
 
-def make_waves(flush: Flush, ladder: list[int]) -> list[Wave]:
+def make_waves(flush: Flush, ladder: list[int],
+               first_seq: int) -> list[Wave]:
     """Cut one flush into exact-fit ladder waves (greedy largest-first).
 
     Padding rows replicate row 0 of their wave (same base graph, any p is
     valid there) and are sliced off before results or stats are read —
-    identical to the v1 scheduler's padding contract.
+    identical to the v1 scheduler's padding contract. The waves are
+    numbered from `first_seq` on (the engine passes its running count).
     """
     reqs = flush.requests
     waves = []
     start = 0
-    for size in chunk_plan(len(reqs), ladder):
+    for i, size in enumerate(chunk_plan(len(reqs), ladder)):
         chunk = reqs[start:start + min(size, len(reqs) - start)]
         start += len(chunk)
         q = np.stack([np.asarray(r.vector, np.float32).reshape(-1)
@@ -93,7 +108,7 @@ def make_waves(flush: Flush, ladder: list[int]) -> list[Wave]:
                     [p_vec, np.repeat(p_vec[:1], size - len(chunk))])
         waves.append(Wave(base=flush.base, k=flush.k, exact=flush.exact,
                           reason=flush.reason, requests=chunk, size=size,
-                          q=q, p_vec=p_vec))
+                          q=q, p_vec=p_vec, seq=first_seq + i))
     return waves
 
 
@@ -111,8 +126,9 @@ class TwoStagePipeline:
 
     def dispatch_search(self, wave: Wave) -> None:
         """Stage A: async-dispatch base-graph candidate generation."""
-        wave.cands = self.index.search_stage_candidates(wave.q, wave.base,
-                                                        k=wave.k)
+        with wave.span("engine.dispatch_search"):
+            wave.cands = self.index.search_stage_candidates(
+                wave.q, wave.base, k=wave.k)
         for r in wave.requests:
             r.stage = SEARCHING
 
@@ -125,8 +141,9 @@ class TwoStagePipeline:
         is what makes engine results bitwise-equal to the baselines.
         """
         p_arg = wave.base if wave.exact else wave.p_vec
-        wave.result = self.index.search_stage_finish(
-            wave.q, wave.cands, p_arg, wave.k)
+        with wave.span("engine.dispatch_finish"):
+            wave.result = self.index.search_stage_finish(
+                wave.q, wave.cands, p_arg, wave.k)
         wave.cands = None  # device buffers free as soon as B consumes them
         for r in wave.requests:
             r.stage = VERIFYING
@@ -134,15 +151,19 @@ class TwoStagePipeline:
     def collect(self, wave: Wave):
         """Materialize one wave on host (the pipeline's only blocking
         point). Returns (ids, dists, n_b, n_p, frac, f32, phases, cov,
-        pois) sliced to real rows; `f32` is the per-row f32-rows-gathered
-        fraction (DESIGN.md §10 — 1.0 off the compressed two-band path);
+        pois, hops, hops_max) sliced to real rows; `f32` is the per-row
+        f32-rows-gathered fraction (DESIGN.md §10 — 1.0 off the
+        compressed two-band path);
         phases is the per-phase (n_b_probe, n_b_spill, n_p_probe,
         n_p_spill) attribution from the sharded two-phase search (probe =
         everything, spill = 0 for monolithic indexes and the independent
         policy); `cov` is the exact alive-coverage fraction the wave was
         served at (1.0 for monolithic indexes) and `pois` the per-row
         NaN/inf poison flags from the sharded query-time guard
-        (DESIGN.md §11 — all-False for monolithic indexes).
+        (DESIGN.md §11 — all-False for monolithic indexes). `hops` is each
+        row's level-0 trips summed over its segment lanes and `hops_max`
+        the trip count of the beam loop behind each lane
+        (`CandidateSet.hops_max`, one entry per searched segment).
         """
         ids, dists, st = wave.result
         n = wave.n_real
@@ -151,7 +172,8 @@ class TwoStagePipeline:
             x = np.asarray(x, dtype=np.float64)
             return x[:n] if x.ndim else np.full(n, float(x))
 
-        ids = np.asarray(ids)[:n]
+        with wave.span("engine.collect.wait"):
+            ids = np.asarray(ids)[:n]
         dists = np.asarray(dists)[:n]
         n_b = rows(st.n_b)
         n_p = rows(st.n_p)
@@ -162,7 +184,10 @@ class TwoStagePipeline:
         phases = (rows(nb_pr), rows(nb_sp), rows(np_pr), rows(np_sp))
         cov = float(getattr(st, "coverage_frac", 1.0))
         pois = rows(getattr(st, "poisoned", 0.0)).astype(bool)
+        hops = rows(st.hops)
+        hops_max = np.asarray(st.hops_max, dtype=np.float64).reshape(-1)
         wave.result = None
         for r in wave.requests:
             r.stage = DONE
-        return ids, dists, n_b, n_p, frac, f32, phases, cov, pois
+        return (ids, dists, n_b, n_p, frac, f32, phases, cov, pois, hops,
+                hops_max)

@@ -399,7 +399,6 @@ class UniversalVectorService:
             pp["queries"] += 1
             pp["n_b"] += float(n_b[i])
             pp["n_p"] += float(n_p[i])
-            st["latency_ms"].append((done - t0) * 1e3)
             st["latency_records"].append((
                 (done - t0) * 1e3,            # total
                 max(t_start - t0, 0.0) * 1e3,  # queue-wait
@@ -514,11 +513,12 @@ class UniversalVectorService:
 
     def latency_summary(self) -> dict:
         """Request-latency summary over the most recent window (the
-        backing buffers keep the last 10k requests).
+        backing buffer keeps the last 10k requests).
 
         Beyond the total-latency percentiles, the summary *attributes*
-        each request's time (the ISSUE's accounting fix): `queue_ms` is
-        admission -> dispatch wait, `compute_ms` is dispatch -> host
+        each request's time (the accounting fix): `queue_ms` is
+        admission -> flush (the engine's scheduler poll that released the
+        request; the v1 path's batch start), `compute_ms` is flush -> host
         materialization, `cold_count` is how many requests rode a batch
         shape's first (compiling) execution, and `warm` re-reports the
         total-latency percentiles over non-cold requests only — so a
@@ -543,12 +543,14 @@ class UniversalVectorService:
         tracker = getattr(self.index, "health", None)
         if tracker is not None:
             health["tracker"] = tracker.summary()
-        lat = np.asarray(self.stats["latency_ms"], dtype=np.float64)
-        if lat.size == 0:
+        recs = list(self.stats["latency_records"])
+        if not recs:
             return {"count": 0, "mean": 0.0, "p50": 0.0, "p95": 0.0,
                     "max": 0.0, "queue_ms": {}, "compute_ms": {},
                     "cold_count": 0, "warm": {}, "faults": faults,
                     "health": health}
+        arr = np.asarray([r[:3] for r in recs], dtype=np.float64)
+        lat = arr[:, 0]
         out = {
             "count": int(lat.size),
             "mean": float(lat.mean()),
@@ -558,25 +560,19 @@ class UniversalVectorService:
             "faults": faults,
             "health": health,
         }
-        recs = list(self.stats["latency_records"])
-        if recs:
-            arr = np.asarray([r[:3] for r in recs], dtype=np.float64)
-            cold = np.asarray([bool(r[3]) for r in recs])
-            for name, col in (("queue_ms", arr[:, 1]),
-                              ("compute_ms", arr[:, 2])):
-                out[name] = {
-                    "mean": float(col.mean()),
-                    "p50": float(np.percentile(col, 50)),
-                    "p95": float(np.percentile(col, 95)),
-                }
-            out["cold_count"] = int(cold.sum())
-            warm = arr[~cold, 0]
-            out["warm"] = {} if warm.size == 0 else {
-                "count": int(warm.size),
-                "p50": float(np.percentile(warm, 50)),
-                "p95": float(np.percentile(warm, 95)),
+        cold = np.asarray([bool(r[3]) for r in recs])
+        for name, col in (("queue_ms", arr[:, 1]),
+                          ("compute_ms", arr[:, 2])):
+            out[name] = {
+                "mean": float(col.mean()),
+                "p50": float(np.percentile(col, 50)),
+                "p95": float(np.percentile(col, 95)),
             }
-        else:
-            out["queue_ms"], out["compute_ms"] = {}, {}
-            out["cold_count"], out["warm"] = 0, {}
+        out["cold_count"] = int(cold.sum())
+        warm = lat[~cold]
+        out["warm"] = {} if warm.size == 0 else {
+            "count": int(warm.size),
+            "p50": float(np.percentile(warm, 50)),
+            "p95": float(np.percentile(warm, 95)),
+        }
         return out

@@ -364,7 +364,7 @@ def test_engine_warmup_precompiles_every_ladder_shape(svc, small_ds):
     batches = eng.warmup(k=10, ps=(0.8, 1.8, 2.0))
     assert batches == 3 * len(eng.policy.ladder)
     # warmup must not leak into the served counters...
-    assert svc.stats["queries"] == 0 and len(svc.stats["latency_ms"]) == 0
+    assert svc.stats["queries"] == 0 and len(svc.stats["latency_records"]) == 0
     assert eng.take_results() == {}
     # ...but after it, no traffic at these lanes ever rides a compile
     svc.serve(_requests(small_ds, 13, seed=9))     # 13 -> an odd wave mix

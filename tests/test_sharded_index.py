@@ -54,7 +54,7 @@ def test_padded_stacked_search_matches_unpadded(graph_incremental, small_ds):
         [jnp.arange(g.n, dtype=jnp.int32),
          jnp.full((37,), -1, dtype=jnp.int32)]
     )[None, :]
-    gids, gdists, gnb, _, _ = segmented_knn_search(
+    gids, gdists, gnb, _, _, _ = segmented_knn_search(
         stacked, Xp[None], node_ids, Q, ef=32, t=8
     )
     np.testing.assert_array_equal(
@@ -77,7 +77,7 @@ def test_segment_merge_equals_exact_topk(small_ds):
     Q = jnp.asarray(small_ds.queries[:12])
     n_seg = max(g.n for g in segs.graphs1)
     for base_p, arrays in ((1.0, segs.arrays1), (2.0, segs.arrays2)):
-        gids, gdists, _, _, _ = segmented_knn_search(
+        gids, gdists, _, _, _, _ = segmented_knn_search(
             arrays, segs.X, segs.node_ids, Q, ef=n_seg, t=K
         )
         true_ids, true_d = exact_topk(jnp.asarray(data), Q, base_p, K)

@@ -227,7 +227,7 @@ def test_oracle_threshold_sound_and_cheaper(tiny_segments, small_ds, base_p):
     n_seg = max(g.n for g in segs.graphs1)
     true_ids, true_d = exact_topk(jnp.asarray(data), Q, base_p, K)
     thresh = jnp.asarray(true_d[:, K - 1] * (1 + 1e-6))
-    gids, gdists, nb_t, _, _ = segmented_knn_search(
+    gids, gdists, nb_t, _, _, _ = segmented_knn_search(
         arrays, segs.X, segs.node_ids, Q, ef=n_seg, t=K, thresh=thresh)
     gids, gdists = np.asarray(gids), np.asarray(gdists)
     true_ids, true_d = np.asarray(true_ids), np.asarray(true_d)
@@ -245,7 +245,7 @@ def test_oracle_threshold_sound_and_cheaper(tiny_segments, small_ds, base_p):
                                        rtol=1e-5, atol=1e-5)
     assert recall(jnp.asarray(gids), jnp.asarray(true_ids)) >= 0.9
     # the bound actually saved base-metric work vs the open search
-    _, _, nb_open, _, _ = segmented_knn_search(
+    _, _, nb_open, _, _, _ = segmented_knn_search(
         arrays, segs.X, segs.node_ids, Q, ef=n_seg, t=K)
     assert float(jnp.mean(nb_t)) < float(jnp.mean(nb_open))
 
