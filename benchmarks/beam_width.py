@@ -89,6 +89,88 @@ def _merge_microbench(quick: bool) -> dict:
     return row
 
 
+VISITED_LANES = (128, 512)
+VISITED_WORDS = (256, 512, 1024, 2048, 4096, 8192)
+
+
+def _visited_microbench(quick: bool, lanes_grid=VISITED_LANES,
+                        words_grid=VISITED_WORDS, trips: int = 64,
+                        j: int = 32, seed: int = 0) -> list[dict]:
+    """Visited-bitmask test-and-set alone, dense against indexed form.
+
+    Each (lanes, words) point runs `trips` hops of the level-0 loop's
+    bookkeeping and nothing else: every lane (one query of one segment)
+    tests and sets j = W*m0 distinct neighbour ids per hop, vmapped over
+    the lanes inside one `fori_loop`, as the beam loop runs it. A loop
+    that only reads the ids is timed too, and subtracted, so `us_per_trip_*`
+    is the bookkeeping's own time per hop for all lanes.
+    DENSE_VISITED_MAX_WORDS (core/hnsw.py) is read from this table: the
+    largest `words` at which the dense form is still the faster.
+
+      PYTHONPATH=src python -m benchmarks.beam_width   # prints the table
+    """
+    from repro.core.hnsw import _visited_dense, _visited_scatter
+
+    reps = 3 if quick else 10
+    rng = np.random.default_rng(seed)
+    rows = []
+    for lanes in lanes_grid:
+        for words in words_grid:
+            n = words * 32
+            # distinct ids per (trip, lane): a random base plus j strides
+            base = rng.integers(0, n, size=(trips, lanes, 1))
+            ids = (base + np.arange(j) * (n // j)) % n
+            ids = jnp.asarray(ids.astype(np.int32))
+            eligible = jnp.ones((lanes, j), bool)
+
+            def make(form):
+                @jax.jit
+                def loop(ids):
+                    def body(t, carry):
+                        visited, count = carry
+                        nb = ids[t]
+                        word = nb >> 5
+                        bit = jnp.uint32(1) << (nb.astype(jnp.uint32) & 31)
+                        if form is None:
+                            return visited, count + word.sum()
+                        new, visited = jax.vmap(form)(visited, word, bit,
+                                                      eligible)
+                        return visited, count + new.sum()
+
+                    visited = jnp.zeros((lanes, words), jnp.uint32)
+                    return jax.lax.fori_loop(0, trips, body,
+                                             (visited, jnp.int32(0)))
+                return loop
+
+            def timed(fn):
+                out = jax.block_until_ready(fn(ids))
+                t0 = time.perf_counter()
+                for _ in range(reps):
+                    jax.block_until_ready(fn(ids))
+                return out, (time.perf_counter() - t0) / reps / trips * 1e6
+
+            _, us_null = timed(make(None))
+            (vis_d, cnt_d), us_dense = timed(make(_visited_dense))
+            (vis_s, cnt_s), us_scatter = timed(make(_visited_scatter))
+            assert np.array_equal(np.asarray(vis_d), np.asarray(vis_s))
+            assert int(cnt_d) == int(cnt_s)
+            row = {
+                "dataset": "visited-microbench",
+                "device": jax.devices()[0].device_kind,
+                "lanes": lanes, "words": words, "ids_per_lane": j,
+                "trips": trips,
+                "us_per_trip_null": round(us_null, 2),
+                "us_per_trip_dense": round(us_dense - us_null, 2),
+                "us_per_trip_scatter": round(us_scatter - us_null, 2),
+            }
+            rows.append(row)
+            print(f"  visited micro-bench lanes={lanes} words={words}: "
+                  f"dense {row['us_per_trip_dense']}us vs scatter "
+                  f"{row['us_per_trip_scatter']}us per trip "
+                  f"(loop alone {row['us_per_trip_null']}us)", flush=True)
+    return rows
+
+
 def run(quick: bool = False):
     name = "trevi" if quick else "sun"
     widths = (1, 4) if quick else WIDTHS
@@ -128,4 +210,13 @@ def run(quick: bool = False):
     for r in rows[1:]:
         r["hops_speedup_vs_w1"] = round(base["mean_hops"] / r["mean_hops"], 2)
     rows.append(_merge_microbench(quick))
+    rows.extend(_visited_microbench(quick))
     return rows
+
+
+if __name__ == "__main__":
+    import json
+    import sys
+
+    table = _visited_microbench(quick="--quick" in sys.argv)
+    print(json.dumps(table))

@@ -11,8 +11,9 @@ model. We restructure it (DESIGN.md §2) as fixed-width tensor ops inside
     W-way multi-expansion (`expand_width`, DESIGN.md §2 hot path). Each hop
     expands the W best unexpanded beam entries at once: gather their W*m0
     neighbors, dedupe across lists (sort + first-occurrence mask), test-and-
-    set a per-query visited *bitmask* (uint32 words, carry-safe scatter-add
-    of distinct bits), compute base-metric distances for unseen neighbors in
+    set a per-query visited *bitmask* (uint32 words; dense compares over the
+    word axis up to DENSE_VISITED_MAX_WORDS, a carry-free scatter-add of
+    distinct bits above), compute base-metric distances for unseen neighbors in
     one fused block, and merge via a single `lax.sort`. W=1 is the classic
     single-expansion search.
 
@@ -155,6 +156,55 @@ def _base_dist(q: jax.Array, x: jax.Array, p: float) -> jax.Array:
     return lp_distance(q, x, p, root=False)
 
 
+# Largest per-query visited bitmask, in uint32 words, whose test-and-set runs
+# as dense compares over the word axis (`_visited_dense`); a larger bitmask
+# keeps the indexed gather and scatter (`_visited_scatter`). The dense form
+# costs O(W*m0 * words) element work per query per hop, the indexed form
+# O(W*m0) serial element updates, so the choice rests on `words` alone. Set
+# from benchmarks/beam_width.py's visited micro-bench on a TPU v5e
+# (PERF.md, "Findings").
+DENSE_VISITED_MAX_WORDS = 4096
+
+
+def _visited_scatter(visited, word, bit, eligible):
+    """Visited test-and-set as an indexed gather and scatter-add.
+
+    visited (words,) uint32; word, bit, eligible (J,): each neighbour's
+    bitmask word, its bit within the word, and whether it may be new (a
+    valid id at its first occurrence). Returns (new (J,) bool, visited).
+    """
+    seen = (visited[word] & bit) != 0
+    new = eligible & ~seen
+    # distinct ids -> distinct (word, bit); duplicates are masked to 0,
+    # so the scatter-add is carry-free.
+    return new, visited.at[word].add(bit * new.astype(jnp.uint32))
+
+
+def _visited_dense(visited, word, bit, eligible):
+    """`_visited_scatter`'s arithmetic as dense compares over the word axis.
+
+    Each neighbour's word is picked out by a compare against iota(words)
+    and a sum over words (exactly one term is non-zero), and each word's
+    new bits are the sum over neighbours of the bits landing in it. Integer
+    sums in the same uint32 ring as the scatter-add, so the result is the
+    same bit for bit; XLA fuses each into one compare-select-reduce.
+    """
+    zero = jnp.uint32(0)
+    hit = word[:, None] == jnp.arange(visited.shape[0], dtype=word.dtype)
+    held = jnp.where(hit, visited[None, :], zero).sum(axis=1, dtype=jnp.uint32)
+    new = eligible & ((held & bit) == 0)
+    add = jnp.where(hit, (bit * new.astype(jnp.uint32))[:, None], zero)
+    return new, visited + add.sum(axis=0, dtype=jnp.uint32)
+
+
+def _visited_test_and_set(visited, word, bit, eligible):
+    """Dense form up to DENSE_VISITED_MAX_WORDS words, indexed form above;
+    the two give identical results (tests/test_visited_forms.py)."""
+    if visited.shape[0] <= DENSE_VISITED_MAX_WORDS:
+        return _visited_dense(visited, word, bit, eligible)
+    return _visited_scatter(visited, word, bit, eligible)
+
+
 def _greedy_descend(q, X, adj_l, g2l, ep, ep_dist, nb, p, max_hops):
     """Greedy ef=1 search on one upper layer. Returns (ep, ep_dist, nb)."""
     n = X.shape[0]
@@ -234,7 +284,7 @@ def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
         nbrs = jnp.where(sel_ok[:, None], nbrs, n).reshape(-1)  # (W*m0,)
         if w > 1:
             # the W lists can share neighbors; sort + first-occurrence mask
-            # dedupes so the bitmask scatter-add below stays carry-free
+            # dedupes so the bitmask set below stays carry-free
             nbrs = jax.lax.sort(nbrs)
             first = jnp.concatenate(
                 [jnp.ones((1,), bool), nbrs[1:] != nbrs[:-1]]
@@ -247,11 +297,7 @@ def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
         safe = jnp.clip(nbrs, 0, n - 1)
         word = safe >> 5
         bit = jnp.uint32(1) << (safe.astype(jnp.uint32) & 31)
-        seen = (visited[word] & bit) != 0
-        new = valid & ~seen & first
-        # distinct ids -> distinct (word, bit); duplicates are masked to 0,
-        # so the scatter-add below is carry-free.
-        visited = visited.at[word].add(bit * new.astype(jnp.uint32))
+        new, visited = _visited_test_and_set(visited, word, bit, valid & first)
         # 4. one fused base-metric distance block for unseen neighbors only
         dv = _base_dist(q, X[safe], p)
         dv = jnp.where(new, dv, jnp.inf)
