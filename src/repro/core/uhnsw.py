@@ -199,6 +199,12 @@ class SearchStats(NamedTuple):
         # bisects this back to a segment and quarantines it)
     hops_max: jax.Array | int = 0  # CandidateSet.hops_max, carried
         # through stage B (0 where no staged candidates fed the stats)
+    n_scan_blocks: jax.Array | float = 0.0  # (B,) mean dimension blocks
+        # the abandoning scan entered per verified candidate (DESIGN.md
+        # §8): 0 for a candidate abandoned at entry, ceil(d / block_d) for
+        # one scored whole (full-dimension scoring enters every block).
+        # Each block past the first is one mid-scan abandonment check.
+        # Weighted by n_p like n_dim_frac; 0.0 where nothing was verified.
 
     def phase_n_b(self):
         """(probe, spill) N_b split with the None default resolved."""
@@ -303,7 +309,12 @@ def _verify_abandon_impl(
     entirely), and the full (k + kappa) `lax.sort` merge becomes a
     masked `lax.top_k` merge — abandoned candidates are +inf, so top_k's
     lowest-index tie rule selects exactly what the stable sort did.
-    Returns the extra `n_dim_frac` (B,) — scanned dimension-work fraction.
+    Returns the extra `n_dim_frac` (B,) — scanned dimension-work fraction
+    — and the mean dimension blocks entered per verified candidate (B,),
+    counted only where the last block is ragged: where block_d divides d
+    a candidate's blocks are its scanned dimensions over block_d, so
+    `verify_candidates` reads them off `n_dim_frac` and this program
+    stays free of the count (None).
 
     When (x_scan, perm) are given, the blocked scan runs over the
     energy-ordered corpus view (UHNSWParams.energy_perm, DESIGN.md §10):
@@ -320,8 +331,14 @@ def _verify_abandon_impl(
     n_batches = max((t - k) // kappa, 0)
     p_col = p if metrics.is_static_p(p) else p[:, None]
 
-    from repro.kernels.ops import lp_gather_abandon, lp_gather_distance
+    from repro.kernels.ops import (
+        lp_gather_abandon,
+        lp_gather_distance,
+        pick_abandon_block_d,
+    )
 
+    width = block_d or pick_abandon_block_d(d)
+    ragged = d % width != 0
     Qs = Q if perm is None else jnp.take(Q, perm, axis=1)
     Xs = X if x_scan is None else x_scan
 
@@ -336,16 +353,19 @@ def _verify_abandon_impl(
     ones = jnp.ones((B,), jnp.float32)
 
     if n_batches == 0:
-        return r_ids, metrics._root(r_dist, p_col), n_p0, jnp.int32(0), ones
+        return (r_ids, metrics._root(r_dist, p_col), n_p0, jnp.int32(0),
+                ones, None)
 
     dim0 = ones * (k * d)
+    # the first k rows were scored whole: every block, as in dim0
+    blocks0 = (ones * (k * -(-d // width)),) if ragged else ()
 
     def cond(s):
-        i, _, _, done, _, _ = s
+        i, _, _, done, *_ = s
         return (i < n_batches) & ~jnp.all(done)
 
     def body(s):
-        i, r_ids, r_dist, done, n_p, dim_scan = s
+        i, r_ids, r_dist, done, n_p, dim_scan, *blocks = s
         start = k + i * kappa
         batch = jax.lax.dynamic_slice(cand_ids, (0, start), (B, kappa))
         bbase = jax.lax.dynamic_slice(cand_base, (0, start), (B, kappa))
@@ -374,16 +394,23 @@ def _verify_abandon_impl(
         n_p = n_p + jnp.where(done, 0, kappa)
         dim_scan = dim_scan + jnp.where(
             done, 0.0, nd.sum(axis=1).astype(jnp.float32))
-        return (i + 1, r_ids, r_dist, done | newly_done, n_p, dim_scan)
+        if ragged:
+            # a candidate entered every block it scanned any dimension of
+            entered = ((nd + width - 1) // width).sum(axis=1).astype(
+                jnp.float32)
+            blocks = [blocks[0] + jnp.where(done, 0.0, entered)]
+        return (i + 1, r_ids, r_dist, done | newly_done, n_p, dim_scan,
+                *blocks)
 
     state = (jnp.int32(0), r_ids, r_dist, jnp.zeros((B,), bool), n_p0,
-             dim0)
-    iters, r_ids, r_dist, done, n_p, dim_scan = \
+             dim0, *blocks0)
+    iters, r_ids, r_dist, done, n_p, dim_scan, *blocks = \
         jax.lax.while_loop(cond, body, state)
     # the denominator needs no separate carry: n_p accrues kappa under
     # exactly the mask dim_scan uses, so total offered work == n_p * d
     return (r_ids, metrics._root(r_dist, p_col), n_p, iters,
-            dim_scan / (n_p.astype(jnp.float32) * d))
+            dim_scan / (n_p.astype(jnp.float32) * d),
+            blocks[0] / n_p.astype(jnp.float32) if ragged else None)
 
 
 def _verify_two_band_impl(
@@ -550,9 +577,11 @@ def verify_candidates(
 
     Returns (ids (B, k) int32, dists (B, k) f32 with root applied,
     n_p (B,) int32, iters () int32, n_dim_frac (B,) f32,
-    n_f32_rows_frac (B,) f32, n_band_frac (B,) f32) — the last two are
-    the SearchStats byte-traffic counters (1.0 / 0.0 off the two-band
-    path).
+    n_f32_rows_frac (B,) f32, n_band_frac (B,) f32, n_scan_blocks (B,)
+    f32) — n_f32_rows_frac and n_band_frac are the SearchStats
+    byte-traffic counters (1.0 / 0.0 off the two-band path), and
+    n_scan_blocks the mean dimension blocks of width block_d (None:
+    `pick_abandon_block_d`) entered per verified candidate.
 
     p follows the scalar-vs-vector contract (DESIGN.md §6): a Python float
     re-ranks the whole batch under one metric (one compiled program per p);
@@ -586,7 +615,10 @@ def verify_candidates(
     beams / merges) and are scored as inf so they can never enter R.
     `interpret` forwards to the kernel dispatch (None = backend-aware).
     """
-    B = Q.shape[0]
+    B, d = Q.shape
+    from repro.kernels.ops import pick_abandon_block_d
+
+    n_blocks = -(-d // (block_d or pick_abandon_block_d(d)))
     ones = jnp.ones((B,), jnp.float32)
     zeros = jnp.zeros((B,), jnp.float32)
     if abandon and band is not None:
@@ -594,14 +626,17 @@ def verify_candidates(
             cand_base = jnp.zeros(cand_ids.shape, jnp.float32)
         Qp = jnp.take(Q, band.perm, axis=1)
         if metrics.is_static_p(p):
-            return _verify_two_band_jit_s(
+            out = _verify_two_band_jit_s(
                 Q, Qp, cand_ids, cand_base, X, band.rows, band.scale,
                 band.radius, float(p), k, kappa, tau, float(base_p),
                 interpret, block_d)
-        return _verify_two_band_jit_v(
-            Q, Qp, cand_ids, cand_base, X, band.rows, band.scale,
-            band.radius, jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
-            k, kappa, tau, float(base_p), interpret, block_d)
+        else:
+            out = _verify_two_band_jit_v(
+                Q, Qp, cand_ids, cand_base, X, band.rows, band.scale,
+                band.radius, jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
+                k, kappa, tau, float(base_p), interpret, block_d)
+        # the f32 rows scored are whole rows: n_dim_frac is their share
+        return (*out, out[4] * n_blocks)
     if abandon:
         if cand_base is None:
             cand_base = jnp.zeros(cand_ids.shape, jnp.float32)
@@ -615,8 +650,10 @@ def verify_candidates(
                 jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
                 k, kappa, tau, float(base_p), interpret, block_d,
                 x_scan, scan_perm)
-        ids, dists, n_p, iters, frac = out
-        return ids, dists, n_p, iters, frac, ones, zeros
+        ids, dists, n_p, iters, frac, blocks = out
+        if blocks is None:  # whole blocks: scanned dims over block_d
+            blocks = frac * n_blocks
+        return ids, dists, n_p, iters, frac, ones, zeros, blocks
     if metrics.is_static_p(p):
         out = _verify_jit_s(Q, cand_ids, X, float(p), k, kappa, tau,
                             interpret)
@@ -625,20 +662,20 @@ def verify_candidates(
                             jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
                             k, kappa, tau, interpret)
     ids, dists, n_p, iters = out
-    return ids, dists, n_p, iters, ones, ones, zeros
+    return ids, dists, n_p, iters, ones, ones, zeros, ones * n_blocks
 
 
 def mask_base_rows(cand_ids, cand_dists, ids, dists, n_p, p_vec, base_p,
                    k: int, n_dim_frac=None, n_f32_frac=None,
-                   n_band_frac=None):
+                   n_band_frac=None, n_scan_blocks=None):
     """Per-row base-metric skip (paper §3 preamble) inside a mixed batch.
 
     Rows whose p equals the base metric take the beam's own ordering —
     the exact values the scalar skip path produces — and report n_p = 0
     (and, when given, the scalar skip path's neutral stats: n_dim_frac
-    and n_f32_frac 1.0, n_band_frac 0.0). Returns 3, 4, or 6 values
-    depending on which optional frac counters were supplied (the 6-form
-    requires all three).
+    and n_f32_frac 1.0, n_band_frac and n_scan_blocks 0.0). Returns 3, 4,
+    or 7 values depending on which optional counters were supplied (the
+    7-form requires all four).
     """
     pj = jnp.asarray(p_vec, dtype=jnp.float32)
     is_base = pj == base_p
@@ -653,7 +690,8 @@ def mask_base_rows(cand_ids, cand_dists, ids, dists, n_p, p_vec, base_p,
     if n_f32_frac is None:
         return ids, dists, n_p, frac
     return (ids, dists, n_p, frac, jnp.where(is_base, 1.0, n_f32_frac),
-            jnp.where(is_base, 0.0, n_band_frac))
+            jnp.where(is_base, 0.0, n_band_frac),
+            jnp.where(is_base, 0.0, n_scan_blocks))
 
 
 def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
@@ -662,12 +700,12 @@ def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
 
     search_base_vec(Q_sub (B', d), p_sub (B',) f32, k, base_p) must run one
     homogeneous-base sub-batch and return (ids, dists, n_p, iters, n_b,
-    hops, n_dim_frac, n_f32_rows_frac, n_band_frac) — optionally followed
-    by the four per-phase counters (n_b_probe, n_b_spill, n_p_probe,
-    n_p_spill), which the sharded index appends (DESIGN.md §3); absent,
-    the whole sub-batch counts as probe. A 14th element, the per-row
-    poisoned flag from the NaN/inf guard (DESIGN.md §11), is likewise
-    optional and defaults to all-clean.
+    hops, n_dim_frac, n_f32_rows_frac, n_band_frac, n_scan_blocks) —
+    optionally followed by the four per-phase counters (n_b_probe,
+    n_b_spill, n_p_probe, n_p_spill), which the sharded index appends
+    (DESIGN.md §3); absent, the whole sub-batch counts as probe. A 15th
+    element, the per-row poisoned flag from the NaN/inf guard (DESIGN.md
+    §11), is likewise optional and defaults to all-clean.
     Returns (ids (B, k), dists (B, k), SearchStats) with per-row stats
     scattered back into request order; stats.base_p is the (B,) host-side
     base-metric array (the partition itself is host logic).
@@ -690,7 +728,8 @@ def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
         zf = jnp.zeros((0,), jnp.float32)
         return z.astype(jnp.int32), z, SearchStats(
             n_b=zi, n_p=zi, iterations=jnp.int32(0), base_p=base, hops=zi,
-            n_dim_frac=zf, n_f32_rows_frac=zf, n_band_frac=zf)
+            n_dim_frac=zf, n_f32_rows_frac=zf, n_band_frac=zf,
+            n_scan_blocks=zf)
     sels, parts = [], []
     iters = jnp.int32(0)
     for base_p in (1.0, 2.0):
@@ -699,35 +738,36 @@ def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
             continue
         res = search_base_vec(Q[sel], p_arr[sel], k, base_p)
         (s_ids, s_dists, s_np, s_it, s_nb, s_hops, s_frac, s_f32,
-         s_band) = res[:9]
-        if len(res) > 9:
-            nb_pr, nb_sp, np_pr, np_sp = res[9:13]
+         s_band, s_blocks) = res[:10]
+        if len(res) > 10:
+            nb_pr, nb_sp, np_pr, np_sp = res[10:14]
         else:  # phase-unaware index: everything is probe work
             nb_pr, nb_sp = s_nb, jnp.zeros_like(s_nb)
             np_pr, np_sp = s_np, jnp.zeros_like(s_np)
         # NaN/inf-guard flag (DESIGN.md §11); absent = all-clean
-        s_pois = res[13] if len(res) > 13 else jnp.zeros_like(s_frac)
+        s_pois = res[14] if len(res) > 14 else jnp.zeros_like(s_frac)
         sels.append(sel)
         parts.append((s_ids, s_dists, s_np, s_nb, s_hops, s_frac,
-                      s_f32, s_band, nb_pr, nb_sp, np_pr, np_sp, s_pois))
+                      s_f32, s_band, nb_pr, nb_sp, np_pr, np_sp, s_pois,
+                      s_blocks))
         iters = jnp.maximum(iters, jnp.asarray(s_it, jnp.int32))
     if len(parts) == 1:  # homogeneous batch: already in request order
         (ids, dists, n_p, n_b, hops, frac, f32f, bandf,
-         nb_pr, nb_sp, np_pr, np_sp, pois) = parts[0]
+         nb_pr, nb_sp, np_pr, np_sp, pois, blocks) = parts[0]
     else:
         order = np.concatenate(sels)
         inv = np.empty(b, np.int64)
         inv[order] = np.arange(b)
         inv = jnp.asarray(inv)
         (ids, dists, n_p, n_b, hops, frac, f32f, bandf,
-         nb_pr, nb_sp, np_pr, np_sp, pois) = (
+         nb_pr, nb_sp, np_pr, np_sp, pois, blocks) = (
             jnp.concatenate(xs, axis=0)[inv] for xs in zip(*parts)
         )
     stats = SearchStats(
         n_b=n_b, n_p=n_p, iterations=iters, base_p=base, hops=hops,
         n_dim_frac=frac, n_b_probe=nb_pr, n_b_spill=nb_sp,
         n_p_probe=np_pr, n_p_spill=np_sp, n_f32_rows_frac=f32f,
-        n_band_frac=bandf, poisoned=pois,
+        n_band_frac=bandf, poisoned=pois, n_scan_blocks=blocks,
     )
     return ids, dists, stats
 
@@ -977,7 +1017,7 @@ class UHNSW:
                 hops_max=cands.hops_max)
         kappa = prm.kappa or max(k // 2, 1)
         p_arg = float(p) if metrics.is_static_p(p) else p
-        ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
+        ids, dists, n_p, iters, frac, f32f, bandf, blocks = verify_candidates(
             Q, cand_ids, self._X_rows, p_arg, k, kappa, prm.tau,
             interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
             abandon=prm.abandon, block_d=prm.abandon_block_d,
@@ -986,15 +1026,17 @@ class UHNSW:
         if not metrics.is_static_p(p):
             # per-row base-metric skip: base-p rows return the exact values
             # the scalar skip path produces
-            ids, dists, n_p, frac, f32f, bandf = mask_base_rows(
+            ids, dists, n_p, frac, f32f, bandf, blocks = mask_base_rows(
                 cand_ids, cand_dists, ids, dists, n_p, p, base_p, k,
-                n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf)
+                n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf,
+                n_scan_blocks=blocks)
         return ids, dists, SearchStats(n_b=n_b, n_p=n_p, iterations=iters,
                                        base_p=base_p, hops=hops,
                                        n_dim_frac=frac,
                                        n_f32_rows_frac=f32f,
                                        n_band_frac=bandf,
-                                       hops_max=cands.hops_max)
+                                       hops_max=cands.hops_max,
+                                       n_scan_blocks=blocks)
 
     def _search_scalar(self, Q, p: float, k: int):
         _, base_p = self.base_graph_for(p)
@@ -1007,7 +1049,8 @@ class UHNSW:
         cands = self.search_stage_candidates(Q, base_p)
         ids, dists, st = self.search_stage_finish(Q, cands, p_vec, k)
         return (ids, dists, st.n_p, st.iterations, st.n_b, st.hops,
-                st.n_dim_frac, st.n_f32_rows_frac, st.n_band_frac)
+                st.n_dim_frac, st.n_f32_rows_frac, st.n_band_frac,
+                st.n_scan_blocks)
 
     def _search_mixed(self, Q, p, k: int):
         """Mixed-p batch: two-way G1/G2 partition + per-row-p programs."""

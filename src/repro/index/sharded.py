@@ -69,7 +69,7 @@ from repro.core.uhnsw import (
 from repro.index.delta import DeltaBuffer
 from repro.index.health import SegmentHealthTracker
 from repro.index.segment import SegmentedGraphs, build_segment_pair, build_segments
-from repro.kernels.ops import kernel_rows
+from repro.kernels.ops import kernel_rows, pick_abandon_block_d
 
 
 @dataclass(frozen=True)
@@ -561,9 +561,10 @@ class ShardedUHNSW:
                 frac = jnp.ones(n_b.shape, jnp.float32)
                 f32f = jnp.ones(n_b.shape, jnp.float32)
                 bandf = jnp.zeros(n_b.shape, jnp.float32)
+                blocks = jnp.zeros(n_b.shape, jnp.float32)
             else:
                 # -1 padding passes through: verify_candidates scores it inf
-                ids, dists, n_p, iters, frac, f32f, bandf = \
+                ids, dists, n_p, iters, frac, f32f, bandf, blocks = \
                     verify_candidates(
                         Q, cand_ids, self._X_rows, p, k, kappa, prm.tau,
                         interpret=prm.interpret, cand_base=cand_dists,
@@ -574,26 +575,28 @@ class ShardedUHNSW:
             phases = self._phase_split(cands, n_p)
             return self._merge_delta(Q, p, k, ids, dists, n_p, iters, n_b,
                                      hops, base_p, frac, f32f, bandf,
-                                     phases, coverage=cands.coverage_frac,
+                                     blocks, phases,
+                                     coverage=cands.coverage_frac,
                                      poisoned=cands.poisoned,
                                      hops_max=cands.hops_max)
         # vector p over one homogeneous base: the traced-p program + the
         # per-row base-metric skip mask, exactly as _search_mixed runs it
-        ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
+        ids, dists, n_p, iters, frac, f32f, bandf, blocks = verify_candidates(
             Q, cand_ids, self._X_rows, p, k, kappa, prm.tau,
             interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
             abandon=prm.abandon, block_d=prm.abandon_block_d,
             **self._verify_extras(),
         )
-        ids, dists, n_p, frac, f32f, bandf = mask_base_rows(
+        ids, dists, n_p, frac, f32f, bandf, blocks = mask_base_rows(
             cand_ids, cand_dists, ids, dists, n_p, p, base_p, k,
-            n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf)
+            n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf,
+            n_scan_blocks=blocks)
         phases = self._phase_split(cands, n_p)
         p_arr = np.broadcast_to(np.asarray(p, np.float32).reshape(-1),
                                 (int(Q.shape[0]),))
         return self._merge_delta(Q, p_arr, k, ids, dists, n_p, iters, n_b,
-                                 hops, base_p, frac, f32f, bandf, phases,
-                                 coverage=cands.coverage_frac,
+                                 hops, base_p, frac, f32f, bandf, blocks,
+                                 phases, coverage=cands.coverage_frac,
                                  poisoned=cands.poisoned,
                                  hops_max=cands.hops_max)
 
@@ -792,18 +795,20 @@ class ShardedUHNSW:
         cands = self.search_stage_candidates(Q, base_p, k=k)
         cand_ids, cand_dists = cands.ids, cands.base_dists
         kappa = prm.kappa or max(k // 2, 1)
-        ids, dists, n_p, iters, frac, f32f, bandf = verify_candidates(
+        ids, dists, n_p, iters, frac, f32f, bandf, blocks = verify_candidates(
             Q, cand_ids, self._X_rows, p_vec, k, kappa, prm.tau,
             interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
             abandon=prm.abandon, block_d=prm.abandon_block_d,
             **self._verify_extras(),
         )
-        ids, dists, n_p, frac, f32f, bandf = mask_base_rows(
+        ids, dists, n_p, frac, f32f, bandf, blocks = mask_base_rows(
             cand_ids, cand_dists, ids, dists, n_p, p_vec, base_p, k,
-            n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf)
+            n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf,
+            n_scan_blocks=blocks)
         nb_pr, nb_sp, np_pr, np_sp = self._phase_split(cands, n_p)
         return (ids, dists, n_p, iters, cands.n_b, cands.hops, frac,
-                f32f, bandf, nb_pr, nb_sp, np_pr, np_sp, cands.poisoned)
+                f32f, bandf, blocks, nb_pr, nb_sp, np_pr, np_sp,
+                cands.poisoned)
 
     def _search_mixed(self, Q, p, k: int):
         """Mixed-p batch: two-way G1/G2 partition, then one delta merge."""
@@ -819,21 +824,23 @@ class ShardedUHNSW:
                                  stats.iterations, stats.n_b, stats.hops,
                                  stats.base_p, stats.n_dim_frac,
                                  stats.n_f32_rows_frac, stats.n_band_frac,
-                                 phases, coverage=self.coverage_frac(),
+                                 stats.n_scan_blocks, phases,
+                                 coverage=self.coverage_frac(),
                                  poisoned=stats.poisoned)
 
     def _merge_delta(self, Q, p, k, ids, dists, n_p, iters, n_b, hops,
                      base_p, n_dim_frac, n_f32_frac, n_band_frac,
-                     phases=None, coverage: float = 1.0, poisoned=0.0,
-                     hops_max=0):
+                     n_scan_blocks, phases=None, coverage: float = 1.0,
+                     poisoned=0.0, hops_max=0):
         """Sort-merge exact delta-tier hits into the verified top-k.
 
         With abandonment on, the delta scan inherits the verified top-k's
         k-th-best as its abandon threshold (DESIGN.md §8): buffered
         vectors that provably cannot enter the top-k skip their remaining
         dimension blocks. `n_dim_frac` is then updated as the N_p-weighted
-        mean of the graph-verify fraction and the delta scan's fraction;
-        likewise `n_f32_frac`/`n_band_frac` (DESIGN.md §10) — the delta
+        mean of the graph-verify fraction and the delta scan's fraction,
+        and `n_scan_blocks` likewise with the delta scan's blocks entered;
+        so are `n_f32_frac`/`n_band_frac` (DESIGN.md §10) — the delta
         tier is f32-only, so its scans count as full-f32 rows with zero
         band traffic regardless of `compressed_band`.
         `phases` is the (n_b_probe, n_b_spill, n_p_probe, n_p_spill)
@@ -860,8 +867,11 @@ class ShardedUHNSW:
             sd, si = jax.lax.sort((all_d, all_ids), num_keys=1)
             ids, dists = si[:, :k], sd[:, :k]
             delta_frac = d_nd.sum(axis=1).astype(jnp.float32) / (n_delta * d)
+            bd = self.params.abandon_block_d or pick_abandon_block_d(d)
+            d_blocks = ((d_nd + bd - 1) // bd).sum(axis=1).astype(jnp.float32)
             denom = jnp.maximum(n_p + n_delta, 1)
             n_dim_frac = (n_dim_frac * n_p + delta_frac * n_delta) / denom
+            n_scan_blocks = (n_scan_blocks * n_p + d_blocks) / denom
             # delta rows are full f32 gathers (no compressed replica of
             # the mutable tier) and contribute no band-dimension traffic
             n_f32_frac = (n_f32_frac * n_p + 1.0 * n_delta) / denom
@@ -877,7 +887,8 @@ class ShardedUHNSW:
                             n_band_frac=n_band_frac,
                             coverage_frac=float(coverage),
                             degraded=bool(coverage < 1.0),
-                            poisoned=poisoned, hops_max=hops_max)
+                            poisoned=poisoned, hops_max=hops_max,
+                            n_scan_blocks=n_scan_blocks)
         return ids, dists, stats
 
     def modeled_query_cost(self, stats: SearchStats, p, d: int) -> dict:

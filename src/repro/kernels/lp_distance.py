@@ -521,8 +521,11 @@ def gather_lp_kernel_call(
 # a row whose candidates are all dead at entry (threshold -inf = frozen
 # query, or every entry bound beaten) skips its DMA gather too. The alive
 # mask crosses loop and branch boundaries as int32 (Mosaic cannot carry
-# i1 vectors through scf.if). `d` is the logical width: lane-padding
-# columns past it are never scanned.
+# i1 vectors through scf.if). `d` is the logical width: the scan runs
+# ceil(d / block_d) blocks, and where block_d does not divide d the last
+# block is ragged — its rows past d are the zero lane padding of the
+# (dx, TC) scratch, which add 0 to every sum; lane padding past that last
+# block is never scanned.
 # ---------------------------------------------------------------------------
 
 
@@ -536,9 +539,13 @@ def _blocked_scan(i, ids_v, sb_ref, thr, pi, gather_tile, block_fn,
     every candidate is dead at entry); `block_fn(off)` returns the block
     at dimension offset `off` as (lower-bound terms, base-metric terms),
     both (block_d, TC) >= 0. A candidate dies when its deflated running
-    sum, or that plus the suffix bound, exceeds `thr`.
+    sum, or that plus the suffix bound, exceeds `thr`. A ragged last block
+    counts only its d % block_d real dimensions in `nd`, and the suffix
+    bound counts the logical dimensions left, max(d - scanned, 0). Where
+    block_d divides d, both reduce to the whole-block forms.
     """
-    nb = d // block_d
+    nb = -(-d // block_d)
+    ragged = d % block_d != 0
     sb_row = sb_ref[i, :]
     ids_row = ids_v[i, :]
     valid = (ids_row >= 0) & (ids_row < n)
@@ -558,9 +565,14 @@ def _blocked_scan(i, ids_v, sb_ref, thr, pi, gather_tile, block_fn,
             bb = jnp.sum(au if base_p == 1.0 else au * au, axis=0)
             s = jnp.where(alive, s + bs, s)
             sbase = jnp.where(alive, sbase + bb, sbase)
-            nd = nd + jnp.where(alive, block_d, 0)
+            width = jnp.minimum(block_d, d - b * block_d) if ragged \
+                else block_d
+            nd = nd + jnp.where(alive, width, 0)
             dead = s * deflate > thr
-            d_rem = (d - (b + 1) * block_d).astype(jnp.float32)
+            d_rem = d - (b + 1) * block_d
+            if ragged:
+                d_rem = jnp.maximum(d_rem, 0)
+            d_rem = d_rem.astype(jnp.float32)
             rem = lp_suffix_bound(sb_row - sbase, base_p, pi, d_rem)
             dead = dead | ((d_rem > 0) & ((s + rem) * deflate > thr))
             return (s, sbase, (alive & ~dead).astype(jnp.int32), nd)
@@ -616,13 +628,14 @@ def _blocked_call(kernel, ids, q, p, thresh, sb, src, extra, *, d: int,
     """pallas_call shared by the abandon and screen kernels: operands are
     the gather head (ids, q, [p]), SMEM thresholds, base sums, whole-array
     VMEM `extra` operands, then the row source `src` (n, dx); d <= dx is
-    the logical width scanned."""
+    the logical width scanned, in ceil(d / block_d) blocks that dx must
+    hold."""
     b, dx = q.shape
     b2, cc = ids.shape
     n = src.shape[0]
     assert b == b2 and b % block_b == 0 and cc % block_c == 0, \
         (b, b2, cc, block_b, block_c)
-    assert d <= dx and d % block_d == 0, (d, dx, block_d)
+    assert -(-d // block_d) * block_d <= dx, (d, dx, block_d)
     assert thresh.shape == (b, 1), (thresh.shape, b)
     vector_p = not is_static_p(p)
     if vector_p:
@@ -669,8 +682,8 @@ def gather_lp_abandon_kernel_call(
     out_dtype=jnp.float32,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0,
-    d % block_d == 0; d = logical width <= dx, default dx — compiled for
-    TPU, dx % 128 == 0). Returns (dists (B, C) root-free power sums with
+    ceil(d / block_d) * block_d <= dx; d = logical width, default dx —
+    compiled for TPU, dx % 128 == 0). Returns (dists (B, C) root-free power sums with
     +inf for abandoned/padding candidates, nd (B, C) int32 dimensions
     scanned).
 
@@ -764,8 +777,8 @@ def gather_lp_screen_kernel_call(
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0,
-    d % block_d == 0; d = logical width <= dx, default dx — compiled for
-    TPU, dx % 128 == 0). Returns (keep (B, C) int32 — 1 iff the candidate
+    ceil(d / block_d) * block_d <= dx; d = logical width, default dx —
+    compiled for TPU, dx % 128 == 0). Returns (keep (B, C) int32 — 1 iff the candidate
     survived the screen and its f32 row must be gathered for the exact
     rerank, nd (B, C) int32 band dimensions scanned).
 

@@ -367,13 +367,26 @@ def pick_abandon_block_d(d: int) -> int:
     (d, TC) layout — enough compute per block to amortize the per-block
     alive-mask branch, fine enough that a junk candidate dies after a
     small fraction of d. Falls back to 16/8 (sublane granularity floor)
-    when they divide d, else a single full-width block: entry-bound-only
-    abandonment, zero mid-scan checks.
+    when they divide d. A width that is not a multiple of 8 (GloVe's
+    100) takes 32 as well: the scan runs ceil(d / 32) blocks and the last
+    one is ragged, its columns past d the zero lane padding of
+    `kernel_rows` (distance-neutral; `nd` counts only the d real ones).
     """
     for bd in (32, 16, 8):
         if d % bd == 0:
             return bd
-    return d
+    return 32
+
+
+def _scan_rows(q, src, d: int, block_d: int, interpret: bool):
+    """(queries, row source) for the blocked scan over ceil(d / block_d)
+    blocks. On the chip the `kernel_rows` lane padding already covers a
+    ragged last block; in interpret mode a narrower source is widened
+    with zero columns to that span, as `kernel_rows` lays it out."""
+    span = -(-d // block_d) * block_d
+    if interpret and src.shape[-1] < span:
+        src = _pad_axis(src, src.ndim - 1, span, 0)
+    return _match_rows(q, src, interpret), src
 
 
 def _pick_tiles_abandon(b: int, c: int, d: int) -> tuple[int, int]:
@@ -404,7 +417,7 @@ def _abandon_impl(q, ids, x, thresh, sb, p, base_p, root=False,
         out, nd = gather_lp_abandon_ref(q, ids, x, thresh, sb, p, base_p, bd)
         return _root_rows(out, p, root), nd
     interpret = bool(interpret)
-    q = _match_rows(q, x, interpret)
+    q, x = _scan_rows(q, x, d, bd, interpret)
     _, cc = ids.shape
     tb, tc = _pick_tiles_abandon(b, cc, q.shape[1])
     tb, tc = block_b or tb, block_c or tc
@@ -471,7 +484,7 @@ def _screen_impl(q, ids, codes, scale, radius, thresh, sb, p, base_p,
         return gather_lp_screen_ref(q, ids, codes, scale, radius, thresh,
                                     sb, p, base_p, bd)
     interpret = bool(interpret)
-    q = _match_rows(q, codes, interpret)
+    q, codes = _scan_rows(q, codes, d, bd, interpret)
     dx = q.shape[1]
     _, cc = ids.shape
     tb, tc = _pick_tiles_abandon(b, cc, dx)

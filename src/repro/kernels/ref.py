@@ -34,6 +34,17 @@ pairwise_lp_ref = pairwise_lp
 rowwise_lp_ref = rowwise_lp
 
 
+def _pad_blocks(t, block_d: int):
+    """A (B, d, C) tile zero-padded along d to whole blocks of block_d, as
+    the kernels read it from their lane-padded scratch. The barrier keeps
+    XLA from folding the zero rows out of the last block's sum, which
+    would reassociate it against the kernel's sum over the padded block."""
+    short = -t.shape[1] % block_d
+    if not short:
+        return t
+    return lax.optimization_barrier(jnp.pad(t, ((0, 0), (0, short), (0, 0))))
+
+
 def gather_lp_abandon_ref(
     q: jnp.ndarray,       # (B, d) f32
     ids: jnp.ndarray,     # (B, C) int32; out-of-range = padding
@@ -52,14 +63,15 @@ def gather_lp_abandon_ref(
     `(dists, nd)` outputs; abandoned and padding candidates score +inf and
     dims scanned after a candidate dies are not counted. The per-block
     reduction mirrors the kernel's transposed (block_d, TC) axis-0 sum.
-    Requires d % block_d == 0 (the dispatcher picks block_d accordingly).
+    Where block_d does not divide d the last of the ceil(d / block_d)
+    blocks is ragged: zero-padded to block_d columns, as the kernel reads
+    it from its lane-padded scratch, with `nd` counting its real ones.
     """
     n, d = x.shape
-    assert d % block_d == 0, (d, block_d)
-    nb = d // block_d
+    nb = -(-d // block_d)
     valid = (ids >= 0) & (ids < n)
     diff = x[jnp.clip(ids, 0, n - 1)] - q[:, None, :]   # (B, C, d)
-    dt = jnp.swapaxes(diff, 1, 2)                       # (B, d, C)
+    dt = _pad_blocks(jnp.swapaxes(diff, 1, 2), block_d)  # (B, nb*bd, C)
     if is_static_p(p):
         p_blk = p_row = p
     else:
@@ -78,7 +90,7 @@ def gather_lp_abandon_ref(
         bb = jnp.sum(a if base_p == 1.0 else a * a, axis=1)
         s = jnp.where(alive, s + bs, s)
         sbase = jnp.where(alive, sbase + bb, sbase)
-        nd = nd + jnp.where(alive, block_d, 0)
+        nd = nd + jnp.where(alive, min(block_d, d - b * block_d), 0)
         dead = s > thr
         d_rem = d - (b + 1) * block_d
         if d_rem > 0:
@@ -119,19 +131,19 @@ def gather_lp_screen_ref(
     Returns (keep (B, C) bool — True iff the candidate survived the
     screen (padding never survives), nd (B, C) int32 band dimensions
     scanned; like the abandon oracle this computes-then-masks off TPU
-    while reporting exactly what the TPU kernel would skip).
+    while reporting exactly what the TPU kernel would skip). A ragged
+    last block is zero-padded as in `gather_lp_abandon_ref`.
     """
     n, d = codes.shape
-    assert d % block_d == 0, (d, block_d)
-    nb = d // block_d
+    nb = -(-d // block_d)
     valid = (ids >= 0) & (ids < n)
     xh = codes[jnp.clip(ids, 0, n - 1)].astype(jnp.float32) \
         * scale[None, None, :]                              # (B, C, d)
     a0 = jnp.abs(xh - q[:, None, :])
     al = jnp.maximum(a0 - radius[None, None, :], 0.0)       # lower bounds
     au = a0 + radius[None, None, :]                         # upper bounds
-    alt = jnp.swapaxes(al, 1, 2)                            # (B, d, C)
-    aut = jnp.swapaxes(au, 1, 2)
+    alt = _pad_blocks(jnp.swapaxes(al, 1, 2), block_d)      # (B, nb*bd, C)
+    aut = _pad_blocks(jnp.swapaxes(au, 1, 2), block_d)
     if is_static_p(p):
         p_blk = p_row = p
     else:
@@ -151,7 +163,7 @@ def gather_lp_screen_ref(
         bb = jnp.sum(ublk if base_p == 1.0 else ublk * ublk, axis=1)
         s = jnp.where(alive, s + bs, s)
         sbase = jnp.where(alive, sbase + bb, sbase)
-        nd = nd + jnp.where(alive, block_d, 0)
+        nd = nd + jnp.where(alive, min(block_d, d - b * block_d), 0)
         dead = s * deflate > thr
         d_rem = d - (b + 1) * block_d
         if d_rem > 0:

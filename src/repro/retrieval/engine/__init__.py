@@ -134,6 +134,10 @@ def default_stats() -> dict:
         # early-abandoning verify buckets report effective T_p as
         # dim_frac_w / n_p (1.0 = full-dimension scans everywhere)
         "dim_frac_w": 0.0,
+        # N_p-weighted dimension blocks the abandoning scan entered per
+        # verified candidate (0 when abandoned at entry, ceil(d / block_d)
+        # when scored whole): scan_blocks_w / n_p, its mid-scan checks
+        "scan_blocks_w": 0.0,
         # N_p-weighted f32 rows gathered (DESIGN.md §10): the compressed
         # two-band path reports gathered-f32-bytes reduction as
         # n_p / f32_rows_w (1.0 = every scored candidate hit f32 HBM)
@@ -642,7 +646,7 @@ class ServingEngine:
             self._collect_wave(wave)
 
     def _collect_wave(self, wave: Wave) -> None:
-        (ids, dists, n_b, n_p, frac, f32, phases, cov, pois, hops,
+        (ids, dists, n_b, n_p, frac, f32, blocks, phases, cov, pois, hops,
          hops_max) = self.pipeline.collect(wave)
         st = self.stats
         health = getattr(self.index, "health", None)
@@ -702,6 +706,7 @@ class ServingEngine:
         st["n_p_probe"] += float(np_pr.sum())
         st["n_p_spill"] += float(np_sp.sum())
         st["dim_frac_w"] += frac_w
+        st["scan_blocks_w"] += float((blocks * n_p).sum())
         st["f32_rows_w"] += f32_w
         st["beam_lane_trips"] += int(hops.sum())
         st["beam_lane_slots"] += int(hops_max.sum()) * wave.n_real

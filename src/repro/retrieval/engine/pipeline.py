@@ -150,10 +150,11 @@ class TwoStagePipeline:
 
     def collect(self, wave: Wave):
         """Materialize one wave on host (the pipeline's only blocking
-        point). Returns (ids, dists, n_b, n_p, frac, f32, phases, cov,
-        pois, hops, hops_max) sliced to real rows; `f32` is the per-row
-        f32-rows-gathered fraction (DESIGN.md §10 — 1.0 off the
-        compressed two-band path);
+        point). Returns (ids, dists, n_b, n_p, frac, f32, blocks, phases,
+        cov, pois, hops, hops_max) sliced to real rows; `f32` is the
+        per-row f32-rows-gathered fraction (DESIGN.md §10 — 1.0 off the
+        compressed two-band path); `blocks` the per-row mean dimension
+        blocks entered per verified candidate (`n_scan_blocks`);
         phases is the per-phase (n_b_probe, n_b_spill, n_p_probe,
         n_p_spill) attribution from the sharded two-phase search (probe =
         everything, spill = 0 for monolithic indexes and the independent
@@ -179,6 +180,7 @@ class TwoStagePipeline:
         n_p = rows(st.n_p)
         frac = rows(st.n_dim_frac)
         f32 = rows(st.n_f32_rows_frac)
+        blocks = rows(st.n_scan_blocks)
         nb_pr, nb_sp = st.phase_n_b()
         np_pr, np_sp = st.phase_n_p()
         phases = (rows(nb_pr), rows(nb_sp), rows(np_pr), rows(np_sp))
@@ -189,5 +191,5 @@ class TwoStagePipeline:
         wave.result = None
         for r in wave.requests:
             r.stage = DONE
-        return (ids, dists, n_b, n_p, frac, f32, phases, cov, pois, hops,
-                hops_max)
+        return (ids, dists, n_b, n_p, frac, f32, blocks, phases, cov, pois,
+                hops, hops_max)

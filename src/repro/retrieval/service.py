@@ -363,6 +363,7 @@ class UniversalVectorService:
         # N_p-weighted scanned-dim fraction (1.0 on full-dimension paths)
         frac = rows(stats.n_dim_frac)
         frac_w = float((frac * n_p).sum())
+        blocks_w = float((rows(stats.n_scan_blocks) * n_p).sum())
         # N_p-weighted f32-rows fraction (DESIGN.md §10 two-band scan)
         f32_w = float((rows(stats.n_f32_rows_frac) * n_p).sum())
         # per-phase attribution (probe == total for monolithic/independent)
@@ -384,6 +385,7 @@ class UniversalVectorService:
         st["n_p_probe"] += float(np_pr.sum())
         st["n_p_spill"] += float(np_sp.sum())
         st["dim_frac_w"] += frac_w
+        st["scan_blocks_w"] += blocks_w
         st["f32_rows_w"] += f32_w
         pb = st["per_base"]["G1" if base == 1.0 else "G2"]
         pb["queries"] += n_real

@@ -29,7 +29,7 @@ import pytest
 
 from repro.kernels import ops
 
-D_GRID = [96, 128, 256, 960]   # Deep-96, SIFT, Deep-256, GIST widths
+D_GRID = [96, 100, 128, 256, 960]  # Deep-96, GloVe, SIFT, Deep-256, GIST
 P_GRID = [0.8, 2.0, "vector"]  # slow family, MXU family, per-row p
 B, C, N = 16, 300, 4099        # ragged batch / candidate / corpus sizes
 
@@ -126,7 +126,7 @@ def test_vector_p_pairwise_kernel_compiles(one_chip, d):
 _HLO_RESULT = re.compile(r"=\s*(\w+\[[\d,]*\])(?:\{[^}]*\})?\s+([\w\-]+)\(")
 
 
-@pytest.mark.parametrize("d", [96, 960])
+@pytest.mark.parametrize("d", [96, 100, 960])
 def test_verification_program_holds_no_corpus_copy(one_chip, d):
     """The mixed-p verification program reads the corpus where the index
     laid it out: an (N, dx) array comes only from the parameter and the
@@ -149,6 +149,17 @@ def test_verification_program_holds_no_corpus_copy(one_chip, d):
     made = {op for shape, op in _HLO_RESULT.findall(text)
             if shape == f"f32[{N},{dx}]"}
     assert made <= {"parameter", "get-tuple-element"}, made
+
+
+def test_block_choice_is_unchanged_where_8_divides_d():
+    """Widths that are a multiple of 8 keep the widest of 32, 16 and 8
+    that divides them, so their compiled scans stay as they were; any
+    other width takes 32-dimension blocks with a ragged last one."""
+    for d in range(8, 4097, 8):
+        want = 32 if d % 32 == 0 else 16 if d % 16 == 0 else 8
+        assert ops.pick_abandon_block_d(d) == want, d
+    for d in (1, 4, 36, 100, 300, 4095):
+        assert ops.pick_abandon_block_d(d) == 32, d
 
 
 def test_compiled_kernels_refuse_an_unaligned_row_source():

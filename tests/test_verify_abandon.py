@@ -201,7 +201,7 @@ def test_pick_abandon_block_d():
     assert pick_abandon_block_d(256) == 32
     assert pick_abandon_block_d(48) == 16
     assert pick_abandon_block_d(40) == 8
-    assert pick_abandon_block_d(100) == 100  # ragged: one full-width block
+    assert pick_abandon_block_d(100) == 32  # ragged: 4 blocks, the last 4 wide
 
 
 # ---------------------------------------------------------------------------
