@@ -28,65 +28,97 @@ WIDTHS = (1, 2, 4, 8)
 TIMING_REPS = 3
 
 
-def _merge_microbench(quick: bool) -> dict:
-    """Cost of the level-0 (ef + W*m0) merge's expanded-mask construction
-    (DESIGN.md §2.1): the historical code rebuilt `jnp.isinf` over the
-    full concatenated array every hop; the hoisted form masks only the
-    (W*m0) frontier half, relying on the invariant that beam entries with
-    inf distance always carry exp=1 (sentinel init + every earlier
-    merge's forcing). Both variants are measured here so the note in
-    DESIGN.md §2.1 stays pinned to data; the merge sort itself dominates,
-    which is why the win is a few percent of the hop, not a multiple.
+MERGE_LANES = 512
+MERGE_FRONTIERS = (32, 64, 128, 256, 512)
+
+
+def _merge_microbench(quick: bool, lanes: int = MERGE_LANES, ef: int = 600,
+                      frontiers=MERGE_FRONTIERS, m0: int = 32,
+                      trips: int = 64, seed: int = 0) -> list[dict]:
+    """The level-0 loop's merge alone, one sort against the dense merge.
+
+    Each frontier width F (= W*m0 neighbours per hop) runs `trips` hops of
+    the merge and nothing else: every lane (one query of one segment)
+    carries a sorted (ef,) beam of (dist, id, expanded) and merges a fresh
+    F-entry frontier into it per hop, most of it inf (already visited),
+    vmapped over the lanes inside one `fori_loop` as the beam loop runs it.
+    A loop that only reads the frontier is timed too, and subtracted, so
+    `us_per_trip_*` is the merge's own time per hop for all lanes. The
+    unstable sort the loop ran before the stable one (`sort_unstable`) is
+    timed beside it, for the frontiers above the crossover that still sort.
+    DENSE_MERGE_MAX_FRONTIER (core/hnsw.py) is read from this table: the
+    largest F at which the dense merge is still the faster.
+
+      PYTHONPATH=src python -m benchmarks.beam_width   # prints the tables
     """
-    ef, w, m0 = 600, 4, 32
-    reps = 200 if quick else 1000
-    rng = np.random.default_rng(0)
-    dist = jnp.asarray(rng.exponential(size=ef).astype(np.float32))
-    dv = jnp.asarray(
-        np.where(rng.random(w * m0) < 0.3, np.inf,
-                 rng.exponential(size=w * m0)).astype(np.float32))
-    ids = jnp.asarray(rng.permutation(ef * 4)[:ef].astype(np.int32))
-    nbrs = jnp.asarray(rng.permutation(ef * 4)[:w * m0].astype(np.int32))
-    exp = jnp.asarray((rng.random(ef) < 0.5).astype(np.int32))
+    from repro.core.hnsw import _merge_dense, _merge_sort
 
-    @jax.jit
-    def merge_full_mask(ids, dist, exp, nbrs, dv):
-        all_ids = jnp.concatenate([ids, nbrs])
-        all_dist = jnp.concatenate([dist, dv])
-        all_exp = jnp.concatenate([exp, jnp.zeros((w * m0,), jnp.int32)])
-        all_exp = jnp.where(jnp.isinf(all_dist), 1, all_exp)
-        sd, si, se = jax.lax.sort((all_dist, all_ids, all_exp), num_keys=1)
-        return si[:ef], sd[:ef], se[:ef]
+    def merge_sort_unstable(beam, front):
+        cat = tuple(jnp.concatenate([b, f]) for b, f in zip(beam, front))
+        out = jax.lax.sort(cat, num_keys=1)
+        return tuple(x[:ef] for x in out)
 
-    @jax.jit
-    def merge_hoisted(ids, dist, exp, nbrs, dv):
-        all_ids = jnp.concatenate([ids, nbrs])
-        all_dist = jnp.concatenate([dist, dv])
-        all_exp = jnp.concatenate([exp, jnp.isinf(dv).astype(jnp.int32)])
-        sd, si, se = jax.lax.sort((all_dist, all_ids, all_exp), num_keys=1)
-        return si[:ef], sd[:ef], se[:ef]
+    if quick:
+        lanes, trips = min(lanes, 64), min(trips, 8)
+        frontiers = tuple(f for f in frontiers if f <= 128)
+    reps = 3 if quick else 10
+    rng = np.random.default_rng(seed)
+    bd = np.sort(rng.exponential(size=(lanes, ef)).astype(np.float32), axis=1)
+    bd[:, ef // 2:] = np.inf
+    beam = (jnp.asarray(bd),
+            jnp.asarray(rng.integers(0, 1 << 20, size=(lanes, ef),
+                                     dtype=np.int32)),
+            jnp.asarray(np.isinf(bd).astype(np.int32)))
+    rows = []
+    for f in frontiers:
+        fd = rng.exponential(size=(trips, lanes, f)).astype(np.float32)
+        fd[rng.random(fd.shape) < 0.7] = np.inf
+        fd = jnp.asarray(fd)
+        fi = jnp.asarray(rng.integers(0, 1 << 20, size=(trips, lanes, f),
+                                      dtype=np.int32))
 
-    def timed(fn):
-        jax.block_until_ready(fn(ids, dist, exp, nbrs, dv))
-        t0 = time.time()
-        for _ in range(reps):
-            out = fn(ids, dist, exp, nbrs, dv)
-        jax.block_until_ready(out)
-        return (time.time() - t0) / reps * 1e6
+        def make(form):
+            @jax.jit
+            def loop(beam, fd, fi):
+                def body(t, beam):
+                    front = (fd[t], fi[t], jnp.isinf(fd[t]).astype(jnp.int32))
+                    if form is None:
+                        return (beam[0], beam[1] + front[1][:, :1], beam[2])
+                    return jax.vmap(form)(beam, front)
 
-    us_full = timed(merge_full_mask)
-    us_hoist = timed(merge_hoisted)
-    row = {
-        "dataset": "merge-microbench", "p": None, "k": None,
-        "expand_width": w, "ef": ef, "m0": m0,
-        "us_per_merge_full_mask": round(us_full, 2),
-        "us_per_merge_hoisted": round(us_hoist, 2),
-        "mask_hoist_speedup": round(us_full / us_hoist, 3),
-    }
-    print(f"  merge micro-bench (ef={ef}, W*m0={w * m0}): full-mask "
-          f"{us_full:.1f}us vs hoisted {us_hoist:.1f}us "
-          f"({row['mask_hoist_speedup']}x)", flush=True)
-    return row
+                return jax.lax.fori_loop(0, trips, body, beam)
+            return loop
+
+        def timed(fn):
+            out = jax.block_until_ready(fn(beam, fd, fi))
+            t0 = time.perf_counter()
+            for _ in range(reps):
+                jax.block_until_ready(fn(beam, fd, fi))
+            return out, (time.perf_counter() - t0) / reps / trips * 1e6
+
+        _, us_null = timed(make(None))
+        out_s, us_sort = timed(make(_merge_sort))
+        out_d, us_dense = timed(make(_merge_dense))
+        _, us_unstable = timed(make(merge_sort_unstable))
+        for x, y in zip(out_s, out_d):
+            assert np.array_equal(np.asarray(x), np.asarray(y))
+        row = {
+            "dataset": "merge-microbench", "p": None, "k": None,
+            "expand_width": f // m0,
+            "device": jax.devices()[0].device_kind,
+            "lanes": lanes, "ef": ef, "frontier": f, "trips": trips,
+            "us_per_trip_null": round(us_null, 2),
+            "us_per_trip_sort": round(us_sort - us_null, 2),
+            "us_per_trip_dense": round(us_dense - us_null, 2),
+            "us_per_trip_sort_unstable": round(us_unstable - us_null, 2),
+        }
+        rows.append(row)
+        print(f"  merge micro-bench lanes={lanes} ef={ef} F={f}: dense "
+              f"{row['us_per_trip_dense']}us vs sort "
+              f"{row['us_per_trip_sort']}us (unstable "
+              f"{row['us_per_trip_sort_unstable']}us) per trip "
+              f"(loop alone {row['us_per_trip_null']}us)", flush=True)
+    return rows
 
 
 VISITED_LANES = (128, 512)
@@ -209,7 +241,7 @@ def run(quick: bool = False):
     base = rows[0]
     for r in rows[1:]:
         r["hops_speedup_vs_w1"] = round(base["mean_hops"] / r["mean_hops"], 2)
-    rows.append(_merge_microbench(quick))
+    rows.extend(_merge_microbench(quick))
     rows.extend(_visited_microbench(quick))
     return rows
 
@@ -218,5 +250,6 @@ if __name__ == "__main__":
     import json
     import sys
 
-    table = _visited_microbench(quick="--quick" in sys.argv)
-    print(json.dumps(table))
+    quick = "--quick" in sys.argv
+    print(json.dumps(_merge_microbench(quick)))
+    print(json.dumps(_visited_microbench(quick)))

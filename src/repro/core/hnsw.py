@@ -14,7 +14,9 @@ model. We restructure it (DESIGN.md §2) as fixed-width tensor ops inside
     set a per-query visited *bitmask* (uint32 words; dense compares over the
     word axis up to DENSE_VISITED_MAX_WORDS, a carry-free scatter-add of
     distinct bits above), compute base-metric distances for unseen neighbors in
-    one fused block, and merge via a single `lax.sort`. W=1 is the classic
+    one fused block, and merge the W*m0-entry frontier into the already-sorted
+    beam (ranks by dense compares up to DENSE_MERGE_MAX_FRONTIER entries, one
+    stable `lax.sort` of beam and frontier together above). W=1 is the classic
     single-expansion search.
 
 The whole search vmaps over the query batch and jits; query batches shard
@@ -205,6 +207,94 @@ def _visited_test_and_set(visited, word, bit, eligible):
     return _visited_scatter(visited, word, bit, eligible)
 
 
+# Widest frontier (W*m0 entries per hop) that the level-0 loop merges into its
+# sorted beam by ranks and dense compares (`_merge_dense`); a wider frontier
+# re-sorts beam and frontier together (`_merge_sort`). The dense merge costs
+# O(ef * W*m0) element work per query per hop, the sort
+# O((ef + W*m0) * log^2(ef + W*m0)), so the choice rests on the static widths
+# alone. Set from benchmarks/beam_width.py's merge micro-bench on a TPU v5e
+# (PERF.md, "Findings").
+DENSE_MERGE_MAX_FRONTIER = 64
+
+
+def _merge_sort(beam, front):
+    """The level-0 merge as one stable sort of beam and frontier together.
+
+    beam: (dist (ef,), ids, exp), sorted by dist; front: (dist (F,), ids,
+    exp) in any order. Returns the first ef of the concatenation sorted by
+    dist, ties in concatenation order.
+    """
+    ef = beam[0].shape[0]
+    cat = tuple(jnp.concatenate([b, f]) for b, f in zip(beam, front))
+    out = jax.lax.sort(cat, num_keys=1, is_stable=True)
+    return tuple(x[:ef] for x in out)
+
+
+# jitted so that its ~180 ops are traced once per shape, not once per
+# search program that holds the loop: a serving warm-up traces hundreds of
+# those programs, even from a warm compile cache, at ~60 ms a merge.
+@jax.jit
+def _merge_dense(beam, front):
+    """`_merge_sort`'s result without a sort: the beam is already sorted.
+
+    Each entry's place in the stable order is counted with dense compares.
+    Frontier entry j lands at (beam entries <= it: the beam wins ties) +
+    (frontier entries before it: smaller, or equal and earlier); beam entry
+    i at i + (frontier entries strictly below it). The frontier is placed by
+    a one-hot over its F entries per output slot (exactly one term is live);
+    the beam, whose shifts rise with i, by a shifter of log2(F + 1) stages
+    that moves each entry by one bit of its shift, highest bit first, where
+    no two entries meet. Both keep values bit for bit, so the result equals
+    `_merge_sort`'s on NaN-free distances (tests/test_merge_forms.py).
+    """
+    bd, fd = beam[0], front[0]
+    ef, f = bd.shape[0], fd.shape[0]
+    le = bd[:, None] <= fd[None, :]                             # (ef, F)
+    j = jnp.arange(f)
+    # before[j, j']: frontier j' precedes frontier j in the stable order
+    before = (fd[None, :] < fd[:, None]) | (
+        (fd[None, :] == fd[:, None]) & (j[None, :] < j[:, None]))
+    pos_f = le.sum(0, dtype=jnp.int32) + before.sum(1, dtype=jnp.int32)
+    shift = f - le.sum(1, dtype=jnp.int32)                      # (ef,)
+
+    hit = pos_f[:, None] == jnp.arange(ef, dtype=jnp.int32)     # (F, ef)
+    is_f = hit.any(0)
+
+    def lowest(x):
+        if jnp.issubdtype(x.dtype, jnp.floating):
+            return jnp.array(-jnp.inf, x.dtype)
+        return jnp.array(jnp.iinfo(x.dtype).min, x.dtype)
+
+    placed = [jnp.where(hit, x[:, None], lowest(x)).max(0) for x in front]
+
+    vals = list(beam)
+    for k in reversed(range(f.bit_length())):
+        s = 1 << k
+        moves = ((shift >> k) & 1) == 1
+        if s >= ef:  # every mover leaves the first ef slots
+            shift = jnp.where(moves, 0, shift)
+            continue
+
+        def down(x):
+            return jnp.concatenate([jnp.zeros((s,), x.dtype), x[:-s]])
+
+        arrives = down(moves)
+        vals = [jnp.where(arrives, down(x), x) for x in vals]
+        # an entry that left keeps a stale copy behind with shift 0: it
+        # never moves again and the entry due there overwrites it
+        shift = jnp.where(arrives, down(shift) & (s - 1),
+                          jnp.where(moves, 0, shift))
+    return tuple(jnp.where(is_f, p, v) for p, v in zip(placed, vals))
+
+
+def _merge_beam(beam, front):
+    """Dense merge up to DENSE_MERGE_MAX_FRONTIER frontier entries, one
+    sort above; the two give identical results (tests/test_merge_forms.py)."""
+    if front[0].shape[0] <= DENSE_MERGE_MAX_FRONTIER:
+        return _merge_dense(beam, front)
+    return _merge_sort(beam, front)
+
+
 def _greedy_descend(q, X, adj_l, g2l, ep, ep_dist, nb, p, max_hops):
     """Greedy ef=1 search on one upper layer. Returns (ep, ep_dist, nb)."""
     n = X.shape[0]
@@ -237,8 +327,9 @@ def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
     `width` (W) is the multi-expansion factor (DESIGN.md §2 hot path): each
     `while_loop` hop expands the W closest unexpanded beam entries at once —
     one (W*m0,) gather, one batched visited test-and-set, one fused distance
-    block, one merge sort. Trip count drops ~W×; each trip's tensor work is
-    W× wider, which the hardware prefers to W serialized skinny hops. W=1
+    block, one merge of the frontier into the sorted beam. Trip count drops
+    ~W×; each trip's tensor work is W× wider, which the hardware prefers to
+    W serialized skinny hops. W=1
     reproduces the classic single-expansion search exactly.
 
     `thresh` (traced scalar, or None for the unmodified program) is the
@@ -305,22 +396,18 @@ def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
         if thresh is not None:
             # cross-segment early-cut: evaluated (counted above, visited
             # stays set) but above the inherited global bound -> inf, which
-            # the merge below flags expanded and sorts past the beam
+            # the merge below flags expanded and places after every finite
+            # entry
             dv = jnp.where(dv <= thresh, dv, jnp.inf)
-        # 5. merge beam + frontier with a single sort, keep top-ef
-        all_ids = jnp.concatenate([ids, nbrs])
-        all_dist = jnp.concatenate([dist, dv])
-        # frontier entries join unexpanded; anything with inf distance
+        # 5. merge the frontier into the sorted beam, keep the first ef.
+        # Frontier entries join unexpanded; anything with inf distance
         # (sentinels, masked duplicates) is flagged expanded so it can never
-        # be selected -> guarantees loop progress. The isinf mask is needed
-        # on the (W*m0) frontier half only: beam entries with inf distance
-        # already carry exp=1 (sentinel init + this very forcing in every
-        # earlier merge), so rebuilding it over the full (ef + W*m0) concat
-        # each hop was redundant work (measured in
-        # benchmarks/beam_width.py's merge micro-bench).
-        all_exp = jnp.concatenate([exp, jnp.isinf(dv).astype(jnp.int32)])
-        sd, si, se = jax.lax.sort((all_dist, all_ids, all_exp), num_keys=1)
-        return (si[:ef], sd[:ef], se[:ef], visited, nb, hops + 1)
+        # be selected -> guarantees loop progress. Beam entries with inf
+        # distance already carry exp=1 (sentinel init + this very forcing
+        # in every earlier merge), so only the frontier needs the mask.
+        front = (dv, nbrs, jnp.isinf(dv).astype(jnp.int32))
+        dist, ids, exp = _merge_beam((dist, ids, exp), front)
+        return (ids, dist, exp, visited, nb, hops + 1)
 
     s = (ids0, dist0, exp0, visited0, nb0, jnp.int32(0))
     ids, dist, exp, visited, nb, hops = jax.lax.while_loop(cond, body, s)
