@@ -1,4 +1,4 @@
-"""Benchmark aggregator: one function per paper table/figure + roofline.
+"""Benchmark aggregator: one function per paper table/figure.
 
   PYTHONPATH=src python -m benchmarks.run [--quick] [--only fig1,sharded]
 
@@ -52,7 +52,6 @@ def main(argv=None) -> int:
         fig2_recall_vs_p,
         fig3_param_tuning,
         fig4_uhnsw_vs_hnsw,
-        roofline,
         serving,
         sharded_index,
         table2_uhnsw_vs_mlsh,
@@ -68,7 +67,6 @@ def main(argv=None) -> int:
         "fig4": fig4_uhnsw_vs_hnsw.run,
         "sharded": sharded_index.run,
         "beam": beam_width.run,
-        "roofline": roofline.run,
         "serving": serving.run,
         "health": serving.run_faulted,
         "verify": verify.run,

@@ -2,80 +2,57 @@
 
     PYTHONPATH=src python examples/knn_lm_serving.py
 
-1. Briefly trains a small LM on the synthetic Markov stream.
-2. Builds a U-HNSW datastore of (hidden state -> next token) pairs from the
-   trained model's own activations.
-3. Serves held-out contexts with plain LM decoding and with kNN-LM mixing,
-   sweeping the retrieval metric p — the knob the paper makes free.
+1. Draws a token stream from a sparse Markov chain and, for each position,
+   a context "hidden state": the embedding of the current token plus
+   heavy-tailed (Laplace) noise, as an LM's last layer would carry it.
+2. Builds a U-HNSW datastore of (hidden state -> next token) pairs.
+3. Scores held-out contexts with a plain unigram LM (a parametric model
+   that has not learned the transitions) and with kNN-LM mixing, sweeping
+   the retrieval metric p — the knob the paper makes free.
 
-Expected: kNN-LM lowers NLL vs the plain LM, and the best p varies with
+Expected: kNN-LM lowers NLL vs the plain LM, and the best p depends on
 the datastore geometry (the paper's motivation for universal-p serving).
+One temperature serves every p here, so at p = 0.5, whose distances run
+largest, the neighbour weights are too sharp and the mix can lose.
 """
 
-import jax
-import jax.numpy as jnp
 import numpy as np
 
-from repro.configs.base import get_arch
-from repro.data.pipeline import SyntheticTokenPipeline
-from repro.dist.sharding import Runtime, set_mesh
-from repro.launch.mesh import make_local_mesh
-from repro.models.model import _head_matrix, forward_train
 from repro.retrieval.knn_lm import KnnLM
-from repro.train.step import TrainConfig, init_train_state, make_train_step
 
 
 def main():
-    cfg = get_arch("tinyllama_1_1b", smoke=True)
-    rt = Runtime(mesh=make_local_mesh())
-    tc = TrainConfig(lr=3e-3, warmup_steps=5, total_steps=60)
-    pipe = SyntheticTokenPipeline(cfg, global_batch=8, seq_len=64, seed=0)
+    rng = np.random.default_rng(0)
+    vocab, d, n_store, n_eval = 64, 32, 5000, 256
+    # each token has 3 likely successors: the structure kNN-LM recovers
+    succ = rng.integers(0, vocab, size=(vocab, 3))
+    embed = rng.standard_normal((vocab, d)).astype(np.float32)
 
-    with set_mesh(rt.mesh):
-        print("training a small LM on the synthetic stream ...")
-        state = init_train_state(cfg, rt, tc, jax.random.PRNGKey(0))
-        step = jax.jit(make_train_step(cfg, rt, tc), donate_argnums=(0,))
-        for i in range(40):
-            state, m = step(state, pipe.batch(i))
-            if i % 10 == 0:
-                print(f"  step {i}: loss {float(m['loss']):.3f}")
-        params = state["params"]
+    def stream(n):
+        tok = np.empty(n + 1, np.int64)
+        tok[0] = rng.integers(vocab)
+        for i in range(n):
+            tok[i + 1] = succ[tok[i], rng.integers(3)]
+        hidden = embed[tok[:-1]] + rng.laplace(
+            scale=0.5, size=(n, d)).astype(np.float32)
+        return hidden.astype(np.float32), tok[1:]
 
-        print("building the (hidden -> next token) datastore ...")
-        # the datastore covers the serving distribution (in production it is
-        # built from the corpus the service answers over — kNN-LM's value is
-        # recalling continuations the parametric model undertrained on)
-        hiddens, nexts = [], []
-        fwd = jax.jit(lambda p, b: forward_train(p, b, cfg, rt))
-        for i in list(range(40, 48)) + [99]:
-            batch = pipe.batch(i)
-            h = fwd(params, batch)
-            hiddens.append(np.asarray(h, dtype=np.float32).reshape(-1, cfg.d_model))
-            nexts.append(np.asarray(batch["labels"]).reshape(-1))
-        hidden = np.concatenate(hiddens)[:5000]
-        next_tok = np.concatenate(nexts)[:5000]
-        knn = KnnLM.build_from_hidden(hidden, next_tok, cfg.vocab_size,
-                                      m=8, k=8, lam=0.3, temperature=1.0)
+    print("building the (hidden -> next token) datastore ...")
+    hidden, next_tok = stream(n_store)
+    knn = KnnLM.build_from_hidden(hidden, next_tok, vocab, m=8, k=8,
+                                  lam=0.3, temperature=1.0)
 
-        print("evaluating held-out contexts: plain LM vs kNN-LM across p ...")
-        batch = pipe.batch(99)
-        h = np.asarray(fwd(params, batch), dtype=np.float32)
-        head = np.asarray(_head_matrix(params, cfg), dtype=np.float32)
-        labels = np.asarray(batch["labels"])
-        B, S = labels.shape
-        flat_h = h.reshape(-1, cfg.d_model)[:256]
-        flat_y = labels.reshape(-1)[:256]
-        logits = flat_h @ head
-        logits = logits[:, : cfg.vocab_size]
-        lm_lp = jax.nn.log_softmax(jnp.asarray(logits), axis=-1)
-        lm_lp = np.asarray(lm_lp)
-        nll_lm = -lm_lp[np.arange(len(flat_y)), flat_y].mean()
-        print(f"  plain LM NLL: {nll_lm:.3f}")
-        for p in [0.5, 0.8, 1.0, 1.4, 2.0]:
-            mixed = knn.mix(lm_lp, flat_h, p)
-            nll = -mixed[np.arange(len(flat_y)), flat_y].mean()
-            print(f"  kNN-LM (p={p}): NLL {nll:.3f} "
-                  f"({'better' if nll < nll_lm else 'worse'})")
+    print("evaluating held-out contexts: plain LM vs kNN-LM across p ...")
+    q, gold = stream(n_eval)
+    unigram = np.bincount(next_tok, minlength=vocab) + 1.0
+    lm_lp = np.log(np.broadcast_to(unigram / unigram.sum(), (n_eval, vocab)))
+    nll_lm = -lm_lp[np.arange(n_eval), gold].mean()
+    print(f"  plain LM NLL: {nll_lm:.3f}")
+    for p in [0.5, 0.8, 1.0, 1.4, 2.0]:
+        mixed = knn.mix(lm_lp, q, p)
+        nll = -mixed[np.arange(n_eval), gold].mean()
+        print(f"  kNN-LM (p={p}): NLL {nll:.3f} "
+              f"({'better' if nll < nll_lm else 'worse'})")
 
 
 if __name__ == "__main__":

@@ -1,17 +1,13 @@
-"""repro: U-HNSW (ANNS under universal Lp metrics) as a first-class retrieval
-feature of a multi-pod JAX LM training/serving framework.
+"""repro: U-HNSW (ANNS under universal Lp metrics) as a served vector index
+on JAX, with Pallas kernels for the TPU.
 
 Layers:
   repro.core       — the paper's contribution (U-HNSW, HNSW, MLSH baseline)
   repro.index      — segmented sharded U-HNSW + streaming-insert delta tier
   repro.kernels    — Pallas TPU kernels for Lp distance computation
-  repro.models     — LM model zoo (10 assigned architectures)
-  repro.dist       — mesh / sharding / collective helpers
-  repro.train      — training loop substrate
-  repro.serve      — prefill/decode serving substrate
-  repro.retrieval  — U-HNSW <-> LM integration (kNN-LM / RAG)
-  repro.checkpoint — sharded fault-tolerant checkpointing
-  repro.launch     — mesh construction, dry-run, train/serve entry points
+  repro.dist       — mesh / logical-axis sharding helpers
+  repro.retrieval  — serving engine, vector service, kNN-LM over the index
+  repro.launch     — the retrieval serving entry point + compile cache
 """
 
 __version__ = "0.1.0"

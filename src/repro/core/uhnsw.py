@@ -149,12 +149,25 @@ class CandidateSet(NamedTuple):
 
 
 class SearchStats(NamedTuple):
-    n_b: jax.Array        # (B,) base-metric Q2D evaluation counts
-    n_p: jax.Array        # (B,) Lp Q2D evaluation counts
-    iterations: jax.Array  # () verification loop iterations executed
-    base_p: float | np.ndarray  # which base metric generated candidates:
-                                # scalar for a single-p batch, (B,) array
-                                # for a mixed-p batch (DESIGN.md §6)
+    """The one carrier of a search's counters, from the verifier to the
+    serving engine's stats.
+
+    A new per-row counter is named here (with its default), in the code
+    that computes it, and in `retrieval.engine.accumulate_stats`; a
+    candidate-generation one also in `with_candidates`, a fraction
+    weighted by n_p also in `ShardedUHNSW._merge_delta`. Per-row
+    fields are (B,) device arrays, or a Python scalar that holds for every
+    row; `ROW_FIELDS` lists them. `SKIPPED` holds the value each
+    verification counter takes on a row whose p is its base metric (the
+    skip, paper §3 preamble): the skip paths and `mask_base_rows` read it.
+    """
+
+    n_b: jax.Array | int = 0  # (B,) base-metric Q2D evaluation counts
+    n_p: jax.Array | int = 0  # (B,) Lp Q2D evaluation counts
+    iterations: jax.Array | int = 0  # () verification loop iterations
+    base_p: float | np.ndarray = 1.0  # which base metric generated
+                                # candidates: scalar for a single-p batch,
+                                # (B,) array for a mixed-p batch (§6)
     hops: jax.Array | int = 0  # (B,) level-0 while_loop trips (one trip
                                # expands up to expand_width beam entries)
     n_dim_frac: jax.Array | float = 1.0  # (B,) fraction of verification
@@ -193,12 +206,11 @@ class SearchStats(NamedTuple):
     # delta rows) / total rows, computed host-side from the health tracker
     # at candidate-generation time. Monolithic searches always report 1.0.
     coverage_frac: float = 1.0
-    degraded: bool = False  # coverage_frac < 1.0
     poisoned: jax.Array | float = 0.0  # (B,) 1.0 where the query-time
         # NaN/inf guard masked non-finite gathered distances (the engine
         # bisects this back to a segment and quarantines it)
     hops_max: jax.Array | int = 0  # CandidateSet.hops_max, carried
-        # through stage B (0 where no staged candidates fed the stats)
+        # through stage B (0 where no single candidate set fed the stats)
     n_scan_blocks: jax.Array | float = 0.0  # (B,) mean dimension blocks
         # the abandoning scan entered per verified candidate (DESIGN.md
         # §8): 0 for a candidate abandoned at entry, ceil(d / block_d) for
@@ -206,15 +218,60 @@ class SearchStats(NamedTuple):
         # Each block past the first is one mid-scan abandonment check.
         # Weighted by n_p like n_dim_frac; 0.0 where nothing was verified.
 
+    ROW_FIELDS = ("n_b", "n_p", "hops", "n_dim_frac", "n_b_probe",
+                  "n_b_spill", "n_p_probe", "n_p_spill", "n_f32_rows_frac",
+                  "n_band_frac", "poisoned", "n_scan_blocks")
+    SKIPPED = {"n_p": 0, "n_dim_frac": 1.0, "n_f32_rows_frac": 1.0,
+               "n_band_frac": 0.0, "n_scan_blocks": 0.0}
+
+    @property
+    def degraded(self) -> bool:
+        """Served below full coverage (some segment was quarantined)."""
+        return self.coverage_frac < 1.0
+
+    def row(self, name: str):
+        """Per-row field `name`, a probe split's None read as its total."""
+        value = getattr(self, name)
+        if value is None:  # n_b_probe / n_p_probe: all work was probe
+            return getattr(self, name.removesuffix("_probe"))
+        return value
+
     def phase_n_b(self):
         """(probe, spill) N_b split with the None default resolved."""
-        probe = self.n_b if self.n_b_probe is None else self.n_b_probe
-        return probe, self.n_b_spill
+        return self.row("n_b_probe"), self.n_b_spill
 
     def phase_n_p(self):
         """(probe, spill) N_p split with the None default resolved."""
-        probe = self.n_p if self.n_p_probe is None else self.n_p_probe
-        return probe, self.n_p_spill
+        return self.row("n_p_probe"), self.n_p_spill
+
+    def with_candidates(self, cands: CandidateSet) -> "SearchStats":
+        """This record with the candidate-generation fields of `cands`."""
+        return self._replace(
+            n_b=cands.n_b, hops=cands.hops, base_p=cands.base_p,
+            n_b_probe=cands.n_b_probe, n_b_spill=cands.n_b_spill,
+            poisoned=cands.poisoned, coverage_frac=cands.coverage_frac,
+            hops_max=cands.hops_max)
+
+    def host_rows(self, n: int) -> "SearchStats":
+        """The record on the host: every per-row field as n float64 rows
+        (a scalar repeated; padding rows past n dropped), `hops_max` as a
+        flat float64 array and `coverage_frac` as a float."""
+        def rows(x):
+            x = np.asarray(x, dtype=np.float64)
+            return x[:n] if x.ndim else np.full(n, float(x))
+
+        return self._replace(
+            **{f: rows(self.row(f)) for f in self.ROW_FIELDS},
+            hops_max=np.asarray(self.hops_max, np.float64).reshape(-1),
+            coverage_frac=float(self.coverage_frac))
+
+
+def skipped_stats(cands: CandidateSet) -> SearchStats:
+    """Stats of a batch whose p is its base metric: no row verifies, and
+    each verification counter takes its `SearchStats.SKIPPED` value."""
+    skip = dict(SearchStats.SKIPPED)
+    skip["n_p"] = jnp.full_like(cands.n_b, skip["n_p"])  # callers sum it
+    return SearchStats(**skip).with_candidates(cands)
 
 
 def _verify_impl(
@@ -576,12 +633,13 @@ def verify_candidates(
     """Early-terminated exact-Lp re-ranking (Algorithm 1 lines 7-11).
 
     Returns (ids (B, k) int32, dists (B, k) f32 with root applied,
-    n_p (B,) int32, iters () int32, n_dim_frac (B,) f32,
-    n_f32_rows_frac (B,) f32, n_band_frac (B,) f32, n_scan_blocks (B,)
-    f32) — n_f32_rows_frac and n_band_frac are the SearchStats
-    byte-traffic counters (1.0 / 0.0 off the two-band path), and
-    n_scan_blocks the mean dimension blocks of width block_d (None:
-    `pick_abandon_block_d`) entered per verified candidate.
+    SearchStats) with the verification fields filled: n_p (B,) int32,
+    iterations () int32, n_dim_frac (B,), the byte-traffic counters
+    n_f32_rows_frac and n_band_frac ((B,) on the two-band path, the
+    record's defaults 1.0 / 0.0 elsewhere) and n_scan_blocks (B,), the
+    mean dimension blocks of width block_d (None: `pick_abandon_block_d`)
+    entered per verified candidate. The candidate-generation fields are
+    the caller's (`SearchStats.with_candidates`).
 
     p follows the scalar-vs-vector contract (DESIGN.md §6): a Python float
     re-ranks the whole batch under one metric (one compiled program per p);
@@ -615,12 +673,10 @@ def verify_candidates(
     beams / merges) and are scored as inf so they can never enter R.
     `interpret` forwards to the kernel dispatch (None = backend-aware).
     """
-    B, d = Q.shape
+    d = Q.shape[1]
     from repro.kernels.ops import pick_abandon_block_d
 
     n_blocks = -(-d // (block_d or pick_abandon_block_d(d)))
-    ones = jnp.ones((B,), jnp.float32)
-    zeros = jnp.zeros((B,), jnp.float32)
     if abandon and band is not None:
         if cand_base is None:
             cand_base = jnp.zeros(cand_ids.shape, jnp.float32)
@@ -635,8 +691,12 @@ def verify_candidates(
                 Q, Qp, cand_ids, cand_base, X, band.rows, band.scale,
                 band.radius, jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
                 k, kappa, tau, float(base_p), interpret, block_d)
+        ids, dists, n_p, iters, frac, f32f, bandf = out
         # the f32 rows scored are whole rows: n_dim_frac is their share
-        return (*out, out[4] * n_blocks)
+        return ids, dists, SearchStats(
+            n_p=n_p, iterations=iters, base_p=base_p, n_dim_frac=frac,
+            n_f32_rows_frac=f32f, n_band_frac=bandf,
+            n_scan_blocks=frac * n_blocks)
     if abandon:
         if cand_base is None:
             cand_base = jnp.zeros(cand_ids.shape, jnp.float32)
@@ -653,7 +713,9 @@ def verify_candidates(
         ids, dists, n_p, iters, frac, blocks = out
         if blocks is None:  # whole blocks: scanned dims over block_d
             blocks = frac * n_blocks
-        return ids, dists, n_p, iters, frac, ones, zeros, blocks
+        return ids, dists, SearchStats(
+            n_p=n_p, iterations=iters, base_p=base_p, n_dim_frac=frac,
+            n_scan_blocks=blocks)
     if metrics.is_static_p(p):
         out = _verify_jit_s(Q, cand_ids, X, float(p), k, kappa, tau,
                             interpret)
@@ -662,36 +724,33 @@ def verify_candidates(
                             jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
                             k, kappa, tau, interpret)
     ids, dists, n_p, iters = out
-    return ids, dists, n_p, iters, ones, ones, zeros, ones * n_blocks
+    # full-dimension scoring enters every block of every candidate
+    return ids, dists, SearchStats(n_p=n_p, iterations=iters, base_p=base_p,
+                                   n_scan_blocks=float(n_blocks))
 
 
-def mask_base_rows(cand_ids, cand_dists, ids, dists, n_p, p_vec, base_p,
-                   k: int, n_dim_frac=None, n_f32_frac=None,
-                   n_band_frac=None, n_scan_blocks=None):
+def mask_base_rows(cands: CandidateSet, ids, dists, stats: SearchStats,
+                   p_vec, k: int):
     """Per-row base-metric skip (paper §3 preamble) inside a mixed batch.
 
-    Rows whose p equals the base metric take the beam's own ordering —
-    the exact values the scalar skip path produces — and report n_p = 0
-    (and, when given, the scalar skip path's neutral stats: n_dim_frac
-    and n_f32_frac 1.0, n_band_frac and n_scan_blocks 0.0). Returns 3, 4,
-    or 7 values depending on which optional counters were supplied (the
-    7-form requires all four).
+    Rows whose p equals the base metric of `cands` take the beam's own
+    ordering — the exact values the scalar skip path produces — and each
+    verification counter of `stats` takes its `SearchStats.SKIPPED` value
+    on those rows. A counter already at that value on every row (a
+    Python scalar) is left as it is. Returns (ids, dists, stats).
     """
     pj = jnp.asarray(p_vec, dtype=jnp.float32)
-    is_base = pj == base_p
-    ids = jnp.where(is_base[:, None], cand_ids[:, :k], ids)
+    is_base = pj == cands.base_p
+    ids = jnp.where(is_base[:, None], cands.ids[:, :k], ids)
     dists = jnp.where(is_base[:, None],
-                      metrics._root(cand_dists[:, :k], pj[:, None]),
+                      metrics._root(cands.base_dists[:, :k], pj[:, None]),
                       dists)
-    n_p = jnp.where(is_base, 0, n_p)
-    if n_dim_frac is None:
-        return ids, dists, n_p
-    frac = jnp.where(is_base, 1.0, n_dim_frac)
-    if n_f32_frac is None:
-        return ids, dists, n_p, frac
-    return (ids, dists, n_p, frac, jnp.where(is_base, 1.0, n_f32_frac),
-            jnp.where(is_base, 0.0, n_band_frac),
-            jnp.where(is_base, 0.0, n_scan_blocks))
+    masked = {}
+    for name, neutral in SearchStats.SKIPPED.items():
+        value = getattr(stats, name)
+        if isinstance(value, jax.Array) or value != neutral:
+            masked[name] = jnp.where(is_base, neutral, value)
+    return ids, dists, stats._replace(**masked)
 
 
 def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
@@ -699,21 +758,19 @@ def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
     §6). Used by both UHNSW and ShardedUHNSW.
 
     search_base_vec(Q_sub (B', d), p_sub (B',) f32, k, base_p) must run one
-    homogeneous-base sub-batch and return (ids, dists, n_p, iters, n_b,
-    hops, n_dim_frac, n_f32_rows_frac, n_band_frac, n_scan_blocks) —
-    optionally followed by the four per-phase counters (n_b_probe,
-    n_b_spill, n_p_probe, n_p_spill), which the sharded index appends
-    (DESIGN.md §3); absent, the whole sub-batch counts as probe. A 15th
-    element, the per-row poisoned flag from the NaN/inf guard (DESIGN.md
-    §11), is likewise optional and defaults to all-clean.
+    homogeneous-base sub-batch and return (ids, dists, SearchStats).
     Returns (ids (B, k), dists (B, k), SearchStats) with per-row stats
     scattered back into request order; stats.base_p is the (B,) host-side
-    base-metric array (the partition itself is host logic).
+    base-metric array (the partition itself is host logic). A homogeneous
+    batch keeps its side's record whole; across two sides `iterations` is
+    the larger, `coverage_frac` the smaller, and `hops_max` is left at 0
+    (no single beam program ran every row).
 
-    Sub-batch results stay *device-resident*: each output is restored to
-    request order by one concatenate + one gather on device at the end —
-    no per-sub-batch `np.asarray` round trip, so a scheduled mixed bucket
-    never forces an extra device->host synchronization per side.
+    Sub-batch results stay *device-resident*: each per-row field is
+    restored to request order by one concatenate + one gather on device
+    at the end — no per-sub-batch `np.asarray` round trip, so a scheduled
+    mixed bucket never forces an extra device->host synchronization per
+    side. A field that is the same Python scalar on both sides stays one.
     """
     Q = jnp.asarray(Q, dtype=jnp.float32)
     b = Q.shape[0]
@@ -726,50 +783,70 @@ def two_way_mixed_search(Q, p, k: int, cutoff: float, search_base_vec):
         z = jnp.zeros((0, k))
         zi = jnp.zeros((0,), jnp.int32)
         zf = jnp.zeros((0,), jnp.float32)
+        default = SearchStats()
         return z.astype(jnp.int32), z, SearchStats(
-            n_b=zi, n_p=zi, iterations=jnp.int32(0), base_p=base, hops=zi,
-            n_dim_frac=zf, n_f32_rows_frac=zf, n_band_frac=zf,
-            n_scan_blocks=zf)
-    sels, parts = [], []
-    iters = jnp.int32(0)
+            **{f: zi if isinstance(getattr(default, f), int) else zf
+               for f in SearchStats.ROW_FIELDS},
+            iterations=jnp.int32(0), base_p=base)
+    sels, sides = [], []
     for base_p in (1.0, 2.0):
         sel = np.flatnonzero(base == base_p)
-        if sel.size == 0:
-            continue
-        res = search_base_vec(Q[sel], p_arr[sel], k, base_p)
-        (s_ids, s_dists, s_np, s_it, s_nb, s_hops, s_frac, s_f32,
-         s_band, s_blocks) = res[:10]
-        if len(res) > 10:
-            nb_pr, nb_sp, np_pr, np_sp = res[10:14]
-        else:  # phase-unaware index: everything is probe work
-            nb_pr, nb_sp = s_nb, jnp.zeros_like(s_nb)
-            np_pr, np_sp = s_np, jnp.zeros_like(s_np)
-        # NaN/inf-guard flag (DESIGN.md §11); absent = all-clean
-        s_pois = res[14] if len(res) > 14 else jnp.zeros_like(s_frac)
-        sels.append(sel)
-        parts.append((s_ids, s_dists, s_np, s_nb, s_hops, s_frac,
-                      s_f32, s_band, nb_pr, nb_sp, np_pr, np_sp, s_pois,
-                      s_blocks))
-        iters = jnp.maximum(iters, jnp.asarray(s_it, jnp.int32))
-    if len(parts) == 1:  # homogeneous batch: already in request order
-        (ids, dists, n_p, n_b, hops, frac, f32f, bandf,
-         nb_pr, nb_sp, np_pr, np_sp, pois, blocks) = parts[0]
-    else:
-        order = np.concatenate(sels)
-        inv = np.empty(b, np.int64)
-        inv[order] = np.arange(b)
-        inv = jnp.asarray(inv)
-        (ids, dists, n_p, n_b, hops, frac, f32f, bandf,
-         nb_pr, nb_sp, np_pr, np_sp, pois, blocks) = (
-            jnp.concatenate(xs, axis=0)[inv] for xs in zip(*parts)
-        )
+        if sel.size:
+            sels.append(sel)
+            sides.append(search_base_vec(Q[sel], p_arr[sel], k, base_p))
+    if len(sides) == 1:  # homogeneous batch: already in request order
+        ids, dists, stats = sides[0]
+        return ids, dists, stats._replace(base_p=base)
+    order = np.concatenate(sels)
+    inv = np.empty(b, np.int64)
+    inv[order] = np.arange(b)
+    inv = jnp.asarray(inv)
+
+    def restore(parts):
+        if not any(isinstance(x, jax.Array) for x in parts) \
+                and len(set(parts)) == 1:
+            return parts[0]
+        parts = [jnp.broadcast_to(x, (sel.size,) + jnp.shape(x)[1:])
+                 for x, sel in zip(parts, sels)]
+        return jnp.concatenate(parts, axis=0)[inv]
+
+    recs = [st for _, _, st in sides]
+    ids = restore([i for i, _, _ in sides])
+    dists = restore([d for _, d, _ in sides])
     stats = SearchStats(
-        n_b=n_b, n_p=n_p, iterations=iters, base_p=base, hops=hops,
-        n_dim_frac=frac, n_b_probe=nb_pr, n_b_spill=nb_sp,
-        n_p_probe=np_pr, n_p_spill=np_sp, n_f32_rows_frac=f32f,
-        n_band_frac=bandf, poisoned=pois, n_scan_blocks=blocks,
-    )
+        **{f: restore([st.row(f) for st in recs])
+           for f in SearchStats.ROW_FIELDS},
+        iterations=jnp.maximum(*(jnp.asarray(st.iterations, jnp.int32)
+                                 for st in recs)),
+        base_p=base,
+        coverage_frac=min(st.coverage_frac for st in recs))
     return ids, dists, stats
+
+
+def finish_candidates(index, Q, cands: CandidateSet, p, k: int):
+    """Stage 2 of a search over `index` (UHNSW or ShardedUHNSW): the
+    base-metric skip when p is a float equal to `cands.base_p` (the
+    beam's own ordering is exact), else verification over the index's
+    row source — scalar-p, or the traced per-row-p program with the
+    per-row skip mask. Returns (ids, dists, SearchStats) with the
+    candidate-generation fields filled; all device-resident."""
+    if metrics.is_static_p(p):
+        p = float(p)
+        if p == cands.base_p:
+            return (cands.ids[:, :k],
+                    metrics._root(cands.base_dists[:, :k], p),
+                    skipped_stats(cands))
+    prm = index.params
+    # -1 padding passes through: verify_candidates scores it inf
+    ids, dists, stats = verify_candidates(
+        Q, cands.ids, index._X_rows, p, k, prm.kappa or max(k // 2, 1),
+        prm.tau, interpret=prm.interpret, cand_base=cands.base_dists,
+        base_p=cands.base_p, abandon=prm.abandon,
+        block_d=prm.abandon_block_d, **index._verify_extras(),
+    )
+    if not metrics.is_static_p(p):
+        ids, dists, stats = mask_base_rows(cands, ids, dists, stats, p, k)
+    return ids, dists, stats.with_candidates(cands)
 
 
 def modeled_query_cost(stats: SearchStats, p, d: int) -> dict:
@@ -999,44 +1076,8 @@ class UHNSW:
         (B,) array runs the traced-p program with the per-row base-metric
         mask. Returns (ids, dists, SearchStats) — all device-resident.
         """
-        prm = self.params
-        Q = jnp.asarray(Q, dtype=jnp.float32)
-        base_p = cands.base_p
-        cand_ids, cand_dists = cands.ids, cands.base_dists
-        n_b, hops = cands.n_b, cands.hops
-        if metrics.is_static_p(p) and float(p) == base_p:
-            # p equals the base metric: the graph's own ordering is exact
-            ids = cand_ids[:, :k]
-            dists = metrics._root(cand_dists[:, :k], float(p))
-            return ids, dists, SearchStats(
-                n_b=n_b, n_p=jnp.zeros_like(n_b), iterations=jnp.int32(0),
-                base_p=base_p, hops=hops,
-                n_dim_frac=jnp.ones(n_b.shape, jnp.float32),
-                n_f32_rows_frac=jnp.ones(n_b.shape, jnp.float32),
-                n_band_frac=jnp.zeros(n_b.shape, jnp.float32),
-                hops_max=cands.hops_max)
-        kappa = prm.kappa or max(k // 2, 1)
-        p_arg = float(p) if metrics.is_static_p(p) else p
-        ids, dists, n_p, iters, frac, f32f, bandf, blocks = verify_candidates(
-            Q, cand_ids, self._X_rows, p_arg, k, kappa, prm.tau,
-            interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
-            abandon=prm.abandon, block_d=prm.abandon_block_d,
-            **self._verify_extras(),
-        )
-        if not metrics.is_static_p(p):
-            # per-row base-metric skip: base-p rows return the exact values
-            # the scalar skip path produces
-            ids, dists, n_p, frac, f32f, bandf, blocks = mask_base_rows(
-                cand_ids, cand_dists, ids, dists, n_p, p, base_p, k,
-                n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf,
-                n_scan_blocks=blocks)
-        return ids, dists, SearchStats(n_b=n_b, n_p=n_p, iterations=iters,
-                                       base_p=base_p, hops=hops,
-                                       n_dim_frac=frac,
-                                       n_f32_rows_frac=f32f,
-                                       n_band_frac=bandf,
-                                       hops_max=cands.hops_max,
-                                       n_scan_blocks=blocks)
+        return finish_candidates(self, jnp.asarray(Q, dtype=jnp.float32),
+                                 cands, p, k)
 
     def _search_scalar(self, Q, p: float, k: int):
         _, base_p = self.base_graph_for(p)
@@ -1047,10 +1088,7 @@ class UHNSW:
         """One homogeneous-base sub-batch with per-row p (traced-p program),
         as the two stages composed back-to-back."""
         cands = self.search_stage_candidates(Q, base_p)
-        ids, dists, st = self.search_stage_finish(Q, cands, p_vec, k)
-        return (ids, dists, st.n_p, st.iterations, st.n_b, st.hops,
-                st.n_dim_frac, st.n_f32_rows_frac, st.n_band_frac,
-                st.n_scan_blocks)
+        return self.search_stage_finish(Q, cands, p_vec, k)
 
     def _search_mixed(self, Q, p, k: int):
         """Mixed-p batch: two-way G1/G2 partition + per-row-p programs."""

@@ -1,14 +1,11 @@
-"""Mesh / sharding / collective helpers.
+"""Mesh / sharding helpers.
 
-  sharding — Runtime (mesh + parallelism flags), logical-axis -> PartitionSpec
-             mapping with divisibility fallbacks, spec-tree shardings
-  tp       — explicit tensor-parallel matmuls (shard_map) for the FFN path
+  sharding — Runtime (a mesh and its data-parallel axes) and the
+             logical-axis -> PartitionSpec mapping with divisibility
+             fallbacks
 """
 
 from repro.dist.sharding import (  # noqa: F401
     Runtime,
-    constrain,
     logical_to_spec,
-    param_struct,
-    spec_shardings,
 )

@@ -24,8 +24,7 @@ variance): Lp is coordinate-separable, so a fixed permutation is bit-exact
 after unpermuting, and front-loading the mass makes both the compressed
 screen and the PR-5 suffix bounds go dead after fewer blocks at small p.
 
-Quantization is the symmetric per-coordinate affine scheme of
-`train/compression.py::quantize_params` (prior art): one f32 scale per
+Quantization is symmetric per-coordinate affine: one f32 scale per
 coordinate, codes in [-127, 127]. Radii are computed *exactly* in f32 as
 the max dequantization error over the corpus — the scan evaluates the
 identical dequant expression `codes.astype(f32) * scale`, so the radius
@@ -119,7 +118,7 @@ def build_band(X, perm: np.ndarray | None = None) -> CompressedBand:
     perm = np.asarray(perm, dtype=np.int32)
     assert perm.shape == (d,), (perm.shape, d)
     Xp = np.ascontiguousarray(Xh[:, perm])
-    # symmetric per-coordinate affine quantization (train/compression.py):
+    # symmetric per-coordinate affine quantization:
     # scale = max|col| / 127, codes = round(col / scale) in [-127, 127]
     absmax = np.abs(Xp).max(axis=0) if n else np.zeros(d, np.float32)
     scale = (np.maximum(absmax, 1e-12) / 127.0).astype(np.float32)

@@ -3,8 +3,8 @@
 A snapshot is an atomic, manifest-based dump of the whole index state:
 per-segment graph topology (`GraphArrays` leaves), the frozen data matrix,
 the global-id maps, query params, the remembered build method, and the
-delta-buffer contents at save time. It is written with the same
-write-tmp/fsync/rename idiom as `repro.checkpoint.store` — a crash
+delta-buffer contents at save time. It is written with the
+write-tmp/fsync/rename idiom — a crash
 mid-write leaves only a `.tmp` directory that loaders never look at — and
 every array file carries a CRC32 recorded in the manifest, so a *torn*
 snapshot (post-crash corruption, partial copy) is detected and skipped,
@@ -119,7 +119,7 @@ def save_snapshot(index: ShardedUHNSW, directory, seq: int | None = None,
 
     seq defaults to one past the newest committed snapshot. The manifest is
     written last (fsync'd), then the directory renames into place — the
-    rename is the commit point, exactly as in checkpoint/store.py.
+    rename is the commit point.
 
     On-disk layout: `<dir>/snapshot_<seq:08d>/{manifest.json, arrays.npz}`.
     The npz holds `X` ((n, d) f32 frozen rows), per-segment

@@ -61,10 +61,9 @@ from repro.core.uhnsw import (
     CandidateSet,
     SearchStats,
     UHNSWParams,
-    mask_base_rows,
+    finish_candidates,
     modeled_query_cost,
     two_way_mixed_search,
-    verify_candidates,
 )
 from repro.index.delta import DeltaBuffer
 from repro.index.health import SegmentHealthTracker
@@ -526,15 +525,8 @@ class ShardedUHNSW:
         arrays = seg.arrays1 if base_p == 1.0 else seg.arrays2
         alive_list = (self._alive_segments() if alive is None
                       else sorted(int(i) for i in alive))
-        (cand_ids, cand_dists, n_b, hops, n_b_probe, n_b_spill,
-         n_cand_spill, poisoned, hops_max) = self._segment_candidates(
-            arrays, Q, k=k, alive=alive_list)
-        return CandidateSet(ids=cand_ids, base_dists=cand_dists, n_b=n_b,
-                            hops=hops, base_p=base_p, n_b_probe=n_b_probe,
-                            n_b_spill=n_b_spill, n_cand_spill=n_cand_spill,
-                            poisoned=poisoned,
-                            coverage_frac=self.coverage_frac(alive_list),
-                            hops_max=hops_max)
+        return self._segment_candidates(arrays, Q, base_p, k=k,
+                                        alive=alive_list)
 
     def search_stage_finish(self, Q, cands: CandidateSet, p, k: int):
         """Stage 2 of 2: verification (or base-metric skip) + delta merge.
@@ -544,79 +536,36 @@ class ShardedUHNSW:
         belong to this stage, and `search` composes exactly these two
         stages (bitwise parity with staged execution by construction).
         """
-        prm = self.params
         Q = jnp.asarray(Q, dtype=jnp.float32)
-        base_p = cands.base_p
-        cand_ids, cand_dists = cands.ids, cands.base_dists
-        n_b, hops = cands.n_b, cands.hops
-        kappa = prm.kappa or max(k // 2, 1)
+        ids, dists, stats = self._finish_graph(Q, cands, p, k)
         if metrics.is_static_p(p):
             p = float(p)
-            if p == base_p:
-                # base-metric query: merged graph ordering is already exact
-                ids = cand_ids[:, :k]
-                dists = metrics._root(cand_dists[:, :k], p)
-                n_p = jnp.zeros_like(n_b)
-                iters = jnp.int32(0)
-                frac = jnp.ones(n_b.shape, jnp.float32)
-                f32f = jnp.ones(n_b.shape, jnp.float32)
-                bandf = jnp.zeros(n_b.shape, jnp.float32)
-                blocks = jnp.zeros(n_b.shape, jnp.float32)
-            else:
-                # -1 padding passes through: verify_candidates scores it inf
-                ids, dists, n_p, iters, frac, f32f, bandf, blocks = \
-                    verify_candidates(
-                        Q, cand_ids, self._X_rows, p, k, kappa, prm.tau,
-                        interpret=prm.interpret, cand_base=cand_dists,
-                        base_p=base_p, abandon=prm.abandon,
-                        block_d=prm.abandon_block_d,
-                        **self._verify_extras(),
-                    )
-            phases = self._phase_split(cands, n_p)
-            return self._merge_delta(Q, p, k, ids, dists, n_p, iters, n_b,
-                                     hops, base_p, frac, f32f, bandf,
-                                     blocks, phases,
-                                     coverage=cands.coverage_frac,
-                                     poisoned=cands.poisoned,
-                                     hops_max=cands.hops_max)
-        # vector p over one homogeneous base: the traced-p program + the
-        # per-row base-metric skip mask, exactly as _search_mixed runs it
-        ids, dists, n_p, iters, frac, f32f, bandf, blocks = verify_candidates(
-            Q, cand_ids, self._X_rows, p, k, kappa, prm.tau,
-            interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
-            abandon=prm.abandon, block_d=prm.abandon_block_d,
-            **self._verify_extras(),
-        )
-        ids, dists, n_p, frac, f32f, bandf, blocks = mask_base_rows(
-            cand_ids, cand_dists, ids, dists, n_p, p, base_p, k,
-            n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf,
-            n_scan_blocks=blocks)
-        phases = self._phase_split(cands, n_p)
-        p_arr = np.broadcast_to(np.asarray(p, np.float32).reshape(-1),
+        else:  # the delta scan takes (B,) host p
+            p = np.broadcast_to(np.asarray(p, np.float32).reshape(-1),
                                 (int(Q.shape[0]),))
-        return self._merge_delta(Q, p_arr, k, ids, dists, n_p, iters, n_b,
-                                 hops, base_p, frac, f32f, bandf, blocks,
-                                 phases, coverage=cands.coverage_frac,
-                                 poisoned=cands.poisoned,
-                                 hops_max=cands.hops_max)
+        return self._merge_delta(Q, p, k, ids, dists, stats)
 
-    def _phase_split(self, cands: CandidateSet, n_p):
-        """Per-phase (probe, spill) N_b/N_p attribution (DESIGN.md §3).
+    def _finish_graph(self, Q, cands: CandidateSet, p, k: int):
+        """Verification (or the base-metric skip) of the merged candidates
+        with the per-phase N_p split, before the delta merge."""
+        ids, dists, stats = finish_candidates(self, Q, cands, p, k)
+        return ids, dists, self._phase_split(cands, stats)
 
-        N_b splits exactly (counted per phase in the beams). N_p is one
-        merged verification pass, so it splits by each phase's share of
-        the merged candidate list — the verify work a phase's survivors
-        brought in. The delta tier's exact scans (added later in
-        `_merge_delta`) belong to neither phase.
+    def _phase_split(self, cands: CandidateSet, stats: SearchStats):
+        """`stats` with the per-phase N_p attribution (DESIGN.md §3).
+
+        N_b splits exactly (counted per phase in the beams, carried over
+        from `cands`). N_p is one merged verification pass, so it splits
+        by each phase's share of the merged candidate list — the verify
+        work a phase's survivors brought in. The delta tier's exact scans
+        (added later in `_merge_delta`) belong to neither phase.
         """
-        n_b_probe = cands.n_b if cands.n_b_probe is None else cands.n_b_probe
-        n_b_spill = cands.n_b_spill
         n_valid = (cands.ids >= 0).sum(axis=1)
         spill_frac = (jnp.asarray(cands.n_cand_spill, jnp.float32)
                       / jnp.maximum(n_valid, 1).astype(jnp.float32))
-        n_p_spill = n_p.astype(jnp.float32) * spill_frac
-        n_p_probe = n_p.astype(jnp.float32) - n_p_spill
-        return n_b_probe, n_b_spill, n_p_probe, n_p_spill
+        n_p = stats.n_p.astype(jnp.float32)
+        n_p_spill = n_p * spill_frac
+        return stats._replace(n_p_probe=n_p - n_p_spill, n_p_spill=n_p_spill)
 
     def _probe_order(self) -> list[int]:
         """Prior ordering for the probe phase: largest segments first
@@ -673,17 +622,18 @@ class ShardedUHNSW:
             self._phase_cache[key] = hit
         return hit
 
-    def _segment_candidates(self, arrays, Q, k: int | None = None,
-                            alive: list[int] | None = None):
+    def _segment_candidates(self, arrays, Q, base_p: float,
+                            k: int | None = None,
+                            alive: list[int] | None = None) -> CandidateSet:
         """Policy-dispatched cross-segment candidate generation.
 
-        Returns (gids (B, t), dists (B, t), n_b, hops, n_b_probe,
-        n_b_spill, n_cand_spill, poisoned, hops_max) — the middle three
+        Returns the CandidateSet: n_b_probe, n_b_spill and n_cand_spill
         feed the per-phase stats split (DESIGN.md §3); threshold-free work
         is "probe", work under an inherited bound is "spill". `poisoned` is
-        the per-row NaN/inf-guard flag (DESIGN.md §11). `hops_max` has one
+        the per-row NaN/inf-guard flag and `coverage_frac` the exact
+        served fraction for `alive` (DESIGN.md §11). `hops_max` has one
         entry per searched segment: the trip count of the beam program
-        that searched it (`CandidateSet.hops_max`).
+        that searched it.
 
         `alive` (sorted segment indices; None = all) restricts the search
         to a subset: every derived quantity — candidate width t, the
@@ -696,6 +646,8 @@ class ShardedUHNSW:
         sp = self.sharded_params
         s_total = self.num_segments
         alive = list(range(s_total)) if alive is None else alive
+        cands = functools.partial(CandidateSet, base_p=base_p,
+                                  coverage_frac=self.coverage_frac(alive))
         if not alive:
             raise RuntimeError(
                 "no alive segments to search — every frozen segment is "
@@ -724,10 +676,10 @@ class ShardedUHNSW:
                 alive=mask,
             )
             zero = jnp.zeros_like(n_b)
-            return (gids, dists, n_b, hops, n_b, zero, zero, pois,
-                    jnp.full((s,), h_max))
+            return cands(ids=gids, base_dists=dists, n_b=n_b, hops=hops,
+                         n_b_probe=n_b, n_b_spill=zero, n_cand_spill=zero,
+                         poisoned=pois, hops_max=jnp.full((s,), h_max))
         rank = sp.resolve_thresh_rank(t, s, k)
-        base_p = arrays.metric_p
         alive_key = None if all_alive else tuple(alive)
         if sp.policy == "two_phase":
             (arr_a, x_a, ni_a), (arr_b, x_b, ni_b) = self._phase_stacks(
@@ -755,9 +707,10 @@ class ShardedUHNSW:
             n_cand_spill = ((flags == 1) & (gids >= 0)).sum(axis=1)
             hops_max = jnp.concatenate([jnp.full((probe,), hmax_a),
                                         jnp.full((s - probe,), hmax_b)])
-            return (gids, dists, nb_a + nb_b, hops_a + hops_b,
-                    nb_a, nb_b, n_cand_spill.astype(jnp.int32),
-                    pois_a | pois_b, hops_max)
+            return cands(ids=gids, base_dists=dists, n_b=nb_a + nb_b,
+                         hops=hops_a + hops_b, n_b_probe=nb_a, n_b_spill=nb_b,
+                         n_cand_spill=n_cand_spill.astype(jnp.int32),
+                         poisoned=pois_a | pois_b, hops_max=hops_max)
         # round_robin: single-phase cascade — every turn inherits the
         # running merged rank-r best of all earlier turns as its bound
         order = [i for i in self._probe_order() if i in set(alive)]
@@ -783,113 +736,82 @@ class ShardedUHNSW:
                 hops = hops + hops_i
                 pois = pois | pois_i
         n_cand_spill = ((flags == 1) & (gids >= 0)).sum(axis=1)
-        return (gids, dists, nb_probe + nb_spill, hops,
-                nb_probe, nb_spill, n_cand_spill.astype(jnp.int32), pois,
-                jnp.stack(hops_max))
+        return cands(ids=gids, base_dists=dists, n_b=nb_probe + nb_spill,
+                     hops=hops, n_b_probe=nb_probe, n_b_spill=nb_spill,
+                     n_cand_spill=n_cand_spill.astype(jnp.int32),
+                     poisoned=pois, hops_max=jnp.stack(hops_max))
 
     def _graph_search_base_vec(self, Q, p_vec, k: int, base_p: float):
         """One homogeneous-base sub-batch with per-row p (traced-p program),
-        mirroring UHNSW._search_base_vec over the segmented candidates."""
-        prm = self.params
+        mirroring UHNSW._search_base_vec over the segmented candidates;
+        the delta merge waits for the whole mixed batch."""
         Q = jnp.asarray(Q, dtype=jnp.float32)
         cands = self.search_stage_candidates(Q, base_p, k=k)
-        cand_ids, cand_dists = cands.ids, cands.base_dists
-        kappa = prm.kappa or max(k // 2, 1)
-        ids, dists, n_p, iters, frac, f32f, bandf, blocks = verify_candidates(
-            Q, cand_ids, self._X_rows, p_vec, k, kappa, prm.tau,
-            interpret=prm.interpret, cand_base=cand_dists, base_p=base_p,
-            abandon=prm.abandon, block_d=prm.abandon_block_d,
-            **self._verify_extras(),
-        )
-        ids, dists, n_p, frac, f32f, bandf, blocks = mask_base_rows(
-            cand_ids, cand_dists, ids, dists, n_p, p_vec, base_p, k,
-            n_dim_frac=frac, n_f32_frac=f32f, n_band_frac=bandf,
-            n_scan_blocks=blocks)
-        nb_pr, nb_sp, np_pr, np_sp = self._phase_split(cands, n_p)
-        return (ids, dists, n_p, iters, cands.n_b, cands.hops, frac,
-                f32f, bandf, blocks, nb_pr, nb_sp, np_pr, np_sp,
-                cands.poisoned)
+        return self._finish_graph(Q, cands, p_vec, k)
 
     def _search_mixed(self, Q, p, k: int):
         """Mixed-p batch: two-way G1/G2 partition, then one delta merge."""
         ids, dists, stats = two_way_mixed_search(
             Q, p, k, self.params.cutoff, self._graph_search_base_vec
         )
-        p_arr = np.asarray(stats.base_p)  # aligned (B,) — reuse its shape
         p_arr = np.broadcast_to(np.asarray(p, np.float32).reshape(-1),
-                                p_arr.shape)
-        phases = (stats.n_b_probe, stats.n_b_spill,
-                  stats.n_p_probe, stats.n_p_spill)
-        return self._merge_delta(Q, p_arr, k, ids, dists, stats.n_p,
-                                 stats.iterations, stats.n_b, stats.hops,
-                                 stats.base_p, stats.n_dim_frac,
-                                 stats.n_f32_rows_frac, stats.n_band_frac,
-                                 stats.n_scan_blocks, phases,
-                                 coverage=self.coverage_frac(),
-                                 poisoned=stats.poisoned)
+                                np.shape(stats.base_p))
+        return self._merge_delta(Q, p_arr, k, ids, dists, stats)
 
-    def _merge_delta(self, Q, p, k, ids, dists, n_p, iters, n_b, hops,
-                     base_p, n_dim_frac, n_f32_frac, n_band_frac,
-                     n_scan_blocks, phases=None, coverage: float = 1.0,
-                     poisoned=0.0, hops_max=0):
+    def _merge_delta(self, Q, p, k, ids, dists, stats: SearchStats):
         """Sort-merge exact delta-tier hits into the verified top-k.
 
         With abandonment on, the delta scan inherits the verified top-k's
         k-th-best as its abandon threshold (DESIGN.md §8): buffered
         vectors that provably cannot enter the top-k skip their remaining
-        dimension blocks. `n_dim_frac` is then updated as the N_p-weighted
-        mean of the graph-verify fraction and the delta scan's fraction,
-        and `n_scan_blocks` likewise with the delta scan's blocks entered;
-        so are `n_f32_frac`/`n_band_frac` (DESIGN.md §10) — the delta
-        tier is f32-only, so its scans count as full-f32 rows with zero
-        band traffic regardless of `compressed_band`.
-        `phases` is the (n_b_probe, n_b_spill, n_p_probe, n_p_spill)
-        split from `_phase_split`; delta scans join the N_p total but
-        neither phase (they are the mutable tier, not segment work).
+        dimension blocks. Each n_p-weighted counter of `stats`
+        (`n_dim_frac`, `n_scan_blocks`, `n_f32_rows_frac`, `n_band_frac`)
+        becomes the N_p-weighted mean of the graph-verify value and the
+        delta scan's: its dimensions scanned, its blocks entered, and its
+        rows as full-f32 gathers with no band traffic (the delta tier has
+        no compressed replica, DESIGN.md §10). Delta scans join the N_p
+        total but neither phase (they are the mutable tier, not segment
+        work). Returns (ids, dists, stats).
         """
-        if len(self.delta):
-            n_delta = len(self.delta)
-            d = self.X.shape[1]
-            # scalar basic-p scans have no transcendental work to skip and
-            # the no-thresh path keeps the 1-D shared-ids pairwise form
-            # (one gather for all queries, MXU matmul for p=2) — strictly
-            # cheaper than a per-query blocked scan
-            basic = metrics.is_static_p(p) and float(p) in (1.0, 2.0)
-            thresh = dists[:, k - 1] if (self.params.abandon and not basic) \
-                else None
-            d_ids, d_dists, d_nd = self.delta.search(
-                jnp.asarray(Q, dtype=jnp.float32), p,
-                interpret=self.params.interpret, thresh=thresh,
-                block_d=self.params.abandon_block_d,
-            )
-            all_ids = jnp.concatenate([ids, d_ids], axis=1)
-            all_d = jnp.concatenate([dists, d_dists], axis=1)
-            sd, si = jax.lax.sort((all_d, all_ids), num_keys=1)
-            ids, dists = si[:, :k], sd[:, :k]
-            delta_frac = d_nd.sum(axis=1).astype(jnp.float32) / (n_delta * d)
-            bd = self.params.abandon_block_d or pick_abandon_block_d(d)
-            d_blocks = ((d_nd + bd - 1) // bd).sum(axis=1).astype(jnp.float32)
-            denom = jnp.maximum(n_p + n_delta, 1)
-            n_dim_frac = (n_dim_frac * n_p + delta_frac * n_delta) / denom
-            n_scan_blocks = (n_scan_blocks * n_p + d_blocks) / denom
-            # delta rows are full f32 gathers (no compressed replica of
-            # the mutable tier) and contribute no band-dimension traffic
-            n_f32_frac = (n_f32_frac * n_p + 1.0 * n_delta) / denom
-            n_band_frac = (n_band_frac * n_p) / denom
-            n_p = n_p + n_delta  # exact-Lp scans count toward N_p
-        nb_pr, nb_sp, np_pr, np_sp = phases if phases is not None else (
-            n_b, jnp.zeros_like(n_b), n_p, jnp.zeros_like(n_p))
-        stats = SearchStats(n_b=n_b, n_p=n_p, iterations=iters, base_p=base_p,
-                            hops=hops, n_dim_frac=n_dim_frac,
-                            n_b_probe=nb_pr, n_b_spill=nb_sp,
-                            n_p_probe=np_pr, n_p_spill=np_sp,
-                            n_f32_rows_frac=n_f32_frac,
-                            n_band_frac=n_band_frac,
-                            coverage_frac=float(coverage),
-                            degraded=bool(coverage < 1.0),
-                            poisoned=poisoned, hops_max=hops_max,
-                            n_scan_blocks=n_scan_blocks)
-        return ids, dists, stats
+        if not len(self.delta):
+            return ids, dists, stats
+        n_delta = len(self.delta)
+        d = self.X.shape[1]
+        # scalar basic-p scans have no transcendental work to skip and
+        # the no-thresh path keeps the 1-D shared-ids pairwise form
+        # (one gather for all queries, MXU matmul for p=2) — strictly
+        # cheaper than a per-query blocked scan
+        basic = metrics.is_static_p(p) and float(p) in (1.0, 2.0)
+        thresh = dists[:, k - 1] if (self.params.abandon and not basic) \
+            else None
+        d_ids, d_dists, d_nd = self.delta.search(
+            jnp.asarray(Q, dtype=jnp.float32), p,
+            interpret=self.params.interpret, thresh=thresh,
+            block_d=self.params.abandon_block_d,
+        )
+        all_ids = jnp.concatenate([ids, d_ids], axis=1)
+        all_d = jnp.concatenate([dists, d_dists], axis=1)
+        sd, si = jax.lax.sort((all_d, all_ids), num_keys=1)
+        bd = self.params.abandon_block_d or pick_abandon_block_d(d)
+        # each weighted counter's sum over the delta rows (None: nothing)
+        delta_sums = {
+            "n_dim_frac": (d_nd.sum(axis=1).astype(jnp.float32)
+                           / (n_delta * d)) * n_delta,
+            "n_scan_blocks": ((d_nd + bd - 1) // bd).sum(axis=1).astype(
+                jnp.float32),
+            "n_f32_rows_frac": 1.0 * n_delta,
+            "n_band_frac": None,
+        }
+        n_p = stats.n_p
+        denom = jnp.maximum(n_p + n_delta, 1)
+        merged = {}
+        for name, extra in delta_sums.items():
+            weighted = getattr(stats, name) * n_p
+            merged[name] = (weighted if extra is None
+                            else weighted + extra) / denom
+        # exact-Lp scans count toward N_p
+        return si[:, :k], sd[:, :k], stats._replace(n_p=n_p + n_delta,
+                                                    **merged)
 
     def modeled_query_cost(self, stats: SearchStats, p, d: int) -> dict:
         """Paper Eq. 1 cost split — the shared core/uhnsw helper."""

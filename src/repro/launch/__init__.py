@@ -1,1 +1,1 @@
-"""Launch layer: mesh construction, dry-run, train/serve entry points."""
+"""Launch layer: the retrieval serving entry point and the compile cache."""

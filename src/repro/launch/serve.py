@@ -1,12 +1,10 @@
-"""Serving entry point: LM decode + optional universal-Lp retrieval tier.
+"""Serving entry point: the universal-Lp vector search tier.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch tinyllama_1_1b --smoke \
-      --batch 4 --prompt-len 16 --steps 32
-  PYTHONPATH=src python -m repro.launch.serve --retrieval --requests 64
+  PYTHONPATH=src python -m repro.launch.serve --requests 64
 
-On real hardware the same engine runs under launch/mesh.py's production
-meshes with the decode cache sequence-sharded over 'model' and (for MoE
-archs) the weights-stationary decode MoE (DESIGN.md §5).
+Builds a segmented index over a synthetic Deep-shaped corpus, serves a
+mixed-p request stream through the continuous-batching engine and prints
+the engine's stats and latency split (DESIGN.md §6).
 """
 
 from __future__ import annotations
@@ -15,38 +13,9 @@ import argparse
 import sys
 import time
 
-import jax
 import numpy as np
 
-from repro.configs.base import get_arch
-from repro.dist.sharding import Runtime, set_mesh
 from repro.launch.compile_cache import enable_compile_cache
-from repro.launch.mesh import make_local_mesh
-
-
-def serve_lm(args) -> int:
-    from repro.models.model import init_params
-    from repro.serve.engine import ServeEngine
-
-    cfg = get_arch(args.arch, smoke=args.smoke)
-    mesh = make_local_mesh(args.data, args.model)
-    rt = Runtime(mesh=mesh, moe_decode_gather=args.moe_decode_gather)
-    with set_mesh(mesh):
-        params = init_params(cfg, jax.random.PRNGKey(args.seed))
-        eng = ServeEngine(cfg, rt, params,
-                          max_seq=args.prompt_len + args.steps)
-        prompts = np.random.default_rng(args.seed).integers(
-            0, cfg.vocab_size, size=(args.batch, args.prompt_len)
-        ).astype(np.int32)
-        t0 = time.time()
-        out = eng.generate(prompts, steps=args.steps,
-                           temperature=args.temperature)
-        dt = time.time() - t0
-    tok = args.batch * args.steps
-    print(f"generated {out.shape} tokens in {dt:.1f}s "
-          f"({tok / dt:.1f} tok/s on this host)")
-    print("sample:", out[0][:16].tolist())
-    return 0
 
 
 def serve_retrieval(args) -> int:
@@ -195,17 +164,6 @@ def serve_retrieval(args) -> int:
 
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
-    ap.add_argument("--arch", default="tinyllama_1_1b")
-    ap.add_argument("--smoke", action="store_true")
-    ap.add_argument("--batch", type=int, default=4)
-    ap.add_argument("--prompt-len", type=int, default=16)
-    ap.add_argument("--steps", type=int, default=32)
-    ap.add_argument("--temperature", type=float, default=0.0)
-    ap.add_argument("--data", type=int, default=1)
-    ap.add_argument("--model", type=int, default=1)
-    ap.add_argument("--moe-decode-gather", action="store_true")
-    ap.add_argument("--retrieval", action="store_true",
-                    help="serve the universal-Lp vector search tier instead")
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--n", type=int, default=5000)
     ap.add_argument("--segments", type=int, default=4,
@@ -235,7 +193,7 @@ def main(argv=None) -> int:
                          "f32 row gathers only for screen survivors")
     args = ap.parse_args(argv)
     enable_compile_cache()
-    return serve_retrieval(args) if args.retrieval else serve_lm(args)
+    return serve_retrieval(args)
 
 
 if __name__ == "__main__":

@@ -184,6 +184,50 @@ def default_stats() -> dict:
     }
 
 
+def accumulate_stats(st: dict, base: float, rows, ps, padded_rows: int):
+    """Add one served batch into a `default_stats()` dict — the one place
+    search counters reach `service.stats`.
+
+    `rows` is the batch's SearchStats as host rows of its real requests
+    (`SearchStats.host_rows`), `ps` those requests' p values in row
+    order, `base` the batch's base metric and `padded_rows` the padding
+    rows it also executed. The verification fractions accumulate
+    N_p-weighted; the beam-lane counters count real rows only.
+    """
+    n = len(ps)
+    n_b, n_p = rows.n_b, rows.n_p
+    frac_w = float((rows.n_dim_frac * n_p).sum())
+    f32_w = float((rows.n_f32_rows_frac * n_p).sum())
+    st["queries"] += n
+    st["coverage_w"] += rows.coverage_frac * n
+    st["batches"] += 1
+    st["padded_rows"] += padded_rows
+    st["n_b"] += float(n_b.sum())
+    st["n_p"] += float(n_p.sum())
+    st["n_b_probe"] += float(rows.n_b_probe.sum())
+    st["n_b_spill"] += float(rows.n_b_spill.sum())
+    st["n_p_probe"] += float(rows.n_p_probe.sum())
+    st["n_p_spill"] += float(rows.n_p_spill.sum())
+    st["dim_frac_w"] += frac_w
+    st["scan_blocks_w"] += float((rows.n_scan_blocks * n_p).sum())
+    st["f32_rows_w"] += f32_w
+    st["beam_lane_trips"] += int(rows.hops.sum())
+    st["beam_lane_slots"] += int(rows.hops_max.sum()) * n
+    pb = st["per_base"]["G1" if base == 1.0 else "G2"]
+    pb["queries"] += n
+    pb["batches"] += 1
+    pb["n_b"] += float(n_b.sum())
+    pb["n_p"] += float(n_p.sum())
+    pb["dim_frac_w"] += frac_w
+    pb["f32_rows_w"] += f32_w
+    for i, p in enumerate(ps):
+        pp = st["per_p"].setdefault(
+            "%g" % float(p), {"queries": 0, "n_b": 0.0, "n_p": 0.0})
+        pp["queries"] += 1
+        pp["n_b"] += float(n_b[i])
+        pp["n_p"] += float(n_p[i])
+
+
 class ServingEngine:
     """The continuous-batching loop: admit -> (poll-flush -> pipeline) ->
     collect, against an injectable clock.
@@ -646,8 +690,9 @@ class ServingEngine:
             self._collect_wave(wave)
 
     def _collect_wave(self, wave: Wave) -> None:
-        (ids, dists, n_b, n_p, frac, f32, blocks, phases, cov, pois, hops,
-         hops_max) = self.pipeline.collect(wave)
+        ids, dists, rows = self.pipeline.collect(wave)
+        cov = rows.coverage_frac
+        pois = rows.poisoned.astype(bool)
         st = self.stats
         health = getattr(self.index, "health", None)
         if pois.any():
@@ -692,39 +737,11 @@ class ServingEngine:
         shape_key = (wave.base, wave.k, wave.exact, wave.size)
         cold = shape_key not in self._seen_shapes
         self._seen_shapes.add(shape_key)
-        frac_w = float((frac * n_p).sum())
-        f32_w = float((f32 * n_p).sum())
-        nb_pr, nb_sp, np_pr, np_sp = phases
-        st["queries"] += wave.n_real
-        st["coverage_w"] += cov * wave.n_real
-        st["batches"] += 1
-        st["padded_rows"] += wave.padded_rows
-        st["n_b"] += float(n_b.sum())
-        st["n_p"] += float(n_p.sum())
-        st["n_b_probe"] += float(nb_pr.sum())
-        st["n_b_spill"] += float(nb_sp.sum())
-        st["n_p_probe"] += float(np_pr.sum())
-        st["n_p_spill"] += float(np_sp.sum())
-        st["dim_frac_w"] += frac_w
-        st["scan_blocks_w"] += float((blocks * n_p).sum())
-        st["f32_rows_w"] += f32_w
-        st["beam_lane_trips"] += int(hops.sum())
-        st["beam_lane_slots"] += int(hops_max.sum()) * wave.n_real
-        pb = st["per_base"]["G1" if wave.base == 1.0 else "G2"]
-        pb["queries"] += wave.n_real
-        pb["batches"] += 1
-        pb["n_b"] += float(n_b.sum())
-        pb["n_p"] += float(n_p.sum())
-        pb["dim_frac_w"] += frac_w
-        pb["f32_rows_w"] += f32_w
+        accumulate_stats(st, wave.base, rows,
+                         [r.p for r in wave.requests], wave.padded_rows)
         for i, r in enumerate(wave.requests):
             r.finish_t = done
             self._results[r.request_id] = (ids[i], dists[i])
-            pp = st["per_p"].setdefault(
-                "%g" % r.p, {"queries": 0, "n_b": 0.0, "n_p": 0.0})
-            pp["queries"] += 1
-            pp["n_b"] += float(n_b[i])
-            pp["n_p"] += float(n_p[i])
             total = (done - r.arrival_t) * 1e3
             queue = max(r.flush_t - r.arrival_t, 0.0) * 1e3
             compute = max(done - r.flush_t, 0.0) * 1e3

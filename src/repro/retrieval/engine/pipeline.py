@@ -150,46 +150,20 @@ class TwoStagePipeline:
 
     def collect(self, wave: Wave):
         """Materialize one wave on host (the pipeline's only blocking
-        point). Returns (ids, dists, n_b, n_p, frac, f32, blocks, phases,
-        cov, pois, hops, hops_max) sliced to real rows; `f32` is the
-        per-row f32-rows-gathered fraction (DESIGN.md §10 — 1.0 off the
-        compressed two-band path); `blocks` the per-row mean dimension
-        blocks entered per verified candidate (`n_scan_blocks`);
-        phases is the per-phase (n_b_probe, n_b_spill, n_p_probe,
-        n_p_spill) attribution from the sharded two-phase search (probe =
-        everything, spill = 0 for monolithic indexes and the independent
-        policy); `cov` is the exact alive-coverage fraction the wave was
-        served at (1.0 for monolithic indexes) and `pois` the per-row
-        NaN/inf poison flags from the sharded query-time guard
-        (DESIGN.md §11 — all-False for monolithic indexes). `hops` is each
-        row's level-0 trips summed over its segment lanes and `hops_max`
-        the trip count of the beam loop behind each lane
-        (`CandidateSet.hops_max`, one entry per searched segment).
+        point). Returns (ids, dists, stats) sliced to the real rows:
+        `stats` is the wave's SearchStats as host rows
+        (`SearchStats.host_rows`) — every per-row counter as float64
+        rows, `hops_max` (the trip count of the beam loop behind each
+        segment lane) flat, `coverage_frac` the exact alive-coverage
+        fraction the wave was served at (DESIGN.md §11).
         """
         ids, dists, st = wave.result
         n = wave.n_real
-
-        def rows(x):
-            x = np.asarray(x, dtype=np.float64)
-            return x[:n] if x.ndim else np.full(n, float(x))
-
         with wave.span("engine.collect.wait"):
             ids = np.asarray(ids)[:n]
         dists = np.asarray(dists)[:n]
-        n_b = rows(st.n_b)
-        n_p = rows(st.n_p)
-        frac = rows(st.n_dim_frac)
-        f32 = rows(st.n_f32_rows_frac)
-        blocks = rows(st.n_scan_blocks)
-        nb_pr, nb_sp = st.phase_n_b()
-        np_pr, np_sp = st.phase_n_p()
-        phases = (rows(nb_pr), rows(nb_sp), rows(np_pr), rows(np_sp))
-        cov = float(getattr(st, "coverage_frac", 1.0))
-        pois = rows(getattr(st, "poisoned", 0.0)).astype(bool)
-        hops = rows(st.hops)
-        hops_max = np.asarray(st.hops_max, dtype=np.float64).reshape(-1)
+        rows = st.host_rows(n)
         wave.result = None
         for r in wave.requests:
             r.stage = DONE
-        return (ids, dists, n_b, n_p, frac, f32, blocks, phases, cov, pois,
-                hops, hops_max)
+        return ids, dists, rows

@@ -44,7 +44,12 @@ import numpy as np
 from repro.core.metrics import base_metric_for
 from repro.core.uhnsw import UHNSW, UHNSWParams
 from repro.index.sharded import ShardedUHNSW
-from repro.retrieval.engine import EnginePolicy, ServingEngine, default_stats
+from repro.retrieval.engine import (
+    EnginePolicy,
+    ServingEngine,
+    accumulate_stats,
+    default_stats,
+)
 
 
 class QueueFull(RuntimeError):
@@ -354,54 +359,16 @@ class UniversalVectorService:
             ids, dists, stats = self.index.search(q, p, k)
         ids = np.asarray(ids)[:n_real]
         dists = np.asarray(dists)[:n_real]
-        def rows(x):
-            x = np.asarray(x, dtype=np.float64)
-            return x[:n_real] if x.ndim else np.full(n_real, float(x))
-
-        n_b = rows(stats.n_b)
-        n_p = rows(stats.n_p)
-        # N_p-weighted scanned-dim fraction (1.0 on full-dimension paths)
-        frac = rows(stats.n_dim_frac)
-        frac_w = float((frac * n_p).sum())
-        blocks_w = float((rows(stats.n_scan_blocks) * n_p).sum())
-        # N_p-weighted f32-rows fraction (DESIGN.md §10 two-band scan)
-        f32_w = float((rows(stats.n_f32_rows_frac) * n_p).sum())
-        # per-phase attribution (probe == total for monolithic/independent)
-        nb_pr, nb_sp = stats.phase_n_b()
-        np_pr, np_sp = stats.phase_n_p()
-        nb_pr, nb_sp, np_pr, np_sp = map(rows, (nb_pr, nb_sp, np_pr, np_sp))
+        rows = stats.host_rows(n_real)
         done = time.perf_counter()
         shape_key = (base, k, exact, size)
         cold = shape_key not in self._seen_shapes
         self._seen_shapes.add(shape_key)
-        st = self.stats
-        st["queries"] += n_real
-        st["batches"] += 1
-        st["padded_rows"] += size - n_real
-        st["n_b"] += float(n_b.sum())
-        st["n_p"] += float(n_p.sum())
-        st["n_b_probe"] += float(nb_pr.sum())
-        st["n_b_spill"] += float(nb_sp.sum())
-        st["n_p_probe"] += float(np_pr.sum())
-        st["n_p_spill"] += float(np_sp.sum())
-        st["dim_frac_w"] += frac_w
-        st["scan_blocks_w"] += blocks_w
-        st["f32_rows_w"] += f32_w
-        pb = st["per_base"]["G1" if base == 1.0 else "G2"]
-        pb["queries"] += n_real
-        pb["batches"] += 1
-        pb["n_b"] += float(n_b.sum())
-        pb["n_p"] += float(n_p.sum())
-        pb["dim_frac_w"] += frac_w
-        pb["f32_rows_w"] += f32_w
+        accumulate_stats(self.stats, base, rows, [r.p for r in reqs],
+                         size - n_real)
         for i, (r, t0) in enumerate(chunk):
             out[r.request_id] = (ids[i], dists[i])
-            pp = st["per_p"].setdefault(
-                "%g" % float(r.p), {"queries": 0, "n_b": 0.0, "n_p": 0.0})
-            pp["queries"] += 1
-            pp["n_b"] += float(n_b[i])
-            pp["n_p"] += float(n_p[i])
-            st["latency_records"].append((
+            self.stats["latency_records"].append((
                 (done - t0) * 1e3,            # total
                 max(t_start - t0, 0.0) * 1e3,  # queue-wait
                 (done - t_start) * 1e3,        # device-compute

@@ -1,8 +1,8 @@
 """Shared fixtures: small synthetic datasets + prebuilt indexes.
 
 NOTE: no XLA_FLAGS device-count forcing here — smoke tests and benches must
-see the real single-device CPU backend. Only launch/dryrun.py forces 512
-placeholder devices, and it does so before importing jax.
+see the real single-device CPU backend; a multi-device mesh is modelled
+with `repro.dist.sharding.abstract_mesh` instead.
 """
 
 import jax

@@ -227,11 +227,12 @@ def test_two_band_bitwise_parity_scalar(p):
                                   err_msg=f"ids differ at p={p}")
     np.testing.assert_array_equal(np.asarray(c[1]), np.asarray(f[1]),
                                   err_msg=f"dists differ at p={p}")
-    np.testing.assert_array_equal(np.asarray(c[2]), np.asarray(f[2]))
+    np.testing.assert_array_equal(np.asarray(c[2].n_p), np.asarray(f[2].n_p))
     # the screen actually saved f32 gathers, and band traffic is counted
-    assert float(np.mean(np.asarray(c[5]))) < 1.0
-    assert float(np.mean(np.asarray(c[6]))) > 0.0
-    assert np.all(np.asarray(f[5]) == 1.0) and np.all(np.asarray(f[6]) == 0.0)
+    assert float(np.mean(np.asarray(c[2].n_f32_rows_frac))) < 1.0
+    assert float(np.mean(np.asarray(c[2].n_band_frac))) > 0.0
+    assert np.all(np.asarray(f[2].n_f32_rows_frac) == 1.0) \
+        and np.all(np.asarray(f[2].n_band_frac) == 0.0)
 
 
 def test_two_band_bitwise_parity_vector_p():

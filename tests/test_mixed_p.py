@@ -176,9 +176,11 @@ def test_verify_candidates_vector_p_batch_invariance(interpret):
     for bs in (1, 3, 5):
         sv = verify_candidates(q[:bs], ids[:bs], x, jnp.asarray(ps[:bs]),
                                k, kappa, 0.92, interpret=interpret)
+        m_rows = (mv[0], mv[1], mv[2].n_p)
+        s_rows = (sv[0], sv[1], sv[2].n_p)
         for j in range(3):  # ids, dists, n_p
-            np.testing.assert_array_equal(np.asarray(mv[j])[:bs],
-                                          np.asarray(sv[j]), err_msg=f"{j}")
+            np.testing.assert_array_equal(np.asarray(m_rows[j])[:bs],
+                                          np.asarray(s_rows[j]), err_msg=f"{j}")
 
 
 def test_verify_candidates_vector_p_matches_scalar():
@@ -193,8 +195,8 @@ def test_verify_candidates_vector_p_matches_scalar():
                                       np.asarray(sv[0])[0], err_msg=f"p={p}")
         np.testing.assert_allclose(np.asarray(mv[1])[i],
                                    np.asarray(sv[1])[0], rtol=1e-6)
-        np.testing.assert_array_equal(np.asarray(mv[2])[i],
-                                      np.asarray(sv[2])[0])
+        np.testing.assert_array_equal(np.asarray(mv[2].n_p)[i],
+                                      np.asarray(sv[2].n_p)[0])
 
 
 # ---------------------------------------------------------------------------

@@ -42,9 +42,10 @@ def test_verify_matches_reference(small_ds, rng):
     Q = small_ds.queries[:6]
     k, kappa, tau, t = 10, 5, 0.9, 60
     cand = np.stack([rng.permutation(small_ds.n)[:t] for _ in range(len(Q))]).astype(np.int32)
-    ids, dists, n_p, iters, *_ = verify_candidates(
+    ids, dists, st = verify_candidates(
         jnp.asarray(Q), jnp.asarray(cand), jnp.asarray(X), 0.7, k, kappa, tau
     )
+    n_p = st.n_p
     ref_ids, ref_np = _reference_verify(Q, cand, X, 0.7, k, kappa, tau)
     # same result *sets* (order may differ on exact ties)
     for i in range(len(Q)):

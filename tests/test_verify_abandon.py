@@ -243,8 +243,8 @@ def test_verify_abandon_matches_full_scalar(p):
     f = verify_candidates(q, cand, x, p, k, kappa, tau, abandon=False)
     np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(f[0]))
     _close_with_inf(np.asarray(a[1]), np.asarray(f[1]))
-    np.testing.assert_array_equal(np.asarray(a[2]), np.asarray(f[2]))
-    assert np.all(np.asarray(f[4]) == 1.0)
+    np.testing.assert_array_equal(np.asarray(a[2].n_p), np.asarray(f[2].n_p))
+    assert np.all(np.asarray(f[2].n_dim_frac) == 1.0)
 
 
 @pytest.mark.parametrize("p", [0.8, 1.25])
@@ -259,8 +259,8 @@ def test_verify_abandon_matches_full_multiblock(p, base_p):
     f = verify_candidates(q, cand, x, p, k, kappa, tau, abandon=False)
     np.testing.assert_array_equal(np.asarray(a[0]), np.asarray(f[0]))
     _close_with_inf(np.asarray(a[1]), np.asarray(f[1]))
-    np.testing.assert_array_equal(np.asarray(a[2]), np.asarray(f[2]))
-    frac = np.asarray(a[4])
+    np.testing.assert_array_equal(np.asarray(a[2].n_p), np.asarray(f[2].n_p))
+    frac = np.asarray(a[2].n_dim_frac)
     assert np.all(frac <= 1.0) and np.all(frac > 0.0)
     assert frac.mean() < 1.0, "no dimension work was saved"
 
@@ -285,10 +285,10 @@ def test_verify_abandon_vector_p_matches_scalar(interpret):
                                       np.asarray(sv[0])[0], err_msg=f"p={p}")
         np.testing.assert_allclose(np.asarray(mv[1])[i],
                                    np.asarray(sv[1])[0], rtol=1e-6)
-        np.testing.assert_array_equal(np.asarray(mv[2])[i],
-                                      np.asarray(sv[2])[0])
-        np.testing.assert_allclose(np.asarray(mv[4])[i],
-                                   np.asarray(sv[4])[0], rtol=1e-6)
+        np.testing.assert_array_equal(np.asarray(mv[2].n_p)[i],
+                                      np.asarray(sv[2].n_p)[0])
+        np.testing.assert_allclose(np.asarray(mv[2].n_dim_frac)[i],
+                                   np.asarray(sv[2].n_dim_frac)[0], rtol=1e-6)
 
 
 def test_verify_abandon_padding_rows():
@@ -300,7 +300,7 @@ def test_verify_abandon_padding_rows():
                             -1, n)
     cand_base = np.asarray(cand_base).copy()
     cand_base[:, 15:] = np.inf
-    ids, dists, n_p, _, frac, *_ = verify_candidates(
+    ids, dists, _ = verify_candidates(
         q, jnp.asarray(cand), x, 0.8, 10, 5, 0.92,
         cand_base=jnp.asarray(cand_base), base_p=1.0, abandon=True)
     assert np.all(np.asarray(ids) >= 0) and np.all(np.asarray(ids) < n)
@@ -312,8 +312,9 @@ def test_verify_abandon_false_is_legacy_bitwise():
     bit-for-bit (pinned against a hand-rolled sort-merge reference)."""
     q, x, cand, _ = _verify_case(d=32)
     k, kappa, tau, p = 10, 5, 0.92, 0.8
-    ids, dists, n_p, iters, frac, *_ = verify_candidates(
+    ids, dists, st = verify_candidates(
         q, cand, x, p, k, kappa, tau, abandon=False)
+    n_p, frac = st.n_p, st.n_dim_frac
     assert np.all(np.asarray(frac) == 1.0)
     # reference: the legacy loop in numpy (full-dimension, lax.sort merge)
     full = np.asarray(lp_gather_distance(q, cand, x, p, root=False))
