@@ -2,6 +2,8 @@
 
     PYTHONPATH=src python -m benchmarks.gather_paths [--n N] [--d D]
         [--rows B] [--cands C] [--reps R]
+    PYTHONPATH=src python -m benchmarks.gather_paths --beam-fetch
+        [--segments S] [--rows B] [--trips T] [--stack-mb M] [--reps R]
 
 The bulk graph builder scores its candidate blocks with an XLA gather
 plus `rowwise_lp` (kernels.ops.gather_rowwise_lp); verification in the
@@ -11,11 +13,23 @@ candidate. This times both on a (B, C) block of random ids into an
 (n, d) corpus and prints the best warm time per scored row, then one
 JSON line with every number. Only a run on a TPU compares the two: off
 the chip `lp_gather_distance` takes the same XLA path.
+
+`--beam-fetch` times one level-0 beam-loop trip's frontier scoring
+(core/hnsw.py::_score_frontier) over an M MB stack of S segments, with
+S x B query lanes of 32
+frontier ids each: the XLA gather of every frontier row plus the masked
+reduction, against the kernel that fetches and scores only the new rows
+(kernels/beam_fetch.py), at new shares 0.2, 0.35 and 1.0, d 1024 and
+4096, p 1 and 2, and the kernel's pipeline depths. T trips run in one
+jitted loop, each on ids shifted by the trip number; it prints µs per
+trip and one JSON line. The kernel path engages at d % 1024 == 0
+(`hnsw.BEAM_FETCH_ROW_ELEMS`), which these numbers are the basis of.
 """
 
 from __future__ import annotations
 
 import argparse
+import functools
 import json
 import time
 
@@ -35,6 +49,78 @@ def _best(fn, reps: int) -> tuple[float, float]:
     return first, min(warm)
 
 
+def beam_fetch(args) -> dict:
+    """Per-trip µs of the two frontier-scoring forms (module docstring)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from repro.core.hnsw import _base_dist
+    from repro.kernels import beam_fetch as bf
+
+    s, b, f, trips = args.segments, args.rows, 32, args.trips
+    interpret = jax.default_backend() != "tpu"  # a CPU run checks the code
+    out = {"device": jax.devices()[0].device_kind, "segments": s,
+           "rows": b, "frontier": f, "trips": trips, "us_per_trip": {}}
+    for d in (4096, 1024):
+        n = (args.stack_mb << 20) // (4 * s * d)  # rows per segment
+        rng = np.random.default_rng(d)
+        x = jnp.asarray(rng.standard_normal((s, n, d), np.float32))
+        src = bf.beam_rows(x)
+        q = jnp.asarray(rng.standard_normal((b, d), np.float32))
+        q_t = q.reshape(b, d // 128, 128)
+        ids0 = jnp.asarray(rng.integers(0, n, (s, b, f), dtype=np.int32))
+        row0 = jnp.repeat(jnp.arange(s, dtype=jnp.int32) * n, b)
+        for share in (0.2, 0.35, 1.0):
+            new = jnp.asarray(rng.random((s, b, f)) < share)
+            for p in (1.0, 2.0):
+                # the corpus and the masks are arguments, never constants
+                # folded into the program
+                def xla(ids, new, x, src, p=p):
+                    def lane(q1, i1, m1, xs):
+                        dv = _base_dist(q1, xs[i1], p)
+                        return jnp.where(m1, dv, jnp.inf)
+                    return jax.vmap(lambda xs, i2, m2: jax.vmap(
+                        lambda q1, i1, m1: lane(q1, i1, m1, xs))(q, i2, m2)
+                    )(x, ids, new)
+
+                def kernel(ids, new, x, src, depth=bf.DEPTH, p=p):
+                    lanes = s * b
+                    return bf.fetch_score_lanes(
+                        q_t, ids.reshape(lanes, f), new.reshape(lanes, f),
+                        row0, src, p=p, interpret=interpret,
+                        depth=depth).reshape(s, b, f)
+
+                args_ = (ids0, new, x, src)
+                ref, got = jax.jit(xla)(*args_), jax.jit(kernel)(*args_)
+                live = jnp.isfinite(ref)
+                rel = jnp.where(live, jnp.abs(got - ref) / ref, 0.0).max()
+                same = bool((live == jnp.isfinite(got)).all())
+                key = f"d={d} new={share} p={p}"
+                out.setdefault("max_rel_err", {})[key] = (
+                    float(rel) if same else None)
+                print(f"{key} max_rel_err {out['max_rel_err'][key]}",
+                      flush=True)
+                forms = [("xla", xla)] + [
+                    (f"kernel_depth{k}", functools.partial(kernel, depth=k))
+                    for k in (2, 4, 8)]
+                for name, form in forms:
+                    @jax.jit
+                    def loop(ids, new, x, src, form=form):
+                        def trip(t, acc):
+                            shifted = (ids + t * 7919) % n
+                            dv = form(shifted, new, x, src)
+                            return acc + jnp.where(jnp.isinf(dv), 0.0,
+                                                   dv).sum()
+                        return jax.lax.fori_loop(0, trips, trip,
+                                                 jnp.float32(0))
+                    _, warm = _best(lambda: loop(*args_), args.reps)
+                    us = warm / trips * 1e6
+                    out["us_per_trip"][f"{key} {name}"] = us
+                    print(f"{key} {name}: {us:.1f} us/trip", flush=True)
+    return out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--n", type=int, default=65536)
@@ -42,11 +128,18 @@ def main(argv=None) -> int:
     ap.add_argument("--rows", type=int, default=256)
     ap.add_argument("--cands", type=int, default=448)
     ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--beam-fetch", action="store_true")
+    ap.add_argument("--segments", type=int, default=4)
+    ap.add_argument("--trips", type=int, default=50)
+    ap.add_argument("--stack-mb", type=int, default=512)
     args = ap.parse_args(argv)
 
     from repro.launch.compile_cache import enable_compile_cache
 
     enable_compile_cache()
+    if args.beam_fetch:
+        print(json.dumps(beam_fetch(args)))
+        return 0
     import jax
     import jax.numpy as jnp
     import numpy as np

@@ -14,10 +14,12 @@ model. We restructure it (DESIGN.md §2) as fixed-width tensor ops inside
     set a per-query visited *bitmask* (uint32 words; dense compares over the
     word axis up to DENSE_VISITED_MAX_WORDS, a carry-free scatter-add of
     distinct bits above), compute base-metric distances for unseen neighbors in
-    one fused block, and merge the W*m0-entry frontier into the already-sorted
-    beam (ranks by dense compares up to DENSE_MERGE_MAX_FRONTIER entries, one
-    stable `lax.sort` of beam and frontier together above). W=1 is the classic
-    single-expansion search.
+    one block (an XLA gather of every frontier row, or, for rows that are
+    whole DMA tiles, BEAM_FETCH_ROW_ELEMS, one Pallas call over every lane
+    that reads only the new rows), and merge the W*m0-entry frontier into the
+    already-sorted beam (ranks by dense compares up to
+    DENSE_MERGE_MAX_FRONTIER entries, one stable `lax.sort` of beam and
+    frontier together above). W=1 is the classic single-expansion search.
 
 The whole search vmaps over the query batch and jits; query batches shard
 over the ('pod','data') mesh axes at serve time (see repro.retrieval).
@@ -36,6 +38,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core.metrics import lp_distance
+from repro.kernels import beam_fetch
 
 
 @jax.tree_util.register_pytree_node_class
@@ -156,6 +159,39 @@ class GraphArrays:
 def _base_dist(q: jax.Array, x: jax.Array, p: float) -> jax.Array:
     """Ordering-equivalent base-metric distance (root-free power sum)."""
     return lp_distance(q, x, p, root=False)
+
+
+# Row width (f32 elements) whose multiples the level-0 loop fetches and
+# scores through `kernels.beam_fetch`, reading only the rows its visited
+# test marks new (a quarter to a third on the cells' segments), in place of
+# an XLA gather of every frontier row (`_score_frontier`). A DMA out of HBM
+# moves whole (8, 128) f32 layout tiles: a row of d % 1024 == 0 is d / 1024
+# of them, with no padding, while a narrower row would read a whole 4 KB
+# tile. The kernel costs about 50 ns per new row at any width, the gather
+# about 100 ns per row at d = 4096 and 18 ns at d = 1024, for every row: on
+# a TPU v5e the kernel wins at d = 4096 whatever the new share, and at
+# d = 1024 up to a share of about 0.35 (PERF.md, "Findings").
+BEAM_FETCH_ROW_ELEMS = 1024
+
+
+def beam_fetch_on(d: int) -> bool:
+    """Whether a search over d-wide rows scores its frontier with the
+    fetch-only-new-rows kernel (BEAM_FETCH_ROW_ELEMS)."""
+    return d % BEAM_FETCH_ROW_ELEMS == 0
+
+
+def _score_frontier(q, X, safe, new, p, fetch):
+    """Base-metric power sums of the frontier, +inf where not new.
+
+    `fetch` None: an XLA gather of every frontier row, masked after. Else
+    (q_tiles, src, base): only the new rows are read, from the flat row
+    source `src` at the lane's segment offset `base` (kernels.beam_fetch).
+    """
+    if fetch is None:
+        dv = _base_dist(q, X[safe], p)
+        return jnp.where(new, dv, jnp.inf)
+    q_tiles, src, base = fetch
+    return beam_fetch.fetch_score(q_tiles, safe, new, base, src, p)
 
 
 # Largest per-query visited bitmask, in uint32 words, whose test-and-set runs
@@ -321,7 +357,7 @@ def _greedy_descend(q, X, adj_l, g2l, ep, ep_dist, nb, p, max_hops):
 
 
 def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
-                    width: int = 1, thresh=None):
+                    width: int = 1, thresh=None, fetch_rows=None):
     """Level-0 ef-beam search for one query. Returns (ids, dists, nb, hops).
 
     `width` (W) is the multi-expansion factor (DESIGN.md §2 hot path): each
@@ -340,8 +376,15 @@ def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
     sub-threshold region reachable from the entry is exhausted, instead of
     flooding the whole ef-neighborhood. The entry itself is always admitted
     (it seeds navigation even when its own distance exceeds the bound).
+
+    `fetch_rows` (None, or (src, base): the flat row source of
+    `kernels.beam_fetch` and this segment's first row in it) scores each
+    frontier by fetching only its new rows (`_score_frontier`).
     """
     n, m0 = X.shape[0], adj0.shape[1]
+    fetch = None
+    if fetch_rows is not None:
+        fetch = (beam_fetch.query_tiles(q),) + tuple(fetch_rows)
     words = (n + 31) // 32
     w = width
 
@@ -389,9 +432,8 @@ def _beam_search_l0(q, X, adj0, entry, entry_dist, nb0, p, ef, max_hops,
         word = safe >> 5
         bit = jnp.uint32(1) << (safe.astype(jnp.uint32) & 31)
         new, visited = _visited_test_and_set(visited, word, bit, valid & first)
-        # 4. one fused base-metric distance block for unseen neighbors only
-        dv = _base_dist(q, X[safe], p)
-        dv = jnp.where(new, dv, jnp.inf)
+        # 4. one base-metric distance block for unseen neighbors only
+        dv = _score_frontier(q, X, safe, new, p, fetch)
         nb = nb + new.sum()
         if thresh is not None:
             # cross-segment early-cut: evaluated (counted above, visited
@@ -452,7 +494,7 @@ def _greedy_descend_l0(q, X, adj0, ep, ep_dist, nb, p, max_hops,
 
 
 def _search_one(q, X, arrays: GraphArrays, ef: int, max_hops: int,
-                expand_width: int = 1, thresh=None):
+                expand_width: int = 1, thresh=None, fetch_rows=None):
     p = arrays.metric_p
     n = arrays.n
     ep = arrays.entry
@@ -469,8 +511,13 @@ def _search_one(q, X, arrays: GraphArrays, ef: int, max_hops: int,
         ep, ep_dist, nb = _greedy_descend_l0(
             q, X, arrays.adj0, ep, ep_dist, nb, p, max_hops, thresh=thresh
         )
-    return _beam_search_l0(q, X, arrays.adj0, ep, ep_dist, nb, p, ef,
-                           max_hops, width=expand_width, thresh=thresh)
+    out = _beam_search_l0(q, X, arrays.adj0, ep, ep_dist, nb, p, ef,
+                          max_hops, width=expand_width, thresh=thresh,
+                          fetch_rows=fetch_rows)
+    if fetch_rows is None:
+        return out
+    # the rows the level-0 loop read: its share of N_b
+    return out + (out[2] - nb,)
 
 
 @functools.partial(jax.jit, static_argnames=("ef", "t", "max_hops", "expand_width"))
@@ -483,6 +530,7 @@ def knn_search(
     max_hops: int = 4096,
     expand_width: int = 1,
     thresh: jax.Array | None = None,
+    fetch_rows: tuple | None = None,
 ):
     """Batched t-NN search under the graph's base metric.
 
@@ -500,13 +548,20 @@ def knn_search(
         but never admitted to its beam; slots past the admitted set come
         back as id n with dist inf. None (the default) compiles the
         unmodified program — bit-identical to the pre-threshold search.
+      fetch_rows: optional (src, base): `X` as the flat row source of
+        `kernels.beam_fetch` (rows of d % BEAM_FETCH_ROW_ELEMS == 0) and
+        X's first row in it. The level-0 loop then reads only the
+        neighbour rows its visited test marks new. None (the default)
+        compiles the XLA gather of every frontier row.
 
     Returns:
       ids   (B, t) int32 candidate ids sorted by base-metric distance;
       dists (B, t) base-metric distances (root-free powers);
       n_b   (B,)   exact count of base-metric Q2D evaluations (Eq. 1 N_b);
       hops  (B,)   level-0 hop counts (while_loop trips — one trip expands
-                   up to `expand_width` beam entries).
+                   up to `expand_width` beam entries);
+      rows  (B,)   with `fetch_rows` only: the corpus rows the level-0 loop
+                   read, its share of n_b.
     """
     assert ef >= t, (ef, t)
     assert 1 <= expand_width <= ef, (
@@ -514,16 +569,19 @@ def knn_search(
         f"ef={ef} (top_k cannot select more entries than the beam holds)"
     )
     if thresh is None:
-        ids, dists, nb, hops = jax.vmap(
-            lambda q: _search_one(q, X, arrays, ef, max_hops, expand_width)
+        out = jax.vmap(
+            lambda q: _search_one(q, X, arrays, ef, max_hops, expand_width,
+                                  fetch_rows=fetch_rows)
         )(Q)
     else:
         thresh = jnp.asarray(thresh, dtype=jnp.float32)
-        ids, dists, nb, hops = jax.vmap(
+        out = jax.vmap(
             lambda q, th: _search_one(q, X, arrays, ef, max_hops,
-                                      expand_width, thresh=th)
+                                      expand_width, thresh=th,
+                                      fetch_rows=fetch_rows)
         )(Q, thresh)
-    return ids[:, :t], dists[:, :t], nb, hops
+    ids, dists = out[:2]
+    return (ids[:, :t], dists[:, :t]) + tuple(out[2:])
 
 
 @functools.partial(jax.jit, static_argnames=("p",))

@@ -30,7 +30,7 @@ import numpy as np
 
 from repro.core import metrics
 from repro.core.build import HNSWGraph, build_hnsw
-from repro.core.hnsw import GraphArrays, knn_search
+from repro.core.hnsw import GraphArrays, beam_fetch_on, knn_search
 from repro.core.metrics import base_metric_for
 
 
@@ -146,6 +146,10 @@ class CandidateSet(NamedTuple):
     # finishes, so a row's lanes occupy hops_max.sum() lane-trips of which
     # `hops` did work.
     hops_max: jax.Array | int = 0
+    rows_read: jax.Array | int = 0  # (B,) corpus rows the level-0 loops
+        # read, summed over segment lanes: hops x W*m0 where each trip
+        # gathers its whole frontier, the level-0 share of n_b where the
+        # fetch kernel reads only the new rows (hnsw.beam_fetch_on)
 
 
 class SearchStats(NamedTuple):
@@ -217,10 +221,11 @@ class SearchStats(NamedTuple):
         # one scored whole (full-dimension scoring enters every block).
         # Each block past the first is one mid-scan abandonment check.
         # Weighted by n_p like n_dim_frac; 0.0 where nothing was verified.
+    rows_read: jax.Array | int = 0  # (B,) CandidateSet.rows_read
 
     ROW_FIELDS = ("n_b", "n_p", "hops", "n_dim_frac", "n_b_probe",
                   "n_b_spill", "n_p_probe", "n_p_spill", "n_f32_rows_frac",
-                  "n_band_frac", "poisoned", "n_scan_blocks")
+                  "n_band_frac", "poisoned", "n_scan_blocks", "rows_read")
     SKIPPED = {"n_p": 0, "n_dim_frac": 1.0, "n_f32_rows_frac": 1.0,
                "n_band_frac": 0.0, "n_scan_blocks": 0.0}
 
@@ -250,7 +255,7 @@ class SearchStats(NamedTuple):
             n_b=cands.n_b, hops=cands.hops, base_p=cands.base_p,
             n_b_probe=cands.n_b_probe, n_b_spill=cands.n_b_spill,
             poisoned=cands.poisoned, coverage_frac=cands.coverage_frac,
-            hops_max=cands.hops_max)
+            hops_max=cands.hops_max, rows_read=cands.rows_read)
 
     def host_rows(self, n: int) -> "SearchStats":
         """The record on the host: every per-row field as n float64 rows
@@ -911,11 +916,22 @@ class UHNSW:
         # X, so rebuilds (e.g. after snapshot recovery) are bit-stable.
         self._band = None
         self._scan_cache = None
+        self._fetch_rows = None
 
     @property
     def dim(self) -> int:
         """Vector dimensionality served by this index."""
         return int(self.X.shape[1])
+
+    def fetch_rows(self) -> tuple:
+        """`knn_search`'s fetch_rows: X as the level-0 fetch kernel's row
+        source (kernels.beam_fetch), made on first use, and X's first row
+        in it."""
+        if self._fetch_rows is None:
+            from repro.kernels.beam_fetch import beam_rows
+
+            self._fetch_rows = (beam_rows(self.X), jnp.int32(0))
+        return self._fetch_rows
 
     def compressed_band(self):
         """The lazily-built int8 CompressedBand over self.X (§10)."""
@@ -1057,14 +1073,21 @@ class UHNSW:
         # bulk-built graphs want a beam wider than t (they trade the
         # sequential builder's deep exploration for vectorized construction)
         ef = max(prm.ef or 2 * prm.t, prm.t)
-        cand_ids, cand_dists, n_b, hops = knn_search(
-            arrays, self.X, Q, ef=ef, t=prm.t, max_hops=prm.max_hops,
-            # degenerate tiny beams can't host the full W; clamp, don't fail
-            expand_width=min(prm.expand_width, ef),
-        )
+        # degenerate tiny beams can't host the full W; clamp, don't fail
+        width = min(prm.expand_width, ef)
+        kw = dict(ef=ef, t=prm.t, max_hops=prm.max_hops, expand_width=width)
+        if beam_fetch_on(self.dim):  # rows that are whole DMA tiles
+            cand_ids, cand_dists, n_b, hops, rows_read = knn_search(
+                arrays, self.X, Q, fetch_rows=self.fetch_rows(), **kw)
+        else:
+            cand_ids, cand_dists, n_b, hops = knn_search(
+                arrays, self.X, Q, **kw)
+            # every trip gathers its whole frontier of W*m0 rows
+            rows_read = hops * (width * arrays.adj0.shape[1])
         return CandidateSet(ids=cand_ids, base_dists=cand_dists, n_b=n_b,
                             hops=hops, base_p=base_p,
-                            hops_max=jnp.max(hops, keepdims=True))
+                            hops_max=jnp.max(hops, keepdims=True),
+                            rows_read=rows_read)
 
     def search_stage_finish(self, Q, cands: CandidateSet, p, k: int):
         """Stage 2 of 2: verification (or the base-metric skip) over a

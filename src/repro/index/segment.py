@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core.build import HNSWGraph, build_hnsw, build_hnsw_bulk
 from repro.core.hnsw import GraphArrays
+from repro.kernels.beam_fetch import beam_rows
 
 # below this size the sequential (faithful) builder is both faster to warm up
 # and higher quality; above it the batched bulk builder wins
@@ -113,7 +114,6 @@ class SegmentedGraphs:
     # stacked device state (derived; rebuilt by _restack):
     arrays1: GraphArrays = field(init=False)
     arrays2: GraphArrays = field(init=False)
-    X: jax.Array = field(init=False)          # (S, n_pad, d) segment data
     node_ids: jax.Array = field(init=False)   # (S, n_pad) int32, -1 pad
 
     def __post_init__(self):
@@ -126,6 +126,18 @@ class SegmentedGraphs:
     @property
     def n_pad(self) -> int:
         return self.arrays1.n
+
+    @property
+    def X(self) -> jax.Array:
+        """(S, n_pad, d) f32 segment data."""
+        return self._X
+
+    @X.setter
+    def X(self, value: jax.Array) -> None:
+        # every write of the rows (restack, placement, a poisoned or
+        # restored segment) drops the fetch kernel's copy of them
+        self._X = value
+        self._beam_src = None
 
     def _restack(self):
         self.arrays1 = _stack_uniform(self.graphs1)
@@ -140,6 +152,15 @@ class SegmentedGraphs:
             node_ids[i, : g.n] = ids
         self.X = jnp.asarray(X)
         self.node_ids = jnp.asarray(node_ids)
+
+    def beam_src(self) -> jax.Array:
+        """X as the level-0 fetch kernel's row source (kernels.beam_fetch):
+        (S * n_pad, d / 128, 128) f32, segment s's rows from s * n_pad.
+        Made on first use, by the searches whose rows are whole DMA tiles
+        (hnsw.beam_fetch_on), and again after every write of X."""
+        if self._beam_src is None:
+            self._beam_src = beam_rows(self.X)
+        return self._beam_src
 
     def append(self, g1: HNSWGraph, g2: HNSWGraph, global_ids: np.ndarray):
         """Add a frozen segment (delta compaction) and restack."""

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import metrics
-from repro.core.hnsw import GraphArrays, knn_search
+from repro.core.hnsw import GraphArrays, beam_fetch_on, knn_search
 from repro.core.metrics import base_metric_for
 from repro.core.uhnsw import (
     CandidateSet,
@@ -170,6 +170,7 @@ def segmented_knn_search(
     expand_width: int = 1,
     thresh: jax.Array | None = None,
     alive: jax.Array | None = None,
+    fetch_rows: tuple | None = None,
 ):
     """Vmapped per-segment base-metric search + one-sort global merge.
 
@@ -200,20 +201,30 @@ def segmented_knn_search(
     evaluation per segment, the row every beam must gather first) and
     flags non-finite entry distances too.
 
+    `fetch_rows` (optional (src, base): `SegmentedGraphs.beam_src`, the
+    stack's rows in the layout of `kernels.beam_fetch`, and (S,) int32 each
+    stacked segment's first row in it) makes every level-0 trip read only
+    the neighbour rows its visited test marks new, in one kernel call over
+    all (segment, row) lanes. None compiles the XLA gather of every
+    frontier row.
+
     Returns (gids (B, t) int32 global ids (-1 past the end of real data),
     dists (B, t) base-metric root-free distances, n_b (B,), hops (B,),
-    poisoned (B,) bool, hops_max ()). `hops` sums each row's level-0 trips
-    over its segment lanes; `hops_max`, the largest trip count of any
-    (segment, row) lane, is the trip count of the one batched loop that
-    runs them all — every lane is held for that many trips.
+    poisoned (B,) bool, hops_max ()), and with `fetch_rows` also
+    rows_read (B,), the corpus rows the level-0 loops read. `hops` sums
+    each row's level-0 trips over its segment lanes; `hops_max`, the
+    largest trip count of any (segment, row) lane, is the trip count of
+    the one batched loop that runs them all — every lane is held for that
+    many trips.
     """
     n_pad = arrays.n
     base_p = arrays.metric_p
 
-    def per_segment(arr, x, ni, al):
-        ids, dists, nb, hops = knn_search(
+    def per_segment(arr, x, ni, al, row0):
+        fetch = None if row0 is None else (fetch_rows[0], row0)
+        ids, dists, nb, hops, *rows = knn_search(
             arr, x, Q, ef=ef, t=t, max_hops=max_hops,
-            expand_width=expand_width, thresh=thresh,
+            expand_width=expand_width, thresh=thresh, fetch_rows=fetch,
         )
         valid = ids < n_pad
         g = jnp.where(valid, ni[jnp.clip(ids, 0, n_pad - 1)], -1)
@@ -234,22 +245,24 @@ def segmented_knn_search(
             d = jnp.where(al, d, jnp.inf)
             nb = jnp.where(al, nb, jnp.zeros_like(nb))
             hops = jnp.where(al, hops, jnp.zeros_like(hops))
+            rows = [jnp.where(al, r, jnp.zeros_like(r)) for r in rows]
             pois = pois & al
-        return g, d, nb, hops, pois
+        return (g, d, nb, hops, pois, *rows)
 
+    row0 = None if fetch_rows is None else fetch_rows[1]
     if alive is None:
-        g, d, nb, hops, pois = jax.vmap(
-            lambda arr, x, ni: per_segment(arr, x, ni, None)
-        )(arrays, X, node_ids)
+        g, d, nb, hops, pois, *rows = jax.vmap(
+            lambda arr, x, ni, r0: per_segment(arr, x, ni, None, r0)
+        )(arrays, X, node_ids, row0)
     else:
-        g, d, nb, hops, pois = jax.vmap(per_segment)(
-            arrays, X, node_ids, alive)
+        g, d, nb, hops, pois, *rows = jax.vmap(per_segment)(
+            arrays, X, node_ids, alive, row0)
     b = Q.shape[0]
     g = jnp.moveaxis(g, 0, 1).reshape(b, -1)  # (B, S*t)
     d = jnp.moveaxis(d, 0, 1).reshape(b, -1)
     sd, si = jax.lax.sort((d, g), num_keys=1)
     return (si[:, :t], sd[:, :t], nb.sum(axis=0), hops.sum(axis=0),
-            pois.any(axis=0), hops.max())
+            pois.any(axis=0), hops.max()) + tuple(r.sum(axis=0) for r in rows)
 
 
 @functools.partial(jax.jit, static_argnames=("t",))
@@ -603,7 +616,7 @@ class ShardedUHNSW:
 
         def take(sel):
             return (jax.tree.map(lambda x: x[sel], arrays),
-                    seg.X[sel], seg.node_ids[sel])
+                    seg.X[sel], seg.node_ids[sel], sel)
 
         val = (take(sel_a), take(sel_b))
         self._phase_cache[key] = val
@@ -618,9 +631,33 @@ class ShardedUHNSW:
             arrays = seg.arrays1 if base_p == 1.0 else seg.arrays2
             sel = np.asarray([i])
             hit = (jax.tree.map(lambda x: x[sel], arrays),
-                   seg.X[sel], seg.node_ids[sel])
+                   seg.X[sel], seg.node_ids[sel], sel)
             self._phase_cache[key] = hit
         return hit
+
+    def _fetch_rows(self, sel) -> tuple:
+        """`fetch_rows` of a stack of the segments `sel`: the whole index's
+        row source and each segment's first row in it (cached)."""
+        seg = self.segments
+        key = ("row0", tuple(int(i) for i in sel))
+        row0 = self._phase_cache.get(key)
+        if row0 is None:
+            row0 = jnp.asarray(np.asarray(sel, np.int32) * seg.X.shape[1])
+            self._phase_cache[key] = row0
+        return seg.beam_src(), row0
+
+    def _stack_search(self, stack, Q, width: int, **kw):
+        """`segmented_knn_search` over a stack (arrays, X, node_ids, the
+        segment indices it holds), with rows_read (B,) appended: the
+        fetch kernel's count where the rows are whole DMA tiles
+        (hnsw.beam_fetch_on), else hops x W*m0, every trip's gather."""
+        arrays, x, ni, sel = stack
+        if beam_fetch_on(x.shape[-1]):
+            return segmented_knn_search(arrays, x, ni, Q, expand_width=width,
+                                        fetch_rows=self._fetch_rows(sel),
+                                        **kw)
+        out = segmented_knn_search(arrays, x, ni, Q, expand_width=width, **kw)
+        return out + (out[3] * (width * arrays.adj0.shape[-1]),)
 
     def _segment_candidates(self, arrays, Q, base_p: float,
                             k: int | None = None,
@@ -670,24 +707,24 @@ class ShardedUHNSW:
                 m = np.zeros(s_total, dtype=bool)
                 m[alive] = True
                 mask = jnp.asarray(m)
-            gids, dists, n_b, hops, pois, h_max = segmented_knn_search(
-                arrays, self.segments.X, self.segments.node_ids, Q,
-                ef=ef, t=t, max_hops=prm.max_hops, expand_width=width,
+            stack = (arrays, self.segments.X, self.segments.node_ids,
+                     np.arange(s_total))
+            gids, dists, n_b, hops, pois, h_max, rows = self._stack_search(
+                stack, Q, width, ef=ef, t=t, max_hops=prm.max_hops,
                 alive=mask,
             )
             zero = jnp.zeros_like(n_b)
             return cands(ids=gids, base_dists=dists, n_b=n_b, hops=hops,
                          n_b_probe=n_b, n_b_spill=zero, n_cand_spill=zero,
-                         poisoned=pois, hops_max=jnp.full((s,), h_max))
+                         poisoned=pois, hops_max=jnp.full((s,), h_max),
+                         rows_read=rows)
         rank = sp.resolve_thresh_rank(t, s, k)
         alive_key = None if all_alive else tuple(alive)
         if sp.policy == "two_phase":
-            (arr_a, x_a, ni_a), (arr_b, x_b, ni_b) = self._phase_stacks(
-                base_p, probe, alive_key)
-            g_a, d_a, nb_a, hops_a, pois_a, hmax_a = segmented_knn_search(
-                arr_a, x_a, ni_a, Q, ef=ef, t=t, max_hops=prm.max_hops,
-                expand_width=width,
-            )
+            stack_a, stack_b = self._phase_stacks(base_p, probe, alive_key)
+            g_a, d_a, nb_a, hops_a, pois_a, hmax_a, rows_a = (
+                self._stack_search(stack_a, Q, width, ef=ef, t=t,
+                                   max_hops=prm.max_hops))
             thresh = d_a[:, rank - 1]
             # spill beams only contribute candidates below the bound, so
             # their width floors at the caller's k (not the global t) —
@@ -699,10 +736,10 @@ class ShardedUHNSW:
             # on ef=t builds (ef*ef_shrink < t there).
             ef_b = max(k or 1, rank, int(round(ef * sp.ef_shrink)))
             t_b = min(t, ef_b)
-            g_b, d_b, nb_b, hops_b, pois_b, hmax_b = segmented_knn_search(
-                arr_b, x_b, ni_b, Q, ef=ef_b, t=t_b, max_hops=prm.max_hops,
-                expand_width=min(width, ef_b), thresh=thresh,
-            )
+            g_b, d_b, nb_b, hops_b, pois_b, hmax_b, rows_b = (
+                self._stack_search(stack_b, Q, min(width, ef_b), ef=ef_b,
+                                   t=t_b, max_hops=prm.max_hops,
+                                   thresh=thresh))
             gids, dists, flags = merge_phase_lists(g_a, d_a, g_b, d_b, t)
             n_cand_spill = ((flags == 1) & (gids >= 0)).sum(axis=1)
             hops_max = jnp.concatenate([jnp.full((probe,), hmax_a),
@@ -710,36 +747,39 @@ class ShardedUHNSW:
             return cands(ids=gids, base_dists=dists, n_b=nb_a + nb_b,
                          hops=hops_a + hops_b, n_b_probe=nb_a, n_b_spill=nb_b,
                          n_cand_spill=n_cand_spill.astype(jnp.int32),
-                         poisoned=pois_a | pois_b, hops_max=hops_max)
+                         poisoned=pois_a | pois_b, hops_max=hops_max,
+                         rows_read=rows_a + rows_b)
         # round_robin: single-phase cascade — every turn inherits the
         # running merged rank-r best of all earlier turns as its bound
         order = [i for i in self._probe_order() if i in set(alive)]
         gids = dists = flags = pois = None
-        nb_probe = nb_spill = hops = None
+        nb_probe = nb_spill = hops = rows = None
         hops_max = []
         for turn, i in enumerate(order):
-            arr_i, x_i, ni_i = self._segment_stack(base_p, i)
             thresh = dists[:, rank - 1] if turn else None
-            g_i, d_i, nb_i, hops_i, pois_i, hmax_i = segmented_knn_search(
-                arr_i, x_i, ni_i, Q, ef=ef, t=t, max_hops=prm.max_hops,
-                expand_width=width, thresh=thresh,
-            )
+            g_i, d_i, nb_i, hops_i, pois_i, hmax_i, rows_i = (
+                self._stack_search(self._segment_stack(base_p, i), Q, width,
+                                   ef=ef, t=t, max_hops=prm.max_hops,
+                                   thresh=thresh))
             hops_max.append(hmax_i)
             if turn == 0:
                 gids, dists, pois = g_i, d_i, pois_i
                 flags = jnp.zeros_like(g_i)
                 nb_probe, nb_spill, hops = nb_i, jnp.zeros_like(nb_i), hops_i
+                rows = rows_i
             else:
                 gids, dists, flags = merge_tagged_lists(
                     gids, dists, flags, g_i, d_i, t)
                 nb_spill = nb_spill + nb_i
                 hops = hops + hops_i
+                rows = rows + rows_i
                 pois = pois | pois_i
         n_cand_spill = ((flags == 1) & (gids >= 0)).sum(axis=1)
         return cands(ids=gids, base_dists=dists, n_b=nb_probe + nb_spill,
                      hops=hops, n_b_probe=nb_probe, n_b_spill=nb_spill,
                      n_cand_spill=n_cand_spill.astype(jnp.int32),
-                     poisoned=pois, hops_max=jnp.stack(hops_max))
+                     poisoned=pois, hops_max=jnp.stack(hops_max),
+                     rows_read=rows)
 
     def _graph_search_base_vec(self, Q, p_vec, k: int, base_p: float):
         """One homogeneous-base sub-batch with per-row p (traced-p program),

@@ -5,8 +5,13 @@
 the batched loops ran (per row, each lane's loop trip count). A lane
 that finishes early idles in lockstep until the loop's slowest lane is
 done, so slots >= trips, with equality when every lane stops together.
+`beam_rows_read` counts the corpus rows those loops read: hops x W*m0
+where each trip gathers its whole frontier, the level-0 share of N_b
+where the fetch kernel reads only the new rows.
 """
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -75,3 +80,64 @@ def test_slots_never_below_trips(small_ds, make_sharded, graphs_bulk,
     st = _serve(index, small_ds.queries[np.arange(21) % 24], ps)
     assert st["queries"] == 21 and st["padded_rows"] > 0
     assert 0 < st["beam_lane_trips"] <= st["beam_lane_slots"]
+
+
+@pytest.mark.parametrize("policy", ["independent", "two_phase",
+                                    "round_robin", "monolithic"])
+def test_rows_read_is_every_frontier_row_of_every_trip(small_ds, make_sharded,
+                                                       graphs_bulk, policy):
+    """Rows narrower than a DMA tile run the gather of the whole frontier:
+    each row reads hops x W*m0 corpus rows, and the engine's
+    `beam_rows_read` sums the real rows only (padding rows are out)."""
+    if policy == "monolithic":
+        index = UHNSW(*graphs_bulk, UHNSWParams(t=60))
+        m0 = index.arrays1.adj0.shape[-1]
+    else:
+        index = make_sharded(params=UHNSWParams(t=60),
+                             sharded_params=ShardedParams(policy=policy,
+                                                          probe=2))
+        m0 = index.segments.arrays1.adj0.shape[-1]
+    q = small_ds.queries[:5]
+    cands = index.search_stage_candidates(q, 1.0, k=10)
+    np.testing.assert_array_equal(np.asarray(cands.rows_read),
+                                  np.asarray(cands.hops) * m0)
+    st = _serve(index, q, [0.8] * 5)
+    assert st["queries"] == 5 and st["padded_rows"] > 0
+    assert st["beam_rows_read"] == int(np.asarray(cands.rows_read).sum())
+
+
+def _upper_share(arrays, x, q, max_hops):
+    """N_b of one segment's search before its level-0 loop: the entry
+    and the greedy descent of the upper layers."""
+    from repro.core.hnsw import _base_dist, _greedy_descend
+
+    p = arrays.metric_p
+    ep = arrays.entry
+    dist, nb = _base_dist(q, x[ep], p), jnp.int32(1)
+    for adj_l, g2l in zip(reversed(arrays.upper_adj),
+                          reversed(arrays.upper_g2l)):
+        ep, dist, nb = _greedy_descend(q, x, adj_l, g2l, ep, dist, nb, p,
+                                       max_hops)
+    return int(nb)
+
+
+def test_rows_read_is_the_level0_share_of_n_b_on_the_kernel_path():
+    """Rows of whole DMA tiles (d = 1024) run the fetch kernel: each row
+    reads exactly the neighbours its level-0 loops evaluated, N_b less
+    each segment's entry and upper-layer descent."""
+    rng = np.random.default_rng(2)
+    data = rng.standard_normal((480, 1024)).astype(np.float32)
+    index = ShardedUHNSW.build(data, num_segments=2, m=8, seed=1,
+                               params=UHNSWParams(t=16, ef=32))
+    q = data[:4] + 0.05 * rng.standard_normal((4, 1024)).astype(np.float32)
+    cands = index.search_stage_candidates(q, 2.0, k=10)
+    seg = index.segments
+    upper = np.array([
+        sum(_upper_share(jax.tree.map(lambda a: a[s], seg.arrays2),
+                         seg.X[s], jnp.asarray(qi), index.params.max_hops)
+            for s in range(seg.num_segments))
+        for qi in q])
+    np.testing.assert_array_equal(np.asarray(cands.rows_read),
+                                  np.asarray(cands.n_b) - upper)
+    m0 = seg.arrays2.adj0.shape[-1]
+    assert (np.asarray(cands.rows_read) < np.asarray(cands.hops) * m0).all()

@@ -48,6 +48,7 @@ ENGINE_KEY = {
     "n_f32_rows_frac": ("f32_rows_w", True),
     "poisoned": ("poison_detected", False),
     "n_scan_blocks": ("scan_blocks_w", True),
+    "rows_read": ("beam_rows_read", False),
 }
 
 

@@ -180,3 +180,79 @@ def test_kernel_rows_pads_only_on_the_chip(monkeypatch):
     assert not np.asarray(rows[:, 96:]).any()
     aligned = jnp.zeros((5, 256), jnp.int8)
     assert ops.kernel_rows(aligned) is aligned
+
+
+# the beam fetch kernel's shapes: S segments of N_SEG rows, B queries,
+# frontiers of W*m0 = 32 ids (kernels/beam_fetch.py)
+S, N_SEG, M0 = 4, 2048, 32
+
+
+@pytest.mark.parametrize("p", [1.0, 2.0])
+@pytest.mark.parametrize("d", [1024, 4096])
+def test_beam_fetch_kernel_compiles(one_chip, d, p):
+    from repro.kernels import beam_fetch
+
+    lanes = S * B
+    _assert_kernel_compiles(
+        lambda q, ids, new, row0, src: beam_fetch.fetch_score_lanes(
+            q, ids, new, row0, src, p=p, interpret=False),
+        _spec((B, d // 128, 128), one_chip),
+        _spec((lanes, M0), one_chip, jnp.int32),
+        _spec((lanes, M0), one_chip, jnp.bool_),
+        _spec((lanes,), one_chip, jnp.int32),
+        _spec((S * N_SEG, d // 128, 128), one_chip))
+
+
+def _candidate_program_text(one_chip, monkeypatch, d: int, fetch: bool):
+    """The compiled segmented candidate program, W = 1, with the level-0
+    loop on the fetch kernel (compiled, as on the chip) or on the gather."""
+    from repro.core.hnsw import GraphArrays, knn_search
+    from repro.index.sharded import segmented_knn_search
+    from repro.kernels import beam_fetch
+
+    monkeypatch.setattr(beam_fetch, "_interpret", lambda: False)
+    arrays = GraphArrays(
+        adj0=_spec((S, N_SEG, M0), one_chip, jnp.int32),
+        upper_adj=(_spec((S, 64, 16), one_chip, jnp.int32),),
+        upper_g2l=(_spec((S, N_SEG), one_chip, jnp.int32),),
+        entry=_spec((S,), one_chip, jnp.int32), n=N_SEG, metric_p=1.0)
+    specs = [arrays, _spec((S, N_SEG, d), one_chip),
+             _spec((S, N_SEG), one_chip, jnp.int32), _spec((B, d), one_chip)]
+    kw = dict(ef=64, t=32)
+    if fetch:
+        specs += [_spec((S * N_SEG, d // 128, 128), one_chip),
+                  _spec((S,), one_chip, jnp.int32)]
+        fn = lambda a, x, ni, q, src, row0: segmented_knn_search(  # noqa: E731
+            a, x, ni, q, fetch_rows=(src, row0), **kw)
+    else:
+        fn = lambda a, x, ni, q: segmented_knn_search(  # noqa: E731
+            a, x, ni, q, **kw)
+    # traces made under the other `_interpret` must not be reused
+    segmented_knn_search.clear_cache()
+    knn_search.clear_cache()
+    try:
+        return jax.jit(fn).lower(*specs).compile().as_text()
+    finally:
+        segmented_knn_search.clear_cache()
+        knn_search.clear_cache()
+
+
+@pytest.mark.parametrize("d", [1024, 4096])
+def test_candidate_program_fetches_only_by_kernel(one_chip, monkeypatch, d):
+    """The segmented candidate program on the fetch path holds the
+    kernel and no gather of a (lanes * W*m0, d) block of rows, which the
+    gather path's program does hold."""
+    gathered = S * B * M0 * d
+
+    def row_blocks(text):
+        """Ops whose f32 result holds lanes * W*m0 rows of d."""
+        return {op for shape, op in _HLO_RESULT.findall(text)
+                if shape.startswith("f32[") and np.prod(
+                    [int(v) for v in shape[4:-1].split(",") if v]) == gathered}
+
+    text = _candidate_program_text(one_chip, monkeypatch, d, fetch=True)
+    assert "tpu_custom_call" in text
+    assert not row_blocks(text)
+    text = _candidate_program_text(one_chip, monkeypatch, d, fetch=False)
+    assert "tpu_custom_call" not in text
+    assert row_blocks(text)
