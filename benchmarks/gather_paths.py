@@ -22,7 +22,11 @@ reduction, against the kernel that fetches and scores only the new rows
 (kernels/beam_fetch.py), at new shares 0.2, 0.35 and 1.0, d 1024 and
 4096, p 1 and 2, and the kernel's pipeline depths. T trips run in one
 jitted loop, each on ids shifted by the trip number; it prints µs per
-trip and one JSON line. The kernel path engages at d % 1024 == 0
+trip, ns per new row, the share of the HBM roofline that the new rows'
+bytes (new rows x d x 4 B over the device's peak bytes/s,
+`chipbench/peaks.json`) take of the trip, and, for each form, a line
+fitted over the three shares: ns per lane plus ns per new row. Then one
+JSON line. The kernel path engages at d % 1024 == 0
 (`hnsw.BEAM_FETCH_ROW_ELEMS`), which these numbers are the basis of.
 """
 
@@ -31,7 +35,10 @@ from __future__ import annotations
 import argparse
 import functools
 import json
+import pathlib
 import time
+
+PEAKS = pathlib.Path(__file__).resolve().parents[1] / "chipbench" / "peaks.json"
 
 
 def _best(fn, reps: int) -> tuple[float, float]:
@@ -49,6 +56,25 @@ def _best(fn, reps: int) -> tuple[float, float]:
     return first, min(warm)
 
 
+def _hbm_bytes_per_s(kind: str) -> float | None:
+    """The device's published HBM peak, None for a device not in the
+    table (a CPU run has no roofline)."""
+    with open(PEAKS) as f:
+        dev = json.load(f)["devices"].get(kind)
+    return dev["hbm_bytes_per_s"] if dev else None
+
+
+def _fit(points) -> tuple[float, float]:
+    """Least-squares t = c + b * rows over (new rows, µs per trip) points
+    -> (c µs per trip, b ns per new row)."""
+    rows = [r for r, _ in points]
+    us = [t for _, t in points]
+    mr, mt = sum(rows) / len(rows), sum(us) / len(us)
+    b = (sum((r - mr) * (t - mt) for r, t in points)
+         / sum((r - mr) ** 2 for r in rows))
+    return mt - b * mr, b * 1e3
+
+
 def beam_fetch(args) -> dict:
     """Per-trip µs of the two frontier-scoring forms (module docstring)."""
     import jax
@@ -60,9 +86,13 @@ def beam_fetch(args) -> dict:
 
     s, b, f, trips = args.segments, args.rows, 32, args.trips
     interpret = jax.default_backend() != "tpu"  # a CPU run checks the code
-    out = {"device": jax.devices()[0].device_kind, "segments": s,
-           "rows": b, "frontier": f, "trips": trips, "us_per_trip": {}}
+    kind = jax.devices()[0].device_kind
+    peak = _hbm_bytes_per_s(kind)
+    out = {"device": kind, "segments": s, "rows": b, "frontier": f,
+           "trips": trips, "us_per_trip": {}, "ns_per_new_row": {},
+           "hbm_share": {}, "fit": {}}
     for d in (4096, 1024):
+        points = {}
         n = (args.stack_mb << 20) // (4 * s * d)  # rows per segment
         rng = np.random.default_rng(d)
         x = jnp.asarray(rng.standard_normal((s, n, d), np.float32))
@@ -73,6 +103,7 @@ def beam_fetch(args) -> dict:
         row0 = jnp.repeat(jnp.arange(s, dtype=jnp.int32) * n, b)
         for share in (0.2, 0.35, 1.0):
             new = jnp.asarray(rng.random((s, b, f)) < share)
+            n_new = int(new.sum())
             for p in (1.0, 2.0):
                 # the corpus and the masks are arguments, never constants
                 # folded into the program
@@ -116,8 +147,23 @@ def beam_fetch(args) -> dict:
                                                  jnp.float32(0))
                     _, warm = _best(lambda: loop(*args_), args.reps)
                     us = warm / trips * 1e6
+                    ns_row = us * 1e3 / n_new
+                    hbm = n_new * d * 4 / peak / (us * 1e-6) if peak else None
                     out["us_per_trip"][f"{key} {name}"] = us
-                    print(f"{key} {name}: {us:.1f} us/trip", flush=True)
+                    out["ns_per_new_row"][f"{key} {name}"] = ns_row
+                    out["hbm_share"][f"{key} {name}"] = hbm
+                    points.setdefault(f"d={d} p={p} {name}", []).append(
+                        (n_new, us))
+                    hbm_s = "not measured" if hbm is None else f"{hbm:.1%}"
+                    print(f"{key} {name}: {us:.1f} us/trip, {ns_row:.1f} "
+                          f"ns/new row, HBM roofline {hbm_s}", flush=True)
+        for name, pts in points.items():
+            c, per_row = _fit(pts)
+            per_lane = c * 1e3 / (s * b)
+            out["fit"][name] = {"ns_per_lane": per_lane,
+                                "ns_per_new_row": per_row}
+            print(f"{name} fit: {per_lane:.1f} ns/lane + {per_row:.1f} "
+                  f"ns/new row", flush=True)
     return out
 
 

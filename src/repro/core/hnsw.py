@@ -167,10 +167,10 @@ def _base_dist(q: jax.Array, x: jax.Array, p: float) -> jax.Array:
 # an XLA gather of every frontier row (`_score_frontier`). A DMA out of HBM
 # moves whole (8, 128) f32 layout tiles: a row of d % 1024 == 0 is d / 1024
 # of them, with no padding, while a narrower row would read a whole 4 KB
-# tile. The kernel costs about 50 ns per new row at any width, the gather
-# about 100 ns per row at d = 4096 and 18 ns at d = 1024, for every row: on
-# a TPU v5e the kernel wins at d = 4096 whatever the new share, and at
-# d = 1024 up to a share of about 0.35 (PERF.md, "Findings").
+# tile. The kernel costs ~220 ns a lane + 22 ns per new row (d = 4096; 210
+# + 21 at 1024), the gather ~100 ns per row at d = 4096 and 18 at 1024, for
+# every row: on a TPU v5e the kernel wins at d = 4096 whatever the new share,
+# and at d = 1024 up to a share of about 0.55 (PERF.md, "Findings").
 BEAM_FETCH_ROW_ELEMS = 1024
 
 

@@ -17,11 +17,19 @@ once per segment stack; each lane adds its segment's row offset to its ids.
 
 Pipeline. Outside the kernel, each lane's new ids are compacted to the
 front of its row (`fetch_score_lanes`); in the kernel, lane l's rows and
-its query go to ring slot l % depth on that slot's DMA semaphore, and
-lanes l + 1 .. l + depth - 1 are in flight while lane l is scored. The
-kernel returns each lane's scores in compacted order, +inf past its
-count; the wrapper puts them back in frontier order, +inf where the entry
-is not new.
+its query go to ring slot l % depth, and lanes l + 1 .. l + depth - 1 are
+in flight while lane l is scored. A lane's rows are scored in groups of 8:
+each group's copies count on a DMA semaphore of their own, so a group is
+scored as soon as its rows are in, while the rows behind it still arrive,
+and its 8 partial sums are one aligned (8, 128) store. Rows of the last
+group past the lane's count are whatever the slot held before; they are
+scored and masked, never fetched, since a padding row would cost its
+bytes. Whole groups' copies are issued unrolled. On a TPU v5e this took
+the kernel from about 110 ns a lane plus 46 ns per new row to about 220
+ns a lane plus 22 ns per new row at d = 4096 (scoring alone 16 -> 4 ns a
+row; PERF.md, "Findings"). The kernel returns
+each lane's scores in compacted order, +inf past its count; the wrapper
+puts them back in frontier order, +inf where the entry is not new.
 
 Batching. The loop body is vmapped twice (queries in `knn_search`,
 segments in `segmented_knn_search`); a vmapped `pallas_call` would run
@@ -42,6 +50,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.lp_ops import abs_pow
 
 _LANE = 128
+# rows scored together, one (8, 128) f32 tile of their partial sums
+_GROUP = 8
 # lanes whose rows are in flight at once, the one being scored included:
 # 4 and 8 read alike on a TPU v5e, 2 slower (PERF.md, "Findings")
 DEPTH = 4
@@ -60,28 +70,61 @@ def beam_rows(x: jax.Array) -> jax.Array:
 
 def _fetch_kernel(cnt_ref, rows_ref, q_hbm, src_hbm, o_ref, buf, qbuf, acc,
                   sem, *, p: float, n_q: int, lane0: int, depth: int):
-    """Every lane in turn: wait for its rows, score them, refill its slot
-    with the rows of the lane `depth - 1` further on. A lane with no new
-    row fetches nothing, its query included."""
+    """Every lane in turn: refill the ring slot of the lane `depth - 1`
+    further on, then score this lane's rows a group of 8 at a time, each
+    group as soon as its own rows are in. A lane with no new row fetches
+    nothing, its query included."""
     lanes, f = o_ref.shape
+    groups, s8 = f // _GROUP, buf.shape[2]
 
     def row_copy(lane, slot, r):
+        # a group's rows count on that group's semaphore; the query on its
+        # own, the slot's last
         row = src_hbm.at[rows_ref[lane * f + r]]
-        return pltpu.make_async_copy(row, buf.at[slot, r], sem.at[slot])
+        return pltpu.make_async_copy(row, buf.at[slot, r],
+                                     sem.at[slot, r // _GROUP])
 
     def query_copy(lane, slot):
         return pltpu.make_async_copy(q_hbm.at[(lane0 + lane) % n_q],
-                                     qbuf.at[slot], sem.at[slot])
+                                     qbuf.at[slot], sem.at[slot, groups])
+
+    def wait_rows(slot, g, n):
+        """Wait for n row copies of group g: a wait counts the bytes of
+        its descriptor, so a whole group is one wait."""
+        def rows_of(k):
+            return pltpu.make_async_copy(src_hbm.at[pl.ds(0, k)],
+                                         buf.at[slot, pl.ds(0, k)],
+                                         sem.at[slot, g])
+
+        @pl.when(n == _GROUP)
+        def _():
+            rows_of(_GROUP).wait()
+
+        @pl.when(n < _GROUP)
+        def _():
+            def one(j, _):
+                rows_of(1).wait()
+                return 0
+
+            jax.lax.fori_loop(0, n, one, 0)
 
     def start(lane):
         slot = lane % depth
+        count = cnt_ref[lane]
+        whole = count // _GROUP
+
+        def group(g, _):
+            for j in range(_GROUP):
+                row_copy(lane, slot, g * _GROUP + j).start()
+            return 0
 
         def one(r, _):
             row_copy(lane, slot, r).start()
             return 0
 
-        count = cnt_ref[lane]
-        jax.lax.fori_loop(0, count, one, 0)
+        # whole groups' copies unrolled, then the last group's one by one
+        jax.lax.fori_loop(0, whole, group, 0)
+        jax.lax.fori_loop(whole * _GROUP, count, one, 0)
 
         @pl.when(count > 0)
         def _():
@@ -98,20 +141,26 @@ def _fetch_kernel(cnt_ref, rows_ref, q_hbm, src_hbm, o_ref, buf, qbuf, acc,
         slot = lane % depth
         count = cnt_ref[lane]
 
-        def drain(r, _):
-            # every copy on the slot's semaphore moves one row's bytes
+        @pl.when(count > 0)
+        def _():
             query_copy(lane, slot).wait()
-            return 0
 
-        jax.lax.fori_loop(0, count + jnp.minimum(count, 1), drain, 0)
         qv = qbuf[slot]
 
-        def score(r, _):
-            a = abs_pow(buf[slot, r] - qv, p)
-            acc[pl.ds(r, 1), :] = jnp.sum(a, axis=0, keepdims=True)
+        def score(g, _):
+            # the group's 8 rows -> one aligned (8, 128) tile of per-row
+            # partial sums; rows past `count` hold an earlier lane's rows
+            # and are masked out below
+            r0 = pl.multiple_of(g * _GROUP, _GROUP)
+            wait_rows(slot, g, jnp.minimum(count - r0, _GROUP))
+            a = abs_pow(buf[slot, pl.ds(r0, _GROUP)] - qv[None], p)
+            part = a[:, :8]
+            for k in range(1, s8 // 8):
+                part = part + a[:, 8 * k:8 * k + 8]
+            acc[pl.ds(r0, _GROUP), :] = jnp.sum(part, axis=1)
             return 0
 
-        jax.lax.fori_loop(0, count, score, 0)
+        jax.lax.fori_loop(0, (count + _GROUP - 1) // _GROUP, score, 0)
         s = jnp.sum(acc[...], axis=1)
         live = jax.lax.broadcasted_iota(jnp.int32, (f,), 0) < count
         o_ref[lane, :] = jnp.where(live, s, jnp.inf)
@@ -129,25 +178,30 @@ def fetch_kernel_call(cnt, rows, q, src, *, p: float, interpret: bool,
     lane's count."""
     lanes = cnt.shape[0]
     f = rows.shape[0] // lanes
+    fg = -(-f // _GROUP) * _GROUP  # whole score groups; the rest never read
+    if fg != f:
+        rows = jnp.pad(rows.reshape(lanes, f), ((0, 0), (0, fg - f)))
+        rows = rows.reshape(-1)
     n_q, s8, lane_w = q.shape
     assert src.shape[1:] == (s8, lane_w), (q.shape, src.shape)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    return pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_fetch_kernel, p=float(p), n_q=n_q, lane0=lane0,
                           depth=depth),
         in_specs=[smem, smem, hbm, hbm],
         out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((lanes, f), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((lanes, fg), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((depth, f, s8, lane_w), jnp.float32),
+            pltpu.VMEM((depth, fg, s8, lane_w), jnp.float32),
             pltpu.VMEM((depth, s8, lane_w), jnp.float32),
-            pltpu.VMEM((f, lane_w), jnp.float32),
-            pltpu.SemaphoreType.DMA((depth,)),
+            pltpu.VMEM((fg, lane_w), jnp.float32),
+            pltpu.SemaphoreType.DMA((depth, fg // _GROUP + 1)),
         ],
         interpret=interpret,
         name="beam_fetch",
     )(cnt, rows, q, src)
+    return out if fg == f else out[:, :f]
 
 
 def fetch_score_lanes(q, ids, new, base, src, *, p: float, interpret: bool,

@@ -10,6 +10,10 @@ with the kernel in interpret mode:
     (relative error <= 1e-6) and +inf on every other entry: lanes with
     every entry new and lanes with none, sentinel ids, W = 2 frontiers
     after the dedupe sort, p in {1, 2};
+  * lanes whose counts sit at the edges of the kernel's 8-row score
+    groups (0, 1, 7, 8, 9, 31, 32, and past 32 at W = 2), at d 1024 and
+    4096: whole rows scored, +inf past the count even where the ring
+    slot still holds an earlier lane's rows;
   * a nested vmap (segments x queries) gives the per-lane calls' values;
   * the segmented search's level-0 loop holds exactly one kernel call,
     whose lane axis is segments x queries, at d = 1024, and none at
@@ -72,6 +76,41 @@ def test_kernel_scores_only_the_new_rows(p, w):
     np.testing.assert_array_equal(np.isinf(got), ~new)
     assert np.isinf(got[1]).all()
     rel = np.abs(got[new] - want[new]) / want[new]
+    assert rel.max() <= 1e-6, rel.max()
+
+
+# per-lane new-row counts at the edges of the kernel's 8-row score groups;
+# with DEPTH 4, lane l + 4 reuses lane l's ring slot, so each lane here
+# with fewer rows than the lane four before it finds that lane's rows
+# still in the slot past its own count
+_EDGE_COUNTS = {12: [12, 9, 11, 8, 7, 1, 0, 3, 0],
+                32: [32, 9, 31, 8, 7, 1, 0, 0, 1, 0, 9, 7],
+                64: [64, 57, 63, 33, 31, 9, 8, 32, 7, 1, 0, 64, 0]}
+
+
+@pytest.mark.parametrize("f", [12, 32, 64])
+@pytest.mark.parametrize("d", [1024, 4096])
+@pytest.mark.parametrize("p", [1.0, 2.0])
+def test_kernel_group_edges_score_whole_rows_and_nothing_stale(p, d, f):
+    """Lane counts 0, 1, 7, 8, 9, 31 and 32 (and past 32 at F = 64, W = 2;
+    F = 12, m0 = 6, is not whole groups): each lane's first `count` outputs
+    are `_base_dist` of its rows; every output past its count is +inf,
+    whatever the slot held before."""
+    counts = np.array(_EDGE_COUNTS[f], np.int32)
+    lanes, n = len(counts), 3 * f
+    rng = np.random.default_rng(d + f + int(p))
+    x = rng.standard_normal((n, d)).astype(np.float32)
+    q = rng.standard_normal((5, d)).astype(np.float32)
+    rows = rng.integers(0, n, (lanes, f)).astype(np.int32)
+    got = np.asarray(bf.fetch_kernel_call(
+        jnp.asarray(counts), jnp.asarray(rows.reshape(-1)),
+        jnp.asarray(q).reshape(5, d // 128, 128), bf.beam_rows(jnp.asarray(x)),
+        p=p, interpret=True))
+    live = np.arange(f)[None, :] < counts[:, None]
+    np.testing.assert_array_equal(np.isinf(got), ~live)
+    want = np.asarray(_base_dist(q[np.arange(lanes) % 5][:, None, :],
+                                 x[rows], p))
+    rel = np.abs(got[live] - want[live]) / want[live]
     assert rel.max() <= 1e-6, rel.max()
 
 

@@ -203,6 +203,22 @@ def test_beam_fetch_kernel_compiles(one_chip, d, p):
         _spec((S * N_SEG, d // 128, 128), one_chip))
 
 
+@pytest.mark.parametrize("p", [1.0, 2.0])
+def test_beam_fetch_kernel_compiles_at_w2(one_chip, p):
+    """W = 2: frontiers of 64 ids a lane, eight score groups of 8 rows."""
+    from repro.kernels import beam_fetch
+
+    lanes, d, f = S * B, 4096, 2 * M0
+    _assert_kernel_compiles(
+        lambda q, ids, new, row0, src: beam_fetch.fetch_score_lanes(
+            q, ids, new, row0, src, p=p, interpret=False),
+        _spec((B, d // 128, 128), one_chip),
+        _spec((lanes, f), one_chip, jnp.int32),
+        _spec((lanes, f), one_chip, jnp.bool_),
+        _spec((lanes,), one_chip, jnp.int32),
+        _spec((S * N_SEG, d // 128, 128), one_chip))
+
+
 def _candidate_program_text(one_chip, monkeypatch, d: int, fetch: bool):
     """The compiled segmented candidate program, W = 1, with the level-0
     loop on the fetch kernel (compiled, as on the chip) or on the gather."""
