@@ -222,12 +222,20 @@ class SearchStats(NamedTuple):
         # Each block past the first is one mid-scan abandonment check.
         # Weighted by n_p like n_dim_frac; 0.0 where nothing was verified.
     rows_read: jax.Array | int = 0  # (B,) CandidateSet.rows_read
+    n_verify_rows: jax.Array | int = 0  # (B,) candidate rows the
+        # abandoning verification's kernels copied: the valid ids of the
+        # first k, plus those of every kappa batch in which the row ran
+        # its scan (some candidate alive at entry). Sentinel ids and the
+        # kernels' lane padding copy nothing. 0 on the base-metric skip,
+        # and on the paths that do not count it (abandon=False, the
+        # two-band scan); the delta tier's scan is not in it.
 
     ROW_FIELDS = ("n_b", "n_p", "hops", "n_dim_frac", "n_b_probe",
                   "n_b_spill", "n_p_probe", "n_p_spill", "n_f32_rows_frac",
-                  "n_band_frac", "poisoned", "n_scan_blocks", "rows_read")
+                  "n_band_frac", "poisoned", "n_scan_blocks", "rows_read",
+                  "n_verify_rows")
     SKIPPED = {"n_p": 0, "n_dim_frac": 1.0, "n_f32_rows_frac": 1.0,
-               "n_band_frac": 0.0, "n_scan_blocks": 0.0}
+               "n_band_frac": 0.0, "n_scan_blocks": 0.0, "n_verify_rows": 0}
 
     @property
     def degraded(self) -> bool:
@@ -376,7 +384,10 @@ def _verify_abandon_impl(
     counted only where the last block is ragged: where block_d divides d
     a candidate's blocks are its scanned dimensions over block_d, so
     `verify_candidates` reads them off `n_dim_frac` and this program
-    stays free of the count (None).
+    stays free of the count (None). Last, the candidate rows the kernels
+    copied (B,) int32 (`SearchStats.n_verify_rows`): a batch's valid ids
+    count where the row ran its scan, which it did iff some candidate
+    scanned a dimension (nd > 0).
 
     When (x_scan, perm) are given, the blocked scan runs over the
     energy-ordered corpus view (UHNSWParams.energy_perm, DESIGN.md §10):
@@ -413,10 +424,16 @@ def _verify_abandon_impl(
     r_dist, r_ids = jax.lax.sort((r_dist, first), num_keys=1)
     n_p0 = jnp.full((B,), k, dtype=jnp.int32)
     ones = jnp.ones((B,), jnp.float32)
+    n = X.shape[0]
+
+    def n_valid(ids):
+        return ((ids >= 0) & (ids < n)).sum(axis=1, dtype=jnp.int32)
+
+    rows0 = n_valid(first)
 
     if n_batches == 0:
         return (r_ids, metrics._root(r_dist, p_col), n_p0, jnp.int32(0),
-                ones, None)
+                ones, None, rows0)
 
     dim0 = ones * (k * d)
     # the first k rows were scored whole: every block, as in dim0
@@ -427,7 +444,7 @@ def _verify_abandon_impl(
         return (i < n_batches) & ~jnp.all(done)
 
     def body(s):
-        i, r_ids, r_dist, done, n_p, dim_scan, *blocks = s
+        i, r_ids, r_dist, done, n_p, dim_scan, rows, *blocks = s
         start = k + i * kappa
         batch = jax.lax.dynamic_slice(cand_ids, (0, start), (B, kappa))
         bbase = jax.lax.dynamic_slice(cand_base, (0, start), (B, kappa))
@@ -456,23 +473,24 @@ def _verify_abandon_impl(
         n_p = n_p + jnp.where(done, 0, kappa)
         dim_scan = dim_scan + jnp.where(
             done, 0.0, nd.sum(axis=1).astype(jnp.float32))
+        rows = rows + jnp.where((nd > 0).any(axis=1), n_valid(batch), 0)
         if ragged:
             # a candidate entered every block it scanned any dimension of
             entered = ((nd + width - 1) // width).sum(axis=1).astype(
                 jnp.float32)
             blocks = [blocks[0] + jnp.where(done, 0.0, entered)]
         return (i + 1, r_ids, r_dist, done | newly_done, n_p, dim_scan,
-                *blocks)
+                rows, *blocks)
 
     state = (jnp.int32(0), r_ids, r_dist, jnp.zeros((B,), bool), n_p0,
-             dim0, *blocks0)
-    iters, r_ids, r_dist, done, n_p, dim_scan, *blocks = \
+             dim0, rows0, *blocks0)
+    iters, r_ids, r_dist, done, n_p, dim_scan, rows, *blocks = \
         jax.lax.while_loop(cond, body, state)
     # the denominator needs no separate carry: n_p accrues kappa under
     # exactly the mask dim_scan uses, so total offered work == n_p * d
     return (r_ids, metrics._root(r_dist, p_col), n_p, iters,
             dim_scan / (n_p.astype(jnp.float32) * d),
-            blocks[0] / n_p.astype(jnp.float32) if ragged else None)
+            blocks[0] / n_p.astype(jnp.float32) if ragged else None, rows)
 
 
 def _verify_two_band_impl(
@@ -643,8 +661,10 @@ def verify_candidates(
     n_f32_rows_frac and n_band_frac ((B,) on the two-band path, the
     record's defaults 1.0 / 0.0 elsewhere) and n_scan_blocks (B,), the
     mean dimension blocks of width block_d (None: `pick_abandon_block_d`)
-    entered per verified candidate. The candidate-generation fields are
-    the caller's (`SearchStats.with_candidates`).
+    entered per verified candidate, and on the default abandon path
+    n_verify_rows (B,), the candidate rows its kernels copied. The
+    candidate-generation fields are the caller's
+    (`SearchStats.with_candidates`).
 
     p follows the scalar-vs-vector contract (DESIGN.md §6): a Python float
     re-ranks the whole batch under one metric (one compiled program per p);
@@ -715,12 +735,12 @@ def verify_candidates(
                 jnp.atleast_1d(jnp.asarray(p, jnp.float32)),
                 k, kappa, tau, float(base_p), interpret, block_d,
                 x_scan, scan_perm)
-        ids, dists, n_p, iters, frac, blocks = out
+        ids, dists, n_p, iters, frac, blocks, rows = out
         if blocks is None:  # whole blocks: scanned dims over block_d
             blocks = frac * n_blocks
         return ids, dists, SearchStats(
             n_p=n_p, iterations=iters, base_p=base_p, n_dim_frac=frac,
-            n_scan_blocks=blocks)
+            n_scan_blocks=blocks, n_verify_rows=rows)
     if metrics.is_static_p(p):
         out = _verify_jit_s(Q, cand_ids, X, float(p), k, kappa, tau,
                             interpret)

@@ -329,7 +329,7 @@ def rowwise_lp_kernel_call(
 # X[ids] -> (B, C, d) in HBM, then the rowwise kernel — i.e. every gathered
 # row makes an HBM round trip before it is read once. Here the gather happens
 # *inside* the kernel: X stays HBM-resident (memory_space=ANY), and each
-# (TB, TC) output tile DMAs its TC candidate rows one-by-one into a (TC, d)
+# (TB, TC) output tile DMAs its candidate rows one by one into a (TC, d)
 # VMEM scratch, then runs one vectorized distance block over the scratch
 # (MXU dot for p=2, VPU elementwise otherwise). The (B, C, d) intermediate
 # never exists.
@@ -341,9 +341,10 @@ def rowwise_lp_kernel_call(
 # callers (kernels/ops.py) pad d to a lane multiple. Rows past the last
 # whole tile (n % tile rows of them) ride a small f32 `tail` operand.
 #
-# Ids outside [0, n) are padding sentinels (-1 from merges, n from beams):
-# they gather a clamped dummy row and score +inf, so callers can pass padded
-# id blocks straight through.
+# Ids outside [0, n) are padding sentinels (-1 from merges, n from beams),
+# and the wrappers pad the candidate axis past the caller's C to whole TC
+# blocks: neither kind of slot copies a row, and both score +inf, so
+# callers can pass padded id blocks straight through.
 # ---------------------------------------------------------------------------
 
 
@@ -369,18 +370,32 @@ def _row_source(x: jax.Array):
     return specs, (x, tail), scratch
 
 
-def _dma_gather_rows(ids_ref, i, x_hbm, tail_ref, stage_ref, wide_ref,
-                     gx_ref, sem, *, n: int, block_c: int):
-    """Copy the TC candidate rows of query row i into the (TC, d) f32
-    scratch `gx_ref`.
+def _slot_count(block_c: int, c: int):
+    """This grid step's share of the call's `c` logical candidates:
+    min(TC, c - j * TC) at candidate block j, a static c where one block
+    holds them all. Read at the kernel's top level (interpret mode binds
+    `program_id` only there)."""
+    if c <= block_c:
+        return c
+    return jnp.minimum(block_c, c - pl.program_id(1) * block_c)
 
-    Row ids are read as scalars from the SMEM id block. Each row's aligned
-    HBM tile is DMA'd into `stage_ref` and the row picked out by ref;
-    rows in the ragged last tile come from `tail_ref`. DMAs issue
-    sequentially (start/wait per row); a double-buffered variant would
-    overlap row j+1's copy with row j's compute, but the VMEM scratch
-    already bounds the win to DMA latency. Shared by the gather, abandon
-    and screen kernels.
+
+def _dma_gather_rows(ids_ref, i, x_hbm, tail_ref, stage_ref, wide_ref,
+                     gx_ref, sem, *, n: int, slots):
+    """Copy the candidate rows of query row i that the kernel scores into
+    the (TC, d) f32 scratch `gx_ref`.
+
+    The loop visits the first `slots` id columns (`_slot_count`), not the
+    wrapper's padding past the call's candidates. Each slot reads its id as a
+    scalar from the SMEM id block and copies a row only for an id in
+    [0, n): the row's aligned HBM tile is DMA'd into `stage_ref` and the
+    row picked out by ref, or a row of the ragged last tile comes from
+    `tail_ref`. DMAs issue one at a time (start, then wait). A slot that
+    copies nothing (a sentinel id, or a padding slot past c) keeps
+    whatever its scratch row held; every kernel masks that lane's output
+    (+inf, nd 0) and reduces only along a lane's own row, so a stale row
+    never reaches a scored lane. Shared by the gather, abandon and screen
+    kernels.
     """
     rows = stage_ref.shape[0]
     n_al = n - n % rows
@@ -388,9 +403,9 @@ def _dma_gather_rows(ids_ref, i, x_hbm, tail_ref, stage_ref, wide_ref,
     pick_ref = wide_ref if widen else stage_ref
 
     def gather(j, _):
-        idx = jnp.clip(ids_ref[i, j], 0, n - 1)
+        idx = ids_ref[i, j]
         if n_al:
-            @pl.when(idx < n_al)
+            @pl.when((idx >= 0) & (idx < n_al))
             def _():
                 base = pl.multiple_of(idx // rows * rows, rows)
                 cp = pltpu.make_async_copy(
@@ -401,12 +416,12 @@ def _dma_gather_rows(ids_ref, i, x_hbm, tail_ref, stage_ref, wide_ref,
                     wide_ref[...] = stage_ref[...].astype(jnp.float32)
                 gx_ref[pl.ds(j, 1), :] = pick_ref[pl.ds(idx - base, 1), :]
         if n_al < n:
-            @pl.when(idx >= n_al)
+            @pl.when((idx >= n_al) & (idx < n))
             def _():
                 gx_ref[pl.ds(j, 1), :] = tail_ref[pl.ds(idx - n_al, 1), :]
         return 0
 
-    jax.lax.fori_loop(0, block_c, gather, 0)
+    jax.lax.fori_loop(0, slots, gather, 0)
 
 
 def _split_p(refs, p):
@@ -418,20 +433,22 @@ def _split_p(refs, p):
     return head + tail, lambda i: p_ref[i, 0]
 
 
-def _gather_lp_kernel(*refs, p, root: bool, n: int, block_c: int):
+def _gather_lp_kernel(*refs, p, root: bool, n: int, block_c: int, c: int):
     """One (TB, TC) output tile.
 
-    Per query row: TC row copies (HBM -> VMEM scratch), then one vectorized
-    (TC, d) distance block. Scalar p (a static float) emits that p's op
-    sequence only; traced p (p=None, per-row SMEM scalars) selects per row.
+    Per query row: a copy of each candidate row (HBM -> VMEM scratch), then
+    one vectorized (TC, d) distance block. Scalar p (a static float) emits
+    that p's op sequence only; traced p (p=None, per-row SMEM scalars)
+    selects per row.
     """
     (ids_s, ids_v, q_ref, x_hbm, tail_ref, o_ref, stage_ref, wide_ref,
      gx_ref, sem), p_of = _split_p(refs, p)
     tb = q_ref.shape[0]
+    slots = _slot_count(block_c, c)
 
     def per_query(i, _):
         _dma_gather_rows(ids_s, i, x_hbm, tail_ref, stage_ref, wide_ref,
-                         gx_ref, sem, n=n, block_c=block_c)
+                         gx_ref, sem, n=n, slots=slots)
         pi = p_of(i)
         qi = q_ref[i, :].astype(jnp.float32)
         s = _row_dist_block(qi, gx_ref[...], pi)
@@ -464,11 +481,14 @@ def gather_lp_kernel_call(
     root: bool = False,
     block_b: int = 8,
     block_c: int = 128,
+    c: int,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> jax.Array:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0;
     compiled for TPU, d % 128 == 0 — zero columns are distance-neutral).
+    c: the caller's candidate count before its padding to C; id columns
+    past it are padding, copy no row and score +inf.
 
     p: Python float, or a pre-padded (B, 1) f32 array (one metric per query
     row — the mixed-p contract described in the module preamble).
@@ -485,7 +505,7 @@ def gather_lp_kernel_call(
     return pl.pallas_call(
         functools.partial(
             _gather_lp_kernel, p=None if vector_p else float(p), root=root,
-            n=n, block_c=block_c,
+            n=n, block_c=block_c, c=c,
         ),
         grid=(b // block_b, cc // block_c),
         in_specs=_gather_in_specs(block_b, block_c, d, vector_p) + src_specs,
@@ -592,17 +612,18 @@ def _blocked_scan(i, ids_v, sb_ref, thr, pi, gather_tile, block_fn,
 
 
 def _gather_abandon_kernel(*refs, p, base_p: float, n: int, d: int,
-                           block_c: int, block_d: int):
+                           block_c: int, block_d: int, c: int):
     (ids_s, ids_v, q_ref, th_ref, sb_ref, x_hbm, tail_ref, o_ref, nd_ref,
      stage_ref, wide_ref, gx_ref, dt_ref, sem), p_of = _split_p(refs, p)
     tb = q_ref.shape[0]
+    slots = _slot_count(block_c, c)
 
     def per_query(i, _):
         qi = q_ref[i, :].astype(jnp.float32)
 
         def gather_tile():
             _dma_gather_rows(ids_s, i, x_hbm, tail_ref, stage_ref, wide_ref,
-                             gx_ref, sem, n=n, block_c=block_c)
+                             gx_ref, sem, n=n, slots=slots)
             # one subtract + transpose; dimension blocks are sublane
             # slices of this (d, TC) diff tile
             dt_ref[...] = (gx_ref[...] - qi[None, :]).T
@@ -623,13 +644,13 @@ def _gather_abandon_kernel(*refs, p, base_p: float, n: int, d: int,
 
 
 def _blocked_call(kernel, ids, q, p, thresh, sb, src, extra, *, d: int,
-                  block_b: int, block_c: int, block_d: int,
+                  block_b: int, block_c: int, block_d: int, c: int,
                   out_dtypes, interpret: bool, **kw):
     """pallas_call shared by the abandon and screen kernels: operands are
     the gather head (ids, q, [p]), SMEM thresholds, base sums, whole-array
     VMEM `extra` operands, then the row source `src` (n, dx); d <= dx is
     the logical width scanned, in ceil(d / block_d) blocks that dx must
-    hold."""
+    hold; c is the caller's candidate count before padding to C."""
     b, dx = q.shape
     b2, cc = ids.shape
     n = src.shape[0]
@@ -645,7 +666,7 @@ def _blocked_call(kernel, ids, q, p, thresh, sb, src, extra, *, d: int,
     return pl.pallas_call(
         functools.partial(
             kernel, p=None if vector_p else float(p), n=n, d=d,
-            block_c=block_c, block_d=block_d, **kw,
+            block_c=block_c, block_d=block_d, c=c, **kw,
         ),
         grid=(b // block_b, cc // block_c),
         in_specs=_gather_in_specs(block_b, block_c, dx, vector_p)
@@ -678,13 +699,15 @@ def gather_lp_abandon_kernel_call(
     block_b: int = 8,
     block_c: int = 128,
     block_d: int = 32,
+    c: int,
     interpret: bool = False,
     out_dtype=jnp.float32,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0,
     ceil(d / block_d) * block_d <= dx; d = logical width, default dx —
-    compiled for TPU, dx % 128 == 0). Returns (dists (B, C) root-free power sums with
-    +inf for abandoned/padding candidates, nd (B, C) int32 dimensions
+    compiled for TPU, dx % 128 == 0; c = the caller's candidate count
+    before padding to C). Returns (dists (B, C) root-free power sums
+    with +inf for abandoned/padding candidates, nd (B, C) int32 dimensions
     scanned).
 
     p: Python float, or a pre-padded (B, 1) f32 array (one metric per query
@@ -694,7 +717,7 @@ def gather_lp_abandon_kernel_call(
     return _blocked_call(
         _gather_abandon_kernel, ids, q, p, thresh, sb, x, (),
         d=q.shape[1] if d is None else d, block_b=block_b, block_c=block_c,
-        block_d=block_d, out_dtypes=(out_dtype, jnp.int32),
+        block_d=block_d, c=c, out_dtypes=(out_dtype, jnp.int32),
         interpret=interpret, base_p=base_p)
 
 
@@ -726,18 +749,19 @@ def gather_lp_abandon_kernel_call(
 
 
 def _gather_screen_kernel(*refs, p, base_p: float, n: int, d: int,
-                          block_c: int, block_d: int):
+                          block_c: int, block_d: int, c: int):
     (ids_s, ids_v, q_ref, th_ref, sb_ref, sc_ref, rad_ref, codes_hbm,
      tail_ref, keep_ref, nd_ref, stage_ref, wide_ref, gx_ref, dt_ref,
      sem), p_of = _split_p(refs, p)
     tb = q_ref.shape[0]
+    slots = _slot_count(block_c, c)
 
     def per_query(i, _):
         qi = q_ref[i, :].astype(jnp.float32)
 
         def gather_tile():
             _dma_gather_rows(ids_s, i, codes_hbm, tail_ref, stage_ref,
-                             wide_ref, gx_ref, sem, n=n, block_c=block_c)
+                             wide_ref, gx_ref, sem, n=n, slots=slots)
             # dequant + subtract + transpose once; dimension blocks are
             # sublane slices of this (d, TC) |q - x̂| tile
             dt_ref[...] = jnp.abs(gx_ref[...] * sc_ref[...] - qi[None, :]).T
@@ -774,11 +798,13 @@ def gather_lp_screen_kernel_call(
     block_b: int = 8,
     block_c: int = 128,
     block_d: int = 32,
+    c: int,
     interpret: bool = False,
 ) -> tuple[jax.Array, jax.Array]:
     """Raw pallas_call for pre-padded inputs (B % block_b == C % block_c == 0,
     ceil(d / block_d) * block_d <= dx; d = logical width, default dx —
-    compiled for TPU, dx % 128 == 0). Returns (keep (B, C) int32 — 1 iff the candidate
+    compiled for TPU, dx % 128 == 0; c = the caller's candidate count
+    before padding to C). Returns (keep (B, C) int32 — 1 iff the candidate
     survived the screen and its f32 row must be gathered for the exact
     rerank, nd (B, C) int32 band dimensions scanned).
 
@@ -793,5 +819,5 @@ def gather_lp_screen_kernel_call(
     return _blocked_call(
         _gather_screen_kernel, ids, q, p, thresh, sb, codes, (scale, radius),
         d=dx if d is None else d, block_b=block_b, block_c=block_c,
-        block_d=block_d, out_dtypes=(jnp.int32, jnp.int32),
+        block_d=block_d, c=c, out_dtypes=(jnp.int32, jnp.int32),
         interpret=interpret, base_p=base_p)

@@ -5,10 +5,11 @@ Responsibilities:
   * padding arbitrary (B, N, C) up to tile multiples and slicing the result;
   * operand layout for Mosaic: per-row p and thresholds go in as
     pre-padded (B, 1) f32 columns the kernels block into SMEM; candidate
-    ids as (B, C) int32, -1-padded; the compiled gather family reads its
-    row source (corpus, band codes) in `kernel_rows` layout — the feature
-    axis zero-padded to a lane multiple once, by the index that owns it —
-    and pads only the queries to match;
+    ids as (B, C) int32, -1-padded, with the caller's C passed on so
+    the kernels copy no row for a padding column; the compiled gather
+    family reads its row source (corpus, band codes) in `kernel_rows`
+    layout — the feature axis zero-padded to a lane multiple once, by
+    the index that owns it — and pads only the queries to match;
   * interpret-mode fallback on non-TPU backends (tests run on the CPU with
     the kernel bodies in interpret mode or the jnp reference; on a TPU the
     same code lowers to Mosaic);
@@ -348,7 +349,7 @@ def _gather_impl(q, ids, x, p, root=False, interpret=None, block_b=None,
     # apply the root *outside* the kernel on the (B, C) result: for root=True
     # callers this keeps the kernel body identical across root modes.
     out = _k.gather_lp_kernel_call(
-        ip, qp, x, pp, root=False, block_b=tb, block_c=tc,
+        ip, qp, x, pp, root=False, block_b=tb, block_c=tc, c=cc,
         interpret=interpret,
     )[:b, :cc]
     return _root_rows(out, p, root)
@@ -424,7 +425,7 @@ def _abandon_impl(q, ids, x, thresh, sb, p, base_p, root=False,
     qp, ip, pp, tp, sp = _gather_operands(q, ids, p, thresh, sb, tb, tc)
     out, nd = _k.gather_lp_abandon_kernel_call(
         ip, qp, tp, sp, x, pp, d=d, base_p=base_p, block_b=tb, block_c=tc,
-        block_d=bd, interpret=interpret,
+        block_d=bd, c=cc, interpret=interpret,
     )
     return _root_rows(out[:b, :cc], p, root), nd[:b, :cc]
 
@@ -494,7 +495,7 @@ def _screen_impl(q, ids, codes, scale, radius, thresh, sb, p, base_p,
     keep, nd = _k.gather_lp_screen_kernel_call(
         ip, qp, tp, sp, _pad_axis(scale, 0, dx, 0.0)[None, :],
         _pad_axis(radius, 0, dx, 0.0)[:, None], codes, pp, d=d,
-        base_p=base_p, block_b=tb, block_c=tc, block_d=bd,
+        base_p=base_p, block_b=tb, block_c=tc, block_d=bd, c=cc,
         interpret=interpret,
     )
     return keep[:b, :cc].astype(bool), nd[:b, :cc]

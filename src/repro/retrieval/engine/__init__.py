@@ -18,7 +18,8 @@ The engine shares the service's stats dict (`default_stats` is the one
 schema both write): Eq. 1 counters, per-base/per-p attribution, flush
 reasons, shed/degraded counts, the level-0 beam loop's lane occupancy
 (`beam_lane_trips` / `beam_lane_slots`) and the corpus rows it read
-(`beam_rows_read`), and per-request latency records
+(`beam_rows_read`), the candidate rows verification copied
+(`verify_rows`), and per-request latency records
 that separate queue-wait from device-compute and flag cold
 (first-compile) program shapes.
 
@@ -180,6 +181,9 @@ def default_stats() -> dict:
         # frontier row where the loop gathers it whole, only the rows its
         # visited test marks new where the fetch kernel runs
         "beam_rows_read": 0,
+        # candidate rows the verification kernels copied (real rows only):
+        # the first k's and each scanned kappa batch's valid ids
+        "verify_rows": 0,
         # per-request latency; bounded so a long-running service cannot
         # grow it without limit (latency_summary reports over the window).
         # latency_records holds (total_ms, queue_ms, compute_ms, cold) per
@@ -219,6 +223,7 @@ def accumulate_stats(st: dict, base: float, rows, ps, padded_rows: int):
     st["beam_lane_trips"] += int(rows.hops.sum())
     st["beam_lane_slots"] += int(rows.hops_max.sum()) * n
     st["beam_rows_read"] += int(rows.rows_read.sum())
+    st["verify_rows"] += int(rows.n_verify_rows.sum())
     pb = st["per_base"]["G1" if base == 1.0 else "G2"]
     pb["queries"] += n
     pb["batches"] += 1

@@ -169,6 +169,56 @@ def _gather_case(seed, b, c, n, d, sentinels=True):
     return q, jnp.asarray(ids), x, ids
 
 
+def _sentinel_case(seed, b, c, n, d):
+    """Real candidate ids with the sentinels -1 and n interleaved, over an
+    X whose rows 0 and n - 1 (where a padding slot's id would clamp to)
+    are NaN and never a candidate; plus `clean`, the same block with every
+    sentinel swapped for a real id."""
+    rng = np.random.default_rng(seed)
+    q = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32) * 3)
+    x = rng.normal(size=(n, d)).astype(np.float32) * 3
+    x[[0, n - 1]] = np.nan
+    ids = rng.integers(1, n - 1, size=(b, c)).astype(np.int32)
+    ids[:, ::3] = -1
+    ids[:, 1::4] = n
+    ids[0, 2], ids[-1, 2] = 1, n - 2   # the first real row, a tail row
+    valid = (ids >= 0) & (ids < n)
+    clean = np.where(valid, ids, rng.integers(1, n - 1, size=ids.shape))
+    return q, jnp.asarray(x), ids, clean.astype(np.int32), valid
+
+
+# the engine's shapes: its first k = 10 and kappa = 5 batches in one grid
+# step of TC = 128; and C = 300 over three steps of TC = 128, the last
+# holding 44 candidates
+COPY_CASES = [(5, None), (10, None), (300, 128)]
+COPY_P = {0.8: 0.8, 2.0: 2.0,
+          "vector": np.asarray([0.5, 0.8, 1.25, 2.0, 1.5, 2.0], np.float32)}
+
+
+@pytest.mark.parametrize("p", list(COPY_P))
+@pytest.mark.parametrize("c,block_c", COPY_CASES)
+def test_gather_kernel_copies_only_scored_rows(c, block_c, p):
+    """Sentinel ids and the lane padding past c copy no row: every padding
+    slot scores +inf, and every real slot is bitwise what it is when the
+    sentinels are real ids (a skipped slot's stale scratch row reaches no
+    scored lane) and bitwise the rowwise oracle, where the row's p is not
+    2 (p = 2 takes the MXU identity, within the oracle's tolerance)."""
+    b, n = 6, 203  # n % 8 = 3: the last rows ride the kernel's tail block
+    q, x, ids, clean, valid = _sentinel_case(c, b, c, n, d=48)
+    p = COPY_P[p]
+    got, again = (np.asarray(lp_gather_distance(
+        q, jnp.asarray(i), x, p, interpret=True, block_c=block_c))
+        for i in (ids, clean))
+    assert np.isinf(got[~valid]).all()
+    np.testing.assert_array_equal(got[valid], again[valid])
+    want = np.asarray(rowwise_lp_ref(q, x[np.clip(ids, 0, n - 1)], p,
+                                     root=False))
+    mxu = np.broadcast_to(np.asarray(p).reshape(-1, 1) == 2.0, ids.shape)
+    np.testing.assert_array_equal(got[valid & ~mxu], want[valid & ~mxu])
+    np.testing.assert_allclose(got[valid & mxu], want[valid & mxu],
+                               rtol=3e-5)
+
+
 @pytest.mark.parametrize("p", P_GATHER)
 @pytest.mark.parametrize("root", [False, True])
 def test_gather_kernel_matches_rowwise_ref(p, root):

@@ -49,6 +49,7 @@ ENGINE_KEY = {
     "poisoned": ("poison_detected", False),
     "n_scan_blocks": ("scan_blocks_w", True),
     "rows_read": ("beam_rows_read", False),
+    "n_verify_rows": ("verify_rows", False),
 }
 
 

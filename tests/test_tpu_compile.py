@@ -77,27 +77,54 @@ def _assert_kernel_compiles(fn, *specs):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("p", P_GRID)
-@pytest.mark.parametrize("d", D_GRID)
-def test_gather_kernel_compiles(one_chip, d, p):
-    extra, p_of = _p_args(p, one_chip)
+def _compile_gather(sharding, d, c, p):
+    extra, p_of = _p_args(p, sharding)
     _assert_kernel_compiles(
         lambda q, ids, x, *e: ops.lp_gather_distance(
             q, ids, x, p_of(e), interpret=False),
-        _spec((B, d), one_chip), _spec((B, C), one_chip, jnp.int32),
-        _spec((N, _rows(d)), one_chip), *extra)
+        _spec((B, d), sharding), _spec((B, c), sharding, jnp.int32),
+        _spec((N, _rows(d)), sharding), *extra)
+
+
+def _compile_abandon(sharding, d, c, p):
+    extra, p_of = _p_args(p, sharding)
+    _assert_kernel_compiles(
+        lambda q, ids, x, thr, sb, *e: ops.lp_gather_abandon(
+            q, ids, x, thr, sb, p_of(e), interpret=False),
+        _spec((B, d), sharding), _spec((B, c), sharding, jnp.int32),
+        _spec((N, _rows(d)), sharding), _spec((B,), sharding),
+        _spec((B, c), sharding), *extra)
+
+
+@pytest.mark.parametrize("p", P_GRID)
+@pytest.mark.parametrize("d", D_GRID)
+def test_gather_kernel_compiles(one_chip, d, p):
+    _compile_gather(one_chip, d, C, p)
 
 
 @pytest.mark.parametrize("p", P_GRID)
 @pytest.mark.parametrize("d", D_GRID)
 def test_abandon_kernel_compiles(one_chip, d, p):
-    extra, p_of = _p_args(p, one_chip)
-    _assert_kernel_compiles(
-        lambda q, ids, x, thr, sb, *e: ops.lp_gather_abandon(
-            q, ids, x, thr, sb, p_of(e), interpret=False),
-        _spec((B, d), one_chip), _spec((B, C), one_chip, jnp.int32),
-        _spec((N, _rows(d)), one_chip), _spec((B,), one_chip),
-        _spec((B, C), one_chip), *extra)
+    _compile_abandon(one_chip, d, C, p)
+
+
+# the shapes verification calls at k = 10: its first k and its kappa = 5
+# batches, each one grid step of 128 candidate lanes with a static slot
+# count; and trevi's width, which D_GRID lacks, at C too
+ENGINE_SHAPES = [(d, c) for d in (100, 256, 4096) for c in (5, 10)] + \
+    [(4096, C)]
+
+
+@pytest.mark.parametrize("p", P_GRID)
+@pytest.mark.parametrize("d,c", ENGINE_SHAPES)
+def test_gather_kernel_compiles_at_engine_shapes(one_chip, d, c, p):
+    _compile_gather(one_chip, d, c, p)
+
+
+@pytest.mark.parametrize("p", P_GRID)
+@pytest.mark.parametrize("d,c", ENGINE_SHAPES)
+def test_abandon_kernel_compiles_at_engine_shapes(one_chip, d, c, p):
+    _compile_abandon(one_chip, d, c, p)
 
 
 @pytest.mark.parametrize("p", P_GRID)

@@ -177,6 +177,42 @@ def test_abandon_kernel_interpret_matches_ref(p, d):
     np.testing.assert_array_equal(np.asarray(r_nd), np.asarray(v_nd))
 
 
+@pytest.mark.parametrize("p", [0.8, 1.25, "vector"])
+@pytest.mark.parametrize("c,block_c", [(5, None), (10, None), (300, 128)])
+def test_abandon_kernel_copies_only_scored_rows(c, block_c, p):
+    """The engine's batches (c = 5 and 10 in one grid step of TC = 128)
+    and C = 300 over three steps, with the sentinels -1 and n among the
+    real ids and X's rows 0 and n - 1 NaN (where a padding slot's id
+    would clamp to): the interpret kernel is bitwise the blocked
+    reference on distances and scanned-dim counts, padding slots score
+    +inf and scan 0 dimensions."""
+    b, n, d = 6, 203, 64
+    rng = np.random.default_rng(c)
+    q = jnp.asarray(rng.normal(size=(b, d)).astype(np.float32) * 2)
+    xs = rng.normal(size=(n, d)).astype(np.float32) * 2
+    xs[[0, n - 1]] = np.nan
+    x = jnp.asarray(xs)
+    ids = rng.integers(1, n - 1, size=(b, c)).astype(np.int32)
+    ids[:, ::3] = -1
+    ids[:, 1::4] = n
+    valid = (ids >= 0) & (ids < n)
+    if p == "vector":
+        p = jnp.asarray(rng.choice(P_GRID, size=b).astype(np.float32))
+    sb = _base_power(q, x, jnp.asarray(ids), 1.0)
+    full = np.asarray(lp_gather_distance(q, jnp.asarray(ids), x, p))
+    thr = jnp.asarray(np.nanquantile(np.where(valid, full, np.nan), 0.6,
+                                     axis=1).astype(np.float32))
+    out, nd = (np.asarray(a) for a in lp_gather_abandon(
+        q, jnp.asarray(ids), x, thr, sb, p, base_p=1.0, interpret=True,
+        block_c=block_c))
+    r_out, r_nd = (np.asarray(a) for a in lp_gather_abandon(
+        q, jnp.asarray(ids), x, thr, sb, p, base_p=1.0))
+    np.testing.assert_array_equal(out, r_out)
+    np.testing.assert_array_equal(nd, r_nd)
+    assert np.isinf(out[~valid]).all() and (nd[~valid] == 0).all()
+    assert np.isfinite(out[valid]).any() and (nd > 0).any(axis=1).all()
+
+
 def test_abandon_vector_p_rows_match_scalar():
     """One traced mixed-p program == per-p scalar programs, row by row."""
     q, x, ids, rng = _case(seed=9, d=64)
@@ -305,6 +341,45 @@ def test_verify_abandon_padding_rows():
         cand_base=jnp.asarray(cand_base), base_p=1.0, abandon=True)
     assert np.all(np.asarray(ids) >= 0) and np.all(np.asarray(ids) < n)
     assert np.isfinite(np.asarray(dists)).all()
+
+
+@pytest.mark.parametrize("p", [0.8, 1.5, "vector"])
+def test_verify_rows_count_every_live_batch_without_bounds(p):
+    """Without base sums no entry bound kills a candidate, so a row runs
+    its scan in every batch it verifies: `n_verify_rows` is the valid ids
+    of its first k and of its first (n_p - k) / kappa batches (the case's
+    last two columns are -1 sentinels, which copy nothing)."""
+    q, x, cand, _ = _verify_case(d=64)
+    k, kappa = 10, 5
+    if p == "vector":
+        p = jnp.asarray(np.resize(np.float32(P_GRID), q.shape[0]))
+    _, _, st = verify_candidates(q, cand, x, p, k, kappa, 0.92,
+                                 abandon=True)
+    n_p = np.asarray(st.n_p)
+    valid = np.asarray(cand) >= 0
+    want = [valid[i, :n_p[i]].sum() for i in range(len(n_p))]
+    np.testing.assert_array_equal(np.asarray(st.n_verify_rows), want)
+    assert (n_p < cand.shape[1]).any()  # some rows stop before the tail
+
+
+def test_verify_rows_on_an_index(monolithic_index, small_ds):
+    """On a small index at kappa = k / 2 = 5, with no sentinel ids among
+    the candidates: each row copied its first k rows plus 5 for every
+    batch whose scan ran, at most one per batch it verified; a query
+    whose p is its base metric verifies nothing and copies nothing."""
+    k = 10
+    idx = monolithic_index
+    q = jnp.asarray(small_ds.queries)
+    cands = idx.search_stage_candidates(q, 1.0, k=k)
+    assert (np.asarray(cands.ids) >= 0).all()
+    for p in (0.5, 0.8, 1.25):
+        _, _, st = idx.search_stage_finish(q, cands, p, k)
+        rows, n_p = np.asarray(st.n_verify_rows), np.asarray(st.n_p)
+        assert ((rows - k) % 5 == 0).all() and (rows >= k).all(), p
+        assert (rows <= n_p).all(), p
+    for base_p in (1.0, 2.0):
+        _, _, st = idx.search(q, base_p, k)
+        assert np.all(np.asarray(st.n_verify_rows) == 0)
 
 
 def test_verify_abandon_false_is_legacy_bitwise():
