@@ -23,12 +23,24 @@ reasons, shed/degraded counts, the level-0 beam loop's lane occupancy
 that separate queue-wait from device-compute and flag cold
 (first-compile) program shapes.
 
-Each wave's stages run inside `jax.profiler.TraceAnnotation` spans
-(`engine.make_waves`, `engine.dispatch_search`, `engine.dispatch_finish`,
-`engine.collect` and, inside it, `engine.collect.wait`), tagged with the
-wave's sequence number (`wave=`) and padded size (`rows=`): a profile
-shows which stage of the engine's host work each device idle gap falls
-in. With no profiling session active the annotations are inert.
+The engine's host time is counted without a profiler (`telemetry.py`).
+Every `admit`, `pump` and `drain` call is cut, with no gaps and no
+overlaps, into stages whose seconds add into flat stats keys:
+`eng_s_admit`, `eng_s_poll`, `eng_s_make_waves`,
+`eng_s_dispatch_search`, `eng_s_dispatch_finish`, `eng_s_collect` (less
+the wait inside it), `eng_s_collect_wait` and `eng_s_other` (the rest:
+`_maintain`, fault boundaries, recovery, loop overhead). From the first
+admission on, the pipeline's state is clocked too: `eng_s_inflight` (a
+wave dispatched and not yet collected), `eng_s_idle_no_work` (nothing in
+flight or queued), `eng_s_idle_held` (requests queued, no flush due) and
+`eng_s_idle_dispatch` (a flush up to its first stage-A dispatch). Each
+named stage also opens the `jax.profiler.TraceAnnotation` of its name
+(`engine.admit`, `engine.poll`, `engine.make_waves`,
+`engine.dispatch_search`, `engine.dispatch_finish`, `engine.collect`
+and, inside it, `engine.collect.wait`), tagged with the next or current
+wave's sequence number (`wave=`) and its rows (`rows=`): a profile shows
+which stage of the engine's host work each device idle gap falls in.
+With no profiling session active the annotations are inert.
 
 Fault tolerance (DESIGN.md §9): every device interaction — stage A/B
 dispatch and host collection — sits behind a fault boundary. A wave that
@@ -55,7 +67,6 @@ from collections import deque
 from types import SimpleNamespace
 
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from repro.core.metrics import base_metric_for
 from repro.index.health import QUARANTINED
@@ -83,6 +94,15 @@ from repro.retrieval.engine.scheduler import (
     ManualClock,
     bucket_ladder,
     chunk_plan,
+)
+from repro.retrieval.engine.telemetry import (
+    DISPATCH,
+    HELD,
+    INFLIGHT,
+    NO_WORK,
+    STAGE_KEYS,
+    STATE_KEYS,
+    HostLedger,
 )
 
 # engine lifecycle states (satellite: admissions are rejected — not
@@ -184,6 +204,11 @@ def default_stats() -> dict:
         # candidate rows the verification kernels copied (real rows only):
         # the first k's and each scanned kappa batch's valid ids
         "verify_rows": 0,
+        # host seconds on the engine's clock (telemetry.py): every engine
+        # call cut into stages (eng_s_admit ... eng_s_other), and, from
+        # the first admission on, the pipeline's time cut into its states
+        # (eng_s_inflight, eng_s_idle_no_work / _held / _dispatch)
+        **dict.fromkeys(STAGE_KEYS + STATE_KEYS, 0.0),
         # per-request latency; bounded so a long-running service cannot
         # grow it without limit (latency_summary reports over the window).
         # latency_records holds (total_ms, queue_ms, compute_ms, cold) per
@@ -257,8 +282,9 @@ class ServingEngine:
         self.policy = policy or EnginePolicy()
         self.clock = clock if clock is not None else time.perf_counter
         self.sched = BucketScheduler(self.policy, self.clock)
-        self.pipeline = TwoStagePipeline(index)
         self.stats = stats if stats is not None else default_stats()
+        self.ledger = HostLedger(self)
+        self.pipeline = TwoStagePipeline(index, self.ledger)
         # None = no injection and ZERO overhead: the device-call boundary
         # is one attribute `is not None` test (the acceptance criterion)
         self.fault_injector = fault_injector
@@ -306,20 +332,23 @@ class ServingEngine:
         not serve it."""
         self._check_live()
         admitted = []
-        for r in requests:
-            if self.sched.over_watermark():
-                if self.policy.overload == SHED:
-                    r.stage = STAGE_SHED
-                    self.stats["shed"] += 1
-                    continue
-                if not r.exact:  # DEGRADE: short-circuit past verification
-                    r.exact = True
-                    r.degraded = True
-                    self.stats["degraded"] += 1
-            self.sched.admit(r)
-            admitted.append(r)
-        self.stats["queue_peak"] = max(self.stats["queue_peak"],
-                                       self.sched.depth)
+        with self.ledger.stage("engine.admit", self._wave_seq,
+                               len(requests)):
+            for r in requests:
+                if self.sched.over_watermark():
+                    if self.policy.overload == SHED:
+                        r.stage = STAGE_SHED
+                        self.stats["shed"] += 1
+                        continue
+                    if not r.exact:  # DEGRADE: skip past verification
+                        r.exact = True
+                        r.degraded = True
+                        self.stats["degraded"] += 1
+                self.sched.admit(r)
+                admitted.append(r)
+            self.stats["queue_peak"] = max(self.stats["queue_peak"],
+                                           self.sched.depth)
+        self.ledger.to(self._rest(), start=True)
         return admitted
 
     def submit(self, r, now: float | None = None) -> EngineRequest | None:
@@ -338,22 +367,42 @@ class ServingEngine:
         ready to overlap with, and holding a dispatched wave for a
         *future* arrival would charge that wave the inter-arrival gap —
         exactly what a latency-first engine must not do."""
-        self._maintain()
-        flushes = self.sched.poll(now)
-        while flushes:
-            self._run(flushes)
-            flushes = self.sched.poll(now)
-        self._settle()
+        with self.ledger.call(self._rest):
+            self._maintain()
+            flushes = self._poll(self.sched.poll, now)
+            while flushes:
+                self._run(flushes)
+                flushes = self._poll(self.sched.poll, now)
+            self._settle()
 
     def drain(self, now: float | None = None) -> dict[int, tuple]:
         """Flush everything queued, finish the pipeline, and hand back
         all results accumulated since the last drain."""
-        self._maintain()
-        self._run(self.sched.poll(now))          # due flushes keep their
-        self._run(self.sched.flush_all(now))     # full/deadline reasons
-        self._settle()
+        with self.ledger.call(self._rest):
+            self._maintain()
+            # due flushes keep their full/deadline reasons
+            self._run(self._poll(self.sched.poll, now))
+            self._run(self._poll(self.sched.flush_all, now))
+            self._settle()
         out, self._results = self._results, {}
         return out
+
+    def _poll(self, decide, now: float | None) -> list[Flush]:
+        """One flush decision (`sched.poll` or `sched.flush_all`) as the
+        `engine.poll` stage. A flush with nothing in flight starts the
+        dispatch state at the poll's first stamp."""
+        with self.ledger.stage("engine.poll", self._wave_seq,
+                               self.sched.depth) as t0:
+            flushes = decide(now)
+        if flushes and self.ledger.state != INFLIGHT:
+            self.ledger.to(DISPATCH, t0)
+        return flushes
+
+    def _rest(self) -> str:
+        """The pipeline's state at the end of an engine call."""
+        if self._inflight is not None:
+            return INFLIGHT
+        return HELD if self.sched.depth else NO_WORK
 
     def serve(self, requests: list[EngineRequest]) -> dict[int, tuple]:
         self.admit(requests)
@@ -401,9 +450,11 @@ class ServingEngine:
         exact-base p values compile per scalar p and should be listed
         explicitly if the traffic is known to contain them. Served
         counters/latency stats are left untouched (the shapes do land in
-        the cold-detection set). Returns device batches executed."""
+        the cold-detection set), and the pipeline's state clocks stop
+        until the next admission. Returns device batches executed."""
         zero = np.zeros(self.index.dim, np.float32)
         keep_stats, self.stats = self.stats, default_stats()
+        self.ledger.state = None   # warm-up is no traffic
         keep_results, self._results = self._results, {}
         # warmup is a compile pass, not traffic — never inject faults into
         # it (and never burn the injector's deterministic draw sequence)
@@ -426,8 +477,8 @@ class ServingEngine:
 
     def _make_waves(self, fl: Flush) -> list[Wave]:
         """Cut a flush into ladder waves numbered on from the last."""
-        with TraceAnnotation("engine.make_waves", wave=self._wave_seq,
-                             rows=len(fl.requests)):
+        with self.ledger.stage("engine.make_waves", self._wave_seq,
+                               len(fl.requests)):
             waves = make_waves(fl, self.policy.ladder, self._wave_seq)
         self._wave_seq += len(waves)
         return waves
@@ -697,7 +748,7 @@ class ServingEngine:
     # -- collection + stats --------------------------------------------------
 
     def _collect(self, wave: Wave) -> None:
-        with wave.span("engine.collect"):
+        with wave.span(self.ledger, "engine.collect"):
             self._collect_wave(wave)
 
     def _collect_wave(self, wave: Wave) -> None:

@@ -25,12 +25,17 @@ A `Wave` is one device-call unit: a ladder-sized, padded, homogeneous
 (base, k, exact) slice of a scheduler flush. Its query tensor and
 candidate set stay device-resident between the stages.
 
-Each stage runs inside a `jax.profiler.TraceAnnotation` named for it
-(`engine.dispatch_search`, `engine.dispatch_finish`, and
+Each stage runs inside a stage of the engine's host-time ledger
+(`telemetry.HostLedger.stage`): a `jax.profiler.TraceAnnotation` named
+for it (`engine.dispatch_search`, `engine.dispatch_finish`, and
 `engine.collect.wait` around the blocking read), tagged with the wave's
 sequence number and padded size, so a profile puts the engine's host
-work on the device trace's clock wave by wave. Outside a profiling
-session an annotation does nothing.
+work on the device trace's clock wave by wave, and the counter of the
+same name (`eng_s_dispatch_search`, `eng_s_dispatch_finish`,
+`eng_s_collect_wait`) that adds the stage's host seconds into the
+engine's stats. Outside a profiling session an annotation does nothing;
+the counters always count. The end of a stage-A dispatch puts the
+pipeline in its in-flight state.
 """
 
 from __future__ import annotations
@@ -38,7 +43,6 @@ from __future__ import annotations
 from dataclasses import dataclass
 
 import numpy as np
-from jax.profiler import TraceAnnotation
 
 from repro.retrieval.engine.request import (
     DONE,
@@ -47,6 +51,7 @@ from repro.retrieval.engine.request import (
     EngineRequest,
 )
 from repro.retrieval.engine.scheduler import Flush, chunk_plan
+from repro.retrieval.engine.telemetry import INFLIGHT, HostLedger
 
 
 @dataclass
@@ -67,9 +72,9 @@ class Wave:
     health_gen: int | None = None    # health generation at stage-A dispatch
     seq: int = 0                     # the engine's wave sequence number
 
-    def span(self, name: str) -> TraceAnnotation:
-        """A profiler annotation of one of this wave's stages."""
-        return TraceAnnotation(name, wave=self.seq, rows=self.size)
+    def span(self, ledger: HostLedger, name: str):
+        """One of this wave's stages: its profiler span and its counter."""
+        return ledger.stage(name, self.seq, self.size)
 
     @property
     def n_real(self) -> int:
@@ -123,12 +128,14 @@ class TwoStagePipeline:
     """
 
     index: object  # UHNSW | ShardedUHNSW (any object with the stage API)
+    ledger: HostLedger  # the engine's stage and state clocks
 
     def dispatch_search(self, wave: Wave) -> None:
         """Stage A: async-dispatch base-graph candidate generation."""
-        with wave.span("engine.dispatch_search"):
+        with wave.span(self.ledger, "engine.dispatch_search"):
             wave.cands = self.index.search_stage_candidates(
                 wave.q, wave.base, k=wave.k)
+        self.ledger.to(INFLIGHT)
         for r in wave.requests:
             r.stage = SEARCHING
 
@@ -141,7 +148,7 @@ class TwoStagePipeline:
         is what makes engine results bitwise-equal to the baselines.
         """
         p_arg = wave.base if wave.exact else wave.p_vec
-        with wave.span("engine.dispatch_finish"):
+        with wave.span(self.ledger, "engine.dispatch_finish"):
             wave.result = self.index.search_stage_finish(
                 wave.q, wave.cands, p_arg, wave.k)
         wave.cands = None  # device buffers free as soon as B consumes them
@@ -159,7 +166,7 @@ class TwoStagePipeline:
         """
         ids, dists, st = wave.result
         n = wave.n_real
-        with wave.span("engine.collect.wait"):
+        with wave.span(self.ledger, "engine.collect.wait"):
             ids = np.asarray(ids)[:n]
         dists = np.asarray(dists)[:n]
         rows = st.host_rows(n)

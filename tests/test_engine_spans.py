@@ -1,8 +1,9 @@
 """The engine's per-wave profiler spans (repro.retrieval.engine).
 
 A small engine serves a mixed-p stream under `jax.profiler` on the CPU.
-The host plane of the profile must hold one span per engine stage, the
-spans of one wave must share its `wave=` id and padded size, and the
+The host plane of the profile must hold one span per engine stage
+(admission and the scheduler's poll among them), the spans of one wave
+must share its `wave=` id and padded size, and the
 blocking read must lie inside the collect that owns it. Profiling must
 not change a single served id or distance.
 """
@@ -16,9 +17,10 @@ from jax.profiler import ProfileData
 
 from repro.retrieval.service import QueryRequest, UniversalVectorService
 
-STAGES = ("engine.make_waves", "engine.dispatch_search",
-          "engine.dispatch_finish", "engine.collect", "engine.collect.wait")
-PER_WAVE = STAGES[1:]  # every wave passes these once; make_waves per flush
+STAGES = ("engine.admit", "engine.poll", "engine.make_waves",
+          "engine.dispatch_search", "engine.dispatch_finish", "engine.collect",
+          "engine.collect.wait")
+PER_WAVE = STAGES[3:]  # every wave passes these once; make_waves per flush
 
 
 def _requests(small_ds, n, seed):
@@ -115,3 +117,19 @@ def test_profiling_leaves_answers_bitwise_unchanged(profiled):
                                       plain[r.request_id][0])
         np.testing.assert_array_equal(traced[r.request_id][1],
                                       plain[r.request_id][1])
+
+
+@pytest.mark.parametrize("name", ["engine.dispatch_search",
+                                  "engine.dispatch_finish"])
+def test_each_stage_counter_lies_inside_its_spans(profiled, name):
+    """A span and its counter bracket the same code: the counter's
+    seconds are the spans' time less the annotation's own enter and
+    exit."""
+    from repro.retrieval.engine.telemetry import stage_key
+
+    _, _, _, stats, spans = profiled
+    ends = [(s, e) for n, s, e, _ in spans if n == name]
+    span_s = sum(e - s for s, e in ends) / 1e9
+    counted = stats[stage_key(name)]
+    assert 0.5 * span_s <= counted <= span_s + 1e-6 * len(ends), \
+        (counted, span_s)
